@@ -120,7 +120,7 @@ def decode_path_cell(m=8, nb=8, bi=1024, bo=64):
     single-K-tile shape (same single-dot accumulation order)."""
     from repro.kernels import bdmm as bdmm_kernel
     from repro.kernels import quant as quant_lib
-    from repro.kernels.tiling import pick_tile, round_up
+    from repro.kernels.tiling import LANE, pick_tile, round_up, sublane
 
     def exact(bi_x):
         x = jax.random.normal(jax.random.PRNGKey(0), (m, nb * bi_x))
@@ -136,14 +136,14 @@ def decode_path_cell(m=8, nb=8, bi=1024, bo=64):
 
     fp_exact, int8_exact = exact(bi_x=256)  # K fits one tile -> bit-exact
 
-    bm_, m_p = pick_tile(m, 128)
-    bn_, bo_p = pick_tile(bo, 128)
-    bk_, bi_p = pick_tile(bi, 512)
+    bm_, m_p = pick_tile(m, 128, sublane(jnp.float32))
+    bn_, bo_p = pick_tile(bo, 128, LANE)
+    bk_, bi_p = pick_tile(bi, 512, LANE)
     return {
         "m": m, "nb": nb, "bi": bi, "bo": bo,
         "grid_steps_general": (m_p // bm_) * nb * (bo_p // bn_) * (bi_p // bk_),
         "grid_steps_decode": nb * (bo_p // bn_),
-        "m_padded_decode": round_up(m, 8),
+        "m_padded_decode": round_up(m, sublane(jnp.float32)),
         "scratch_accumulator_general": True,
         "scratch_accumulator_decode": False,
         "exact_match_bi": 256,

@@ -3,9 +3,9 @@
 Two sources, both offline-synthesizable (this container has no datasets):
 
 * :class:`SyntheticLM` — a deterministic "hash-LM" token stream with real
-  learnable structure: tokens follow a hidden order-2 Markov chain derived
-  from a seeded random transition table, so models actually reduce loss and
-  compression/accuracy comparisons (Table 1 analogues) are meaningful.
+  learnable structure: tokens follow a hidden order-2 Markov chain, a
+  seeded hash of the previous two tokens, so models actually reduce loss
+  and compression/accuracy comparisons (Table 1 analogues) are meaningful.
 * :class:`TeacherStudent` — classification batches from a frozen random
   teacher MLP (inputs ~ N(0,1), labels = argmax of the teacher). This is the
   LeNet-300-100/MNIST stand-in used by the paper-figure benchmarks: the task
@@ -41,9 +41,21 @@ class SyntheticLM:
         assert self.global_batch % self.shard_count == 0
         rng = np.random.default_rng(np.random.SeedSequence([self.seed, 17]))
         # hidden order-2 Markov structure (shared across shards)
-        self._trans = rng.integers(0, self.vocab,
-                                   size=(self.vocab, self.vocab)).astype(np.int64)
+        self._key = np.uint64(rng.integers(0, 2**63))
         self._noise_p = 0.1
+
+    def transition(self, prev2, prev1) -> np.ndarray:
+        """The hidden chain's next token after ``(prev2, prev1)``: a seeded
+        hash of the pair (splitmix64's finaliser), so no ``vocab x vocab``
+        table is held — at a 50k vocab that table alone is 20 GB."""
+        v = np.uint64(self.vocab)
+        with np.errstate(over="ignore"):
+            x = (np.asarray(prev2, np.uint64) * v
+                 + np.asarray(prev1, np.uint64)) ^ self._key
+            x = (x ^ (x >> np.uint64(30))) * np.uint64(0xBF58476D1CE4E5B9)
+            x = (x ^ (x >> np.uint64(27))) * np.uint64(0x94D049BB133111EB)
+            x = x ^ (x >> np.uint64(31))
+        return (x % v).astype(np.int32)
 
     @property
     def local_batch(self) -> int:
@@ -58,7 +70,7 @@ class SyntheticLM:
         toks[:, 0] = rng.integers(0, self.vocab, b)
         toks[:, 1] = rng.integers(0, self.vocab, b)
         for t in range(2, self.seq_len + 1):
-            nxt = self._trans[toks[:, t - 2], toks[:, t - 1]]
+            nxt = self.transition(toks[:, t - 2], toks[:, t - 1])
             noise = rng.random(b) < self._noise_p
             nxt = np.where(noise, rng.integers(0, self.vocab, b), nxt)
             toks[:, t] = nxt

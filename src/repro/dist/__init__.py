@@ -23,7 +23,7 @@ local implementation when no mesh is active.
 """
 
 from . import compress, mesh, microbatch, pipeline, sharding, straggler  # noqa: F401
-from .mesh import data_axes, make_host_mesh, make_production_mesh  # noqa: F401
+from .mesh import data_axes, make_host_mesh, make_mesh, make_production_mesh  # noqa: F401
 from .sharding import (  # noqa: F401
     block_parallel_rules,
     current,

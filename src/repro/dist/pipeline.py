@@ -18,7 +18,6 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -72,11 +71,11 @@ def gpipe_forward(stage_fn: Callable, mesh: Mesh, axis_name: str) -> Callable:
 
         pspecs = jax.tree.map(
             lambda w: P(axis_name, *([None] * (w.ndim - 1))), params)
-        return shard_map(
+        return jax.shard_map(
             local, mesh=mesh,
             in_specs=(pspecs, P(*([None] * xs.ndim))),
             out_specs=P(*([None] * xs.ndim)),
-            check_rep=False,
+            check_vma=False,
         )(params, xs)
 
     return run

@@ -19,24 +19,4 @@ Bias/activation epilogues execute inside every kernel; ``ops`` carries the
 custom VJPs over the fused forms.
 """
 
-from jax.experimental.pallas import tpu as _pltpu
-
-# jax renamed pltpu.TPUCompilerParams -> pltpu.CompilerParams (and back-
-# compat'd neither direction), so resolve whichever the pinned jax exposes
-# once, here, and give the kernels a stable constructor.
-_COMPILER_PARAMS_CLS = getattr(
-    _pltpu, "CompilerParams", getattr(_pltpu, "TPUCompilerParams", None))
-
-
-def tpu_compiler_params(**kwargs):
-    """Version-portable ``pltpu.{TPU,}CompilerParams`` constructor."""
-    if _COMPILER_PARAMS_CLS is None:
-        # only the dict-API pallas era lacks both classes, and it wanted a
-        # platform-keyed dict — nothing we can construct faithfully here
-        raise ImportError(
-            "jax.experimental.pallas.tpu exposes neither CompilerParams nor "
-            "TPUCompilerParams; this jax version is unsupported")
-    return _COMPILER_PARAMS_CLS(**kwargs)
-
-
-from . import ops, ref  # noqa: F401,E402
+from . import ops, ref  # noqa: F401

@@ -26,16 +26,19 @@ TPU mapping
 Grid ``(m_tiles, nb, o_tiles, k_tiles)`` with K innermost ("arbitrary"
 semantics) accumulating into a f32 VMEM scratch tile; the epilogue runs on
 the last K step. Block shapes default to MXU-native ``128×128`` output tiles
-with a ``512``-deep K stream. Awkward (prime/odd) dims are padded to the
-next tile multiple instead of degrading the tile search (zero rows/cols are
-exact; see :mod:`repro.kernels.tiling`).
+with a ``512``-deep K stream. Activations, outputs, scales and biases are
+indexed as flat ``(rows, nb*width)`` arrays — the index map picks block
+``n``'s lane tiles — so every block is lane-aligned as Mosaic requires
+(a ``(bm, 1, bk)`` block over ``(m, nb, bi)`` is not). Awkward (prime/odd)
+dims are padded to the next aligned tile multiple instead of degrading the
+tile search (zero rows/cols are exact; see :mod:`repro.kernels.tiling`).
 
 Decode-shaped path
 ------------------
 Steady-state serve decode runs ``m = n_slots`` (≈8) rows — a 128-row m-tile
 wastes 15/16 of the MXU feed and the K-innermost revisiting grid re-reads
 the tiny activation every step. When ``m`` is small the wrapper switches to
-a weight-stationary variant: ``m`` padded to the sublane multiple, a flat
+a weight-stationary variant: ``m`` padded to the dtype's sublane multiple, a flat
 ``(nb, o_tiles)`` grid with the full K depth resident per step (decode-side
 ``bi = d_in/c`` is small by construction), no scratch accumulator, and the
 same epilogue. Selected automatically (``small_m=None``); both fp and int8
@@ -53,15 +56,24 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from . import tpu_compiler_params
 from .ref import ACTIVATIONS
 from .quant import widen_in_register as _widen
-from .tiling import pad_axis, pick_tile, round_up
+from .tiling import LANE, pad_axis, pick_tile, round_up, sublane
 
 # auto decode-path thresholds: m at or below this uses the flat grid, as
 # long as the full K depth fits comfortably in VMEM alongside one out tile
 SMALL_M_MAX = 32
 SMALL_M_K_MAX = 4096
+
+
+def _epilogue(acc, s_ref, b_ref, activation, out_dtype):
+    """Per-output-channel dequant scale, bias and activation on the f32
+    accumulator; ``s_ref``/``b_ref`` are ``(1, bn)`` tiles."""
+    if s_ref is not None:
+        acc = acc * s_ref[...].astype(jnp.float32)
+    if b_ref is not None:
+        acc = acc + b_ref[...].astype(jnp.float32)
+    return ACTIVATIONS[activation](acc).astype(out_dtype)
 
 
 def _bdmm_kernel(*refs, n_k: int, activation, out_dtype, has_bias: bool,
@@ -78,8 +90,8 @@ def _bdmm_kernel(*refs, n_k: int, activation, out_dtype, has_bias: bool,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    # x tile: (bm, 1, bk) ; w tile: (1, bk, bn)
-    x = x_ref[:, 0, :]
+    # x tile: (bm, bk) ; w tile: (1, bk, bn)
+    x = x_ref[...]
     acc_ref[...] += jax.lax.dot_general(
         x, _widen(w_ref[0], x),
         dimension_numbers=(((1,), (0,)), ((), ())),
@@ -87,14 +99,9 @@ def _bdmm_kernel(*refs, n_k: int, activation, out_dtype, has_bias: bool,
     )
 
     @pl.when(k == n_k - 1)
-    def _epilogue():
-        acc = acc_ref[...]
-        if s_ref is not None:
-            acc = acc * s_ref[0].astype(jnp.float32)
-        if b_ref is not None:
-            acc = acc + b_ref[0].astype(jnp.float32)
-        acc = ACTIVATIONS[activation](acc)
-        o_ref[...] = acc.astype(out_dtype)[:, None, :]
+    def _store():
+        o_ref[...] = _epilogue(acc_ref[...], s_ref, b_ref, activation,
+                               out_dtype)
 
 
 def _bdmm_decode_kernel(*refs, activation, out_dtype, has_bias: bool,
@@ -107,17 +114,13 @@ def _bdmm_decode_kernel(*refs, activation, out_dtype, has_bias: bool,
     b_ref = next(it) if has_bias else None
     o_ref = next(it)
 
-    x = x_ref[:, 0, :]
+    x = x_ref[...]
     acc = jax.lax.dot_general(
         x, _widen(w_ref[0], x),
         dimension_numbers=(((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
     )
-    if s_ref is not None:
-        acc = acc * s_ref[0].astype(jnp.float32)
-    if b_ref is not None:
-        acc = acc + b_ref[0].astype(jnp.float32)
-    o_ref[...] = ACTIVATIONS[activation](acc).astype(out_dtype)[:, None, :]
+    o_ref[...] = _epilogue(acc, s_ref, b_ref, activation, out_dtype)
 
 
 @functools.partial(
@@ -143,11 +146,11 @@ def bdmm(
 
     ``bias`` (if given) is packed ``(nb*bo,)``. An int8 ``wp`` requires
     ``scale: (nb, bo)`` (per-output-channel dequant, applied in the
-    epilogue). Tile sizes clamp to the actual dims and awkward remainders
-    are padded to the next tile multiple, so small/smoke shapes work
-    unchanged. ``small_m`` forces (True) / forbids (False) the
-    decode-shaped weight-stationary path; ``None`` selects it automatically
-    for small row counts.
+    epilogue). Tiles follow :mod:`repro.kernels.tiling` (lane- and
+    dtype-sublane-aligned; awkward dims are padded), so any shape works.
+    ``small_m`` forces (True) / forbids (False) the decode-shaped
+    weight-stationary path; ``None`` selects it automatically for small row
+    counts.
     """
     nb, bi, bo = wp.shape
     lead = x.shape[:-1]
@@ -159,40 +162,39 @@ def bdmm(
     m = 1
     for d in lead:
         m *= d
-    x2 = x.reshape(m, nb, bi)
     out_dtype = out_dtype or x.dtype
 
     if small_m is None:
         small_m = m <= SMALL_M_MAX and bi <= SMALL_M_K_MAX
 
-    m_unit = 8 if jnp.dtype(x.dtype).itemsize >= 4 else 16
+    m_align = max(sublane(x.dtype), sublane(out_dtype))
+    bn_, bo_p = pick_tile(bo, bn, LANE)
+    jt = bo_p // bn_                      # out tiles per diagonal block
     if small_m:
         # weight-stationary flat grid: full K per step, m padded to sublane
-        m_p = round_up(m, m_unit)
-        bn_, bo_p = pick_tile(bo, bn, name="bo", kernel="bdmm")
-        bm_, bk_, bi_p, n_k = m_p, bi, bi, 1
-        grid = (nb, bo_p // bn_)
-        x_idx = lambda n, j: (0, n, 0)
+        bm_ = m_p = round_up(m, m_align)
+        bk_ = bi_p = round_up(bi, LANE)
+        grid = (nb, jt)
+        x_idx = lambda n, j: (0, n)
         w_idx = lambda n, j: (n, 0, j)
-        v_idx = lambda n, j: (n, j)
-        o_idx = lambda n, j: (0, n, j)
+        o_idx = lambda n, j: (0, n * jt + j)
         kernel_fn, dims = _bdmm_decode_kernel, ("parallel", "parallel")
     else:
-        bm_, m_p = pick_tile(m, bm, name="m", kernel="bdmm")
-        bn_, bo_p = pick_tile(bo, bn, name="bo", kernel="bdmm")
-        bk_, bi_p = pick_tile(bi, bk, name="bi", kernel="bdmm")
+        bm_, m_p = pick_tile(m, bm, m_align)
+        bk_, bi_p = pick_tile(bi, bk, LANE)
         n_k = bi_p // bk_
-        grid = (m_p // bm_, nb, bo_p // bn_, n_k)
-        x_idx = lambda i, n, j, k: (i, n, k)
+        grid = (m_p // bm_, nb, jt, n_k)
+        x_idx = lambda i, n, j, k: (i, n * n_k + k)
         w_idx = lambda i, n, j, k: (n, k, j)
-        v_idx = lambda i, n, j, k: (n, j)
-        o_idx = lambda i, n, j, k: (i, n, j)
+        o_idx = lambda i, n, j, k: (i, n * jt + j)
         kernel_fn = _bdmm_kernel
         dims = ("parallel", "parallel", "parallel", "arbitrary")
+    v_idx = lambda *g: (0, o_idx(*g)[1])
 
     # zero-padding is exact: padded K rows/cols contribute nothing, padded
     # m/bo rows are sliced off below
-    x2 = pad_axis(pad_axis(x2, 0, m_p), 2, bi_p)
+    x2 = pad_axis(pad_axis(x.reshape(m, nb, bi), 0, m_p), 2, bi_p)
+    x2 = x2.reshape(m_p, nb * bi_p)
     wp = pad_axis(pad_axis(wp, 1, bi_p), 2, bo_p)
 
     has_bias, has_scale = bias is not None, scale is not None
@@ -203,27 +205,27 @@ def bdmm(
     kernel = functools.partial(kernel_fn, **kw)
 
     in_specs = [
-        pl.BlockSpec((bm_, 1, bk_), x_idx),
+        pl.BlockSpec((bm_, bk_), x_idx),
         pl.BlockSpec((1, bk_, bn_), w_idx),
     ]
     args = [x2, wp]
-    if has_scale:
-        in_specs.append(pl.BlockSpec((1, bn_), v_idx))
-        args.append(pad_axis(scale, 1, bo_p))
-    if has_bias:
-        assert bias.shape == (nb * bo,)
-        in_specs.append(pl.BlockSpec((1, bn_), v_idx))
-        args.append(pad_axis(bias.reshape(nb, bo), 1, bo_p))
+    # per-output-channel operands ride as one (1, nb*bo_p) row
+    for v in (scale, bias):
+        if v is not None:
+            in_specs.append(pl.BlockSpec((1, bn_), v_idx))
+            args.append(pad_axis(v.reshape(nb, bo), 1, bo_p)
+                        .reshape(1, nb * bo_p))
 
     y = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((bm_, 1, bn_), o_idx),
-        out_shape=jax.ShapeDtypeStruct((m_p, nb, bo_p), out_dtype),
+        out_specs=pl.BlockSpec((bm_, bn_), o_idx),
+        out_shape=jax.ShapeDtypeStruct((m_p, nb * bo_p), out_dtype),
         scratch_shapes=([] if small_m
                         else [pltpu.VMEM((bm_, bn_), jnp.float32)]),
-        compiler_params=tpu_compiler_params(dimension_semantics=dims),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=dims),
         interpret=interpret,
     )(*args)
-    return y[:m, :, :bo].reshape(*lead, nb * bo)
+    y = y.reshape(m_p, nb, bo_p)[:m, :, :bo]
+    return y.reshape(*lead, nb * bo)

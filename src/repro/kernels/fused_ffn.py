@@ -32,10 +32,12 @@ the last f step. Working set per step (bm=128, bf=512, bi=bo=256, f32):
     x (bm·bi) + Wu,Wg (bi·bf ×2) + Wd (bf·bo) + h (bm·bf) + acc (bm·bo)
     ≈ 128KB + 512KB×3 + 256KB + 128KB ≈ 2 MB
 
-— comfortably inside ~16 MB VMEM with double-buffering headroom. Awkward
-(prime/odd) ``m``/``f`` dims are padded to the next tile multiple instead
-of degrading the tile search (zero f-channels are exact: their ``w_down``
-rows are zero, so whatever the hidden epilogue produces there contributes
+— comfortably inside ~16 MB VMEM with double-buffering headroom.
+Activations, outputs, scales and biases are indexed as flat
+``(rows, nb*width)`` arrays so every block is lane-aligned. Awkward
+(prime/odd) dims are padded to the next aligned tile multiple instead of
+degrading the tile search (zero f-channels are exact: their ``w_down`` rows
+are zero, so whatever the hidden epilogue produces there contributes
 nothing).
 """
 
@@ -49,10 +51,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from . import tpu_compiler_params
 from .ref import ACTIVATIONS
 from .quant import widen_in_register as _widen
-from .tiling import pad_axis, pick_tile
+from .tiling import LANE, pad_axis, pick_tile, round_up, sublane
 
 
 def _ffn_kernel(*refs, n_f: int, activation, out_dtype, gated: bool,
@@ -78,16 +79,16 @@ def _ffn_kernel(*refs, n_f: int, activation, out_dtype, gated: bool,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    x = x_ref[:, 0, :]  # (bm, bi)
+    x = x_ref[...]  # (bm, bi)
 
     def proj(w_ref, s_ref, b_ref):
         z = jax.lax.dot_general(x, _widen(w_ref[0], x),
                                 (((1,), (0,)), ((), ())),
                                 preferred_element_type=jnp.float32)
         if s_ref is not None:
-            z = z * s_ref[0].astype(jnp.float32)
+            z = z * s_ref[...].astype(jnp.float32)
         if b_ref is not None:
-            z = z + b_ref[0].astype(jnp.float32)
+            z = z + b_ref[...].astype(jnp.float32)
         return z
 
     u = proj(wu_ref, su_ref, bu_ref)
@@ -105,10 +106,10 @@ def _ffn_kernel(*refs, n_f: int, activation, out_dtype, gated: bool,
     def _epilogue():
         out = acc_ref[...]
         if sd_ref is not None:
-            out = out * sd_ref[0].astype(jnp.float32)
+            out = out * sd_ref[...].astype(jnp.float32)
         if bd_ref is not None:
-            out = out + bd_ref[0].astype(jnp.float32)
-        o_ref[...] = out.astype(out_dtype)[:, None, :]
+            out = out + bd_ref[...].astype(jnp.float32)
+        o_ref[...] = out.astype(out_dtype)
 
 
 @functools.partial(
@@ -167,20 +168,25 @@ def fused_ffn(
     m = 1
     for d in lead:
         m *= d
-    x2 = x.reshape(m, nb, bi)
-
-    bm_, m_p = pick_tile(m, bm, name="m", kernel="fused_ffn")
-    bf_, f_p = pick_tile(f, bf, name="f", kernel="fused_ffn")
-    n_f = f_p // bf_
-    grid = (m_p // bm_, nb, n_f)
     out_dtype = out_dtype or x.dtype
     gated_ = w_gate is not None
 
-    # pad m rows (sliced off below) and f channels (exact: padded w_down
-    # rows are zero, so padded hidden channels contribute nothing)
-    x2 = pad_axis(x2, 0, m_p)
-    w_up = pad_axis(w_up, 2, f_p)
-    w_down = pad_axis(w_down, 1, f_p)
+    bm_, m_p = pick_tile(m, bm, max(sublane(x.dtype), sublane(out_dtype)))
+    bf_, f_p = pick_tile(f, bf, LANE)
+    bi_p, bo_p = round_up(bi, LANE), round_up(bo, LANE)
+    n_f = f_p // bf_
+    grid = (m_p // bm_, nb, n_f)
+
+    # pad m rows and bo cols (sliced off below), bi (exact: zero K) and f
+    # channels (exact: padded w_down rows are zero, so padded hidden
+    # channels contribute nothing); activations, outputs and the
+    # per-channel rows are flat (rows, nb*width) so every block is
+    # lane-aligned
+    x2 = pad_axis(pad_axis(x.reshape(m, nb, bi), 0, m_p), 2, bi_p)
+    x2 = x2.reshape(m_p, nb * bi_p)
+
+    def row(v, width, width_p):      # (nb*width,) or (nb, width) -> (1, nb*width_p)
+        return pad_axis(v.reshape(nb, width), 1, width_p).reshape(1, -1)
 
     kernel = functools.partial(
         _ffn_kernel, n_f=n_f, activation=activation, out_dtype=out_dtype,
@@ -188,43 +194,44 @@ def fused_ffn(
         has_b_gate=b_gate is not None, has_b_down=b_down is not None,
     )
 
-    in_specs = [
-        pl.BlockSpec((bm_, 1, bi), lambda i, n, fi: (i, n, 0)),
-        pl.BlockSpec((1, bi, bf_), lambda i, n, fi: (n, 0, fi)),
-    ]
-    args = [x2, w_up]
+    w_in_spec = pl.BlockSpec((1, bi_p, bf_), lambda i, n, fi: (n, 0, fi))
+    f_row_spec = pl.BlockSpec((1, bf_), lambda i, n, fi: (0, n * n_f + fi))
+    o_row_spec = pl.BlockSpec((1, bo_p), lambda i, n, fi: (0, n))
+    in_specs = [pl.BlockSpec((bm_, bi_p), lambda i, n, fi: (i, n)), w_in_spec]
+    args = [x2, pad_axis(pad_axis(w_up, 1, bi_p), 2, f_p)]
     if gated_:
         assert w_gate.shape == (nb, bi, f), (w_gate.shape, (nb, bi, f))
-        in_specs.append(pl.BlockSpec((1, bi, bf_), lambda i, n, fi: (n, 0, fi)))
-        args.append(pad_axis(w_gate, 2, f_p))
-    in_specs.append(pl.BlockSpec((1, bf_, bo), lambda i, n, fi: (n, fi, 0)))
-    args.append(w_down)
+        in_specs.append(w_in_spec)
+        args.append(pad_axis(pad_axis(w_gate, 1, bi_p), 2, f_p))
+    in_specs.append(pl.BlockSpec((1, bf_, bo_p), lambda i, n, fi: (n, fi, 0)))
+    args.append(pad_axis(pad_axis(w_down, 1, f_p), 2, bo_p))
     if quant:
-        for s in ([s_up, s_gate] if gated_ else [s_up]):
-            in_specs.append(pl.BlockSpec((1, bf_), lambda i, n, fi: (n, fi)))
-            args.append(pad_axis(s, 1, f_p))
-        in_specs.append(pl.BlockSpec((1, bo), lambda i, n, fi: (n, 0)))
-        args.append(s_down)
+        for sc in ([s_up, s_gate] if gated_ else [s_up]):
+            in_specs.append(f_row_spec)
+            args.append(row(sc, f, f_p))
+        in_specs.append(o_row_spec)
+        args.append(row(s_down, bo, bo_p))
     for b in (b_up, b_gate):
         if b is not None:
             assert b.shape == (nb * f,), (b.shape, nb, f)
-            in_specs.append(pl.BlockSpec((1, bf_), lambda i, n, fi: (n, fi)))
-            args.append(pad_axis(b.reshape(nb, f), 1, f_p))
+            in_specs.append(f_row_spec)
+            args.append(row(b, f, f_p))
     if b_down is not None:
         assert b_down.shape == (nb * bo,), (b_down.shape, nb, bo)
-        in_specs.append(pl.BlockSpec((1, bo), lambda i, n, fi: (n, 0)))
-        args.append(b_down.reshape(nb, bo))
+        in_specs.append(o_row_spec)
+        args.append(row(b_down, bo, bo_p))
 
     y = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((bm_, 1, bo), lambda i, n, fi: (i, n, 0)),
-        out_shape=jax.ShapeDtypeStruct((m_p, nb, bo), out_dtype),
-        scratch_shapes=[pltpu.VMEM((bm_, bo), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        out_specs=pl.BlockSpec((bm_, bo_p), lambda i, n, fi: (i, n)),
+        out_shape=jax.ShapeDtypeStruct((m_p, nb * bo_p), out_dtype),
+        scratch_shapes=[pltpu.VMEM((bm_, bo_p), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
     )(*args)
-    return y[:m].reshape(*lead, nb * bo)
+    y = y.reshape(m_p, nb, bo_p)[:m, :, :bo]
+    return y.reshape(*lead, nb * bo)

@@ -27,7 +27,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from . import tpu_compiler_params
 from .ref import ACTIVATIONS
 
 
@@ -130,7 +129,7 @@ def masked_matmul(
         out_specs=pl.BlockSpec((bm_, bn_), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm_, bn_), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -197,7 +196,7 @@ def sddmm_masked(
         out_specs=pl.BlockSpec((bi_, bo_), lambda i, j, t: (i, j)),
         out_shape=jax.ShapeDtypeStruct((d_in, d_out), out_dtype),
         scratch_shapes=[pltpu.VMEM((bi_, bo_), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -51,9 +51,11 @@ from . import paged_attention as paged_attn_kernel
 from . import paged_prefill as paged_prefill_kernel
 from . import ref
 
-_BACKEND = "jnp" if jax.default_backend() != "tpu" else "pallas"
+# None until first read: the default is resolved from the platform then,
+# not at import (importing must not initialise a JAX backend).
+_BACKEND: Optional[str] = None
 
-# Prefill-attention override: when None, chunked prefill follows _BACKEND.
+# Prefill-attention override: when None, chunked prefill follows get_backend().
 # Settable independently (``--prefill-kernel``) because the flash prefill
 # kernel's interpret mode is the CPU-testable route while the rest of the
 # serve loop stays on the fast jnp oracle. Read at trace time — set it
@@ -68,6 +70,11 @@ def set_backend(name: str) -> None:
 
 
 def get_backend() -> str:
+    """The kernel route: as last set, else ``"pallas"`` on a TPU and
+    ``"jnp"`` elsewhere. Read at trace time."""
+    global _BACKEND
+    if _BACKEND is None:
+        _BACKEND = "pallas" if jax.default_backend() == "tpu" else "jnp"
     return _BACKEND
 
 
@@ -78,7 +85,8 @@ def set_prefill_backend(name: Optional[str]) -> None:
 
 
 def prefill_backend() -> str:
-    return _PREFILL_BACKEND if _PREFILL_BACKEND is not None else _BACKEND
+    return (_PREFILL_BACKEND if _PREFILL_BACKEND is not None
+            else get_backend())
 
 
 def _act_bwd(activation: Optional[str], z, g):
@@ -91,17 +99,72 @@ def _act_bwd(activation: Optional[str], z, g):
     return vjp(g)[0]
 
 
+def _mesh_axes(logical: str, dim: int):
+    """The active mesh and the mesh axes its rule table maps ``logical`` to,
+    as ``(mesh, axes)``. ``axes`` is ``()`` when there are none or their
+    combined size does not divide ``dim`` (4 KV heads on an 8-way model
+    axis replicate — the policy of :func:`repro.dist.sharding
+    .sanitize_spec`); ``mesh`` is ``None`` outside any mesh."""
+    from repro.dist import sharding as _sh
+    mesh, rules = _sh.current()
+    if mesh is None:
+        return None, ()
+    axes = tuple((rules or {}).get(logical) or ())
+    if any(a not in mesh.shape for a in axes):
+        return mesh, ()
+    size = 1
+    for a in axes:
+        size *= mesh.shape[a]
+    return mesh, (axes if size > 1 and dim % size == 0 else ())
+
+
+def _block_parallel(fn, x, *wargs):
+    """Run a Pallas block-diagonal kernel ``fn(x, *wargs)`` under the active
+    mesh.
+
+    Mosaic kernels cannot be partitioned by GSPMD, so under a mesh the call
+    runs inside ``shard_map``: each device runs its share of the ``nb``
+    diagonal blocks when the rule table's ``"blocks"`` axes divide ``nb``
+    (blocks are independent — the paper's parallel-speedup property — so
+    nothing is exchanged inside), and all of them on replicated operands
+    otherwise. ``x (..., nb*bi)`` and the output ``(..., nb*bo)`` split on
+    their last dim; each of ``wargs`` (weights, scales, packed biases, or
+    ``None``) carries the block axis first, ``wargs[0]`` being ``(nb, ...)``.
+    A plain call outside any mesh.
+    """
+    mesh, axes = _mesh_axes("blocks", wargs[0].shape[0])
+    if mesh is None:
+        return fn(x, *wargs)
+    from jax.sharding import PartitionSpec as P
+
+    ax = axes or None
+    held = [i for i, w in enumerate(wargs) if w is not None]
+    x_spec = P(*([None] * (x.ndim - 1)), ax)
+    w_specs = tuple(P(ax, *([None] * (wargs[i].ndim - 1))) for i in held)
+
+    def inner(x_, *ws):
+        full = list(wargs)
+        for i, w in zip(held, ws):
+            full[i] = w
+        return fn(x_, *full)
+
+    return jax.shard_map(inner, mesh=mesh, in_specs=(x_spec,) + w_specs,
+                         out_specs=x_spec, check_vma=False,
+                         )(x, *(wargs[i] for i in held))
+
+
 # --------------------------------------------------------------------------
 # bdmm — block-diagonal matmul (packed inference/training form)
 # --------------------------------------------------------------------------
 
 def _bdmm_raw(x, wp, bias, activation, precision):
     """Backend-routed fused forward (no custom VJP — used by fwd and bwd)."""
-    if _BACKEND == "jnp":
+    if get_backend() == "jnp":
         return ref.bdmm_ref(x, wp, bias, activation=activation,
                             precision=precision)
-    return bdmm_kernel.bdmm(x, wp, bias, activation=activation,
-                            interpret=(_BACKEND == "interpret"))
+    kernel = functools.partial(bdmm_kernel.bdmm, activation=activation,
+                               interpret=(get_backend() == "interpret"))
+    return _block_parallel(kernel, x, wp, bias)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
@@ -161,12 +224,13 @@ def bdmm_quant(x, wq, scale, bias=None, *, activation: Optional[str] = None,
     in-register against the f32 accumulator; ``precision`` selects the jnp
     einsum precision only.
     """
-    if _BACKEND == "jnp":
+    if get_backend() == "jnp":
         return ref.bdmm_quant_ref(x, wq, scale, bias, activation=activation,
                                   precision=precision)
-    return bdmm_kernel.bdmm(x, wq, bias, scale, activation=activation,
-                            interpret=(_BACKEND == "interpret"),
-                            small_m=small_m)
+    kernel = functools.partial(bdmm_kernel.bdmm, activation=activation,
+                               interpret=(get_backend() == "interpret"),
+                               small_m=small_m)
+    return _block_parallel(kernel, x, wq, bias, scale)
 
 
 # --------------------------------------------------------------------------
@@ -174,11 +238,11 @@ def bdmm_quant(x, wq, scale, bias=None, *, activation: Optional[str] = None,
 # --------------------------------------------------------------------------
 
 def _masked_matmul_raw(x, w, mask, bias, activation, precision):
-    if _BACKEND == "jnp":
+    if get_backend() == "jnp":
         return ref.masked_matmul_ref(x, w, mask, bias, activation=activation,
                                      precision=precision)
     return mm_kernel.masked_matmul(x, w, mask, bias, activation=activation,
-                                   interpret=(_BACKEND == "interpret"))
+                                   interpret=(get_backend() == "interpret"))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
@@ -200,14 +264,14 @@ def _masked_matmul_bwd(activation, precision, res, g):
     x, w, mask, bias, z = res
     if activation is not None:
         g = _act_bwd(activation, z, g)
-    if _BACKEND == "jnp":
+    if get_backend() == "jnp":
         dx = jnp.dot(g, (w * mask.astype(w.dtype)).T, precision=precision)
         dw = ref.matmul_masked_grad_ref(
             x.reshape(-1, x.shape[-1]), g.reshape(-1, g.shape[-1]), mask,
             precision=precision,
         ).astype(w.dtype)
     else:
-        interp = _BACKEND == "interpret"
+        interp = get_backend() == "interpret"
         dx = mm_kernel.masked_matmul(g, w, mask, transpose_rhs=True,
                                      interpret=interp)
         dw = mm_kernel.sddmm_masked(x, g, mask, interpret=interp).astype(w.dtype)
@@ -231,16 +295,27 @@ def masked_matmul(x, w, mask, bias=None, *, activation: Optional[str] = None,
 # fused block-diagonal MLP — the packed+perm-fused FFN hot path
 # --------------------------------------------------------------------------
 
+def _fused_ffn_kernel(x, w_up, w_down, w_gate, b_up, b_gate, b_down, s_up,
+                      s_gate, s_down, activation):
+    """The Pallas fused-FFN call (fp or int8), block-parallel under a
+    mesh."""
+    def kernel(x_, *w):
+        return ffn_kernel.fused_ffn(
+            x_, w[0], w[1], w_gate=w[2], b_up=w[3], b_gate=w[4], b_down=w[5],
+            s_up=w[6], s_gate=w[7], s_down=w[8], activation=activation,
+            interpret=(get_backend() == "interpret"))
+    return _block_parallel(kernel, x, w_up, w_down, w_gate, b_up, b_gate,
+                           b_down, s_up, s_gate, s_down)
+
+
 def _fused_ffn_raw(x, w_up, w_gate, w_down, b_up, b_gate, b_down, activation,
                    precision):
-    if _BACKEND == "jnp":
+    if get_backend() == "jnp":
         return ref.fused_ffn_ref(x, w_up, w_down, w_gate=w_gate, b_up=b_up,
                                  b_gate=b_gate, b_down=b_down,
                                  activation=activation, precision=precision)
-    return ffn_kernel.fused_ffn(x, w_up, w_down, w_gate=w_gate, b_up=b_up,
-                                b_gate=b_gate, b_down=b_down,
-                                activation=activation,
-                                interpret=(_BACKEND == "interpret"))
+    return _fused_ffn_kernel(x, w_up, w_down, w_gate, b_up, b_gate, b_down,
+                             None, None, None, activation)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8))
@@ -341,49 +416,20 @@ def fused_ffn_quant(x, w_up, w_down, *, s_up, s_down, w_gate=None,
     if w_gate is None and (b_gate is not None or s_gate is not None):
         raise ValueError("fused_ffn_quant: gate bias/scale given but w_gate "
                          "is None")
-    if _BACKEND == "jnp":
+    if get_backend() == "jnp":
         return ref.fused_ffn_quant_ref(
             x, w_up, w_down, w_gate=w_gate, b_up=b_up, b_gate=b_gate,
             b_down=b_down, s_up=s_up, s_gate=s_gate, s_down=s_down,
             activation=activation, precision=precision)
-    return ffn_kernel.fused_ffn(
-        x, w_up, w_down, w_gate=w_gate, b_up=b_up, b_gate=b_gate,
-        b_down=b_down, s_up=s_up, s_gate=s_gate, s_down=s_down,
-        activation=activation, interpret=(_BACKEND == "interpret"))
+    return _fused_ffn_kernel(x, w_up, w_down, w_gate, b_up, b_gate, b_down,
+                             s_up, s_gate, s_down, activation)
 
 
 # --------------------------------------------------------------------------
 # paged attention — decode step against the paged KV pool
 # --------------------------------------------------------------------------
 
-def _paged_tp(n_kv_heads: int):
-    """Resolve the active mesh/rules to the tensor-parallel axes the paged
-    attention ops shard their head dim over.
-
-    Returns ``(mesh, axes)`` when a mesh is active, the rule table maps
-    ``"kv_heads"`` to one or more mesh axes, and their combined size divides
-    the pool's KV-head count — i.e. exactly when ``paged_cache_axes`` places
-    the page pools sharded rather than replicated. ``None`` means run the
-    single-device path (also the indivisible-GQA fallback: 4 KV heads on an
-    8-way model axis replicate, same policy as :func:`repro.dist.sharding
-    .sanitize_spec`).
-    """
-    from repro.dist import sharding as _sh
-    mesh, rules = _sh.current()
-    if mesh is None or rules is None:
-        return None
-    axes = tuple((rules.get("kv_heads") or ()))
-    if not axes or any(a not in mesh.shape for a in axes):
-        return None
-    size = 1
-    for a in axes:
-        size *= mesh.shape[a]
-    if size <= 1 or n_kv_heads % size != 0:
-        return None
-    return mesh, axes
-
-
-def _tp_head_parallel(fn, head_axis, q, k_pages, v_pages, *rest):
+def _tp_head_parallel(fn, head_axis, kernel, q, k_pages, v_pages, *rest):
     """Head-parallel ``shard_map`` wrapper shared by the three paged ops.
 
     Queries shard on ``head_axis``; the K/V pools shard on their KV-head
@@ -394,16 +440,21 @@ def _tp_head_parallel(fn, head_axis, q, k_pages, v_pages, *rest):
     identical to the single-device dispatch, so after the output
     ``all_gather`` over the head dim the result is *bit-identical* to the
     unsharded path (the serve exactness contract extends to TP). One
-    collective per attention output; the packed projection weights around
-    it shard on the same ``tp_rules`` axes with GSPMD inserting the one
-    all-reduce per attention/FFN output.
+    collective per attention output; the packed projections around it run
+    block-parallel (:func:`_block_parallel`).
+
+    The pools shard exactly when ``paged_cache_axes`` places them sharded:
+    the rule table maps ``"kv_heads"`` to mesh axes whose size divides the
+    KV-head count. Otherwise the ``jnp`` route runs unsharded and a Pallas
+    route (``kernel``; Mosaic kernels cannot be partitioned by GSPMD) runs
+    on replicated operands on every device.
     """
-    tp = _paged_tp(k_pages.shape[2])
-    if tp is None:
+    mesh, axes = _mesh_axes("kv_heads", k_pages.shape[2])
+    if mesh is None or not (axes or kernel):
         return fn(q, k_pages, v_pages, *rest)
-    mesh, axes = tp
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
+
+    axes = axes or None
 
     rest = tuple(r if hasattr(r, "ndim") else jnp.asarray(r) for r in rest)
     q_spec = P(*(axes if i == head_axis else None for i in range(q.ndim)))
@@ -413,12 +464,14 @@ def _tp_head_parallel(fn, head_axis, q, k_pages, v_pages, *rest):
 
     def inner(q_, kp_, vp_, *rest_):
         o = fn(q_, kp_, vp_, *rest_)
+        if axes is None:
+            return o
         return jax.lax.all_gather(o, axes, axis=head_axis, tiled=True)
 
-    return shard_map(
-        inner, mesh,
+    return jax.shard_map(
+        inner, mesh=mesh,
         in_specs=(q_spec, kv_spec, kv_spec) + rest_specs,
-        out_specs=out_spec, check_rep=False,
+        out_specs=out_spec, check_vma=False,
     )(q, k_pages, v_pages, *rest)
 
 
@@ -435,13 +488,13 @@ def paged_attention(q, k_pages, v_pages, block_tables, lengths):
     bit-identical output, sharded pools.
     """
     def routed(q_, kp_, vp_, bt_, len_):
-        if _BACKEND == "jnp":
+        if get_backend() == "jnp":
             return ref.paged_attention_ref(q_, kp_, vp_, bt_, len_)
         return paged_attn_kernel.paged_attention(
-            q_, kp_, vp_, bt_, len_, interpret=(_BACKEND == "interpret"))
+            q_, kp_, vp_, bt_, len_, interpret=(get_backend() == "interpret"))
 
-    return _tp_head_parallel(routed, 1, q, k_pages, v_pages,
-                             block_tables, lengths)
+    return _tp_head_parallel(routed, 1, get_backend() != "jnp", q, k_pages,
+                             v_pages, block_tables, lengths)
 
 
 def paged_attention_verify(q, k_pages, v_pages, block_tables, lengths):
@@ -455,13 +508,13 @@ def paged_attention_verify(q, k_pages, v_pages, block_tables, lengths):
     :func:`paged_attention`.
     """
     def routed(q_, kp_, vp_, bt_, len_):
-        if _BACKEND == "jnp":
+        if get_backend() == "jnp":
             return ref.paged_attention_verify_ref(q_, kp_, vp_, bt_, len_)
         return paged_attn_kernel.paged_attention_verify(
-            q_, kp_, vp_, bt_, len_, interpret=(_BACKEND == "interpret"))
+            q_, kp_, vp_, bt_, len_, interpret=(get_backend() == "interpret"))
 
-    return _tp_head_parallel(routed, 2, q, k_pages, v_pages,
-                             block_tables, lengths)
+    return _tp_head_parallel(routed, 2, get_backend() != "jnp", q, k_pages,
+                             v_pages, block_tables, lengths)
 
 
 def paged_prefill_attention(q, k_pages, v_pages, bt_row, start, chunk_len):
@@ -489,5 +542,5 @@ def paged_prefill_attention(q, k_pages, v_pages, bt_row, start, chunk_len):
             q_, kp_, vp_, bt_, start_, clen_,
             interpret=(backend == "interpret"))
 
-    return _tp_head_parallel(routed, 1, q, k_pages, v_pages,
-                             bt_row, start, chunk_len)
+    return _tp_head_parallel(routed, 1, prefill_backend() != "jnp", q,
+                             k_pages, v_pages, bt_row, start, chunk_len)

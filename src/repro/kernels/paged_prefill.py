@@ -54,7 +54,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from . import tpu_compiler_params
 
 NEG_INF = -1e30
 
@@ -183,7 +182,7 @@ def paged_prefill_attention(q, k_pages, v_pages, bt_row, start, chunk_len, *,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((Tc, H, Dh), q.dtype),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(bt_row.astype(jnp.int32), info, q, k_pages, v_pages)

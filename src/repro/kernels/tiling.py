@@ -1,19 +1,28 @@
 """Grid-tiling policy shared by the Pallas kernels.
 
-The kernels require every grid axis to tile its dim exactly. The old
-fallback walked divisors down to 1, so a prime or odd dim silently degraded
-to tile size 1 — a correct but catastrophically serial grid. The policy
-here instead *pads the operand* to the next tile multiple (zero rows/cols
-are exact: they contribute nothing to a matmul and are sliced off the
-output), and only accepts an exact divisor when it stays at or above the
-sublane width.
+A TPU block must respect the vreg layout: its last dim is a multiple of
+:data:`LANE` (128) and its second-to-last a multiple of the dtype's sublane
+count (8 rows for 4-byte, 16 for 2-byte, 32 for 1-byte types), unless the
+block spans that whole array dim. The kernels index packed operands as flat
+2-D ``(rows, nb * width)`` arrays, so a block never spans the packed dim
+and every tile here is aligned.
+
+Awkward dims are never met by shrinking the tile below alignment: the
+operand is *padded* to the next tile multiple (zero rows/cols are exact —
+they contribute nothing to a matmul and are sliced off the output).
 """
 
 from __future__ import annotations
 
-import warnings
+import numpy as np
 
-SUBLANE = 8  # f32 sublane width; bf16/int8 want more, but 8 is the floor
+LANE = 128
+
+
+def sublane(dtype) -> int:
+    """Row multiple of a ``dtype`` block's second-to-last dim: 8 rows of
+    4-byte values fill a vreg, 2-byte types pack 16, 1-byte types 32."""
+    return {4: 8, 2: 16, 1: 32}[min(np.dtype(dtype).itemsize, 4)]
 
 
 def round_up(n: int, m: int) -> int:
@@ -30,33 +39,23 @@ def pad_axis(a, axis: int, to: int):
     return jnp.pad(a, pad)
 
 
-def pick_tile(dim: int, want: int, *, unit: int = SUBLANE,
-              name: str = "dim", kernel: str = "kernel"):
-    """Choose a tile size for ``dim`` aiming at ``want``.
+def pick_tile(dim: int, want: int, align: int):
+    """Choose an ``align``-multiple tile for ``dim`` aiming at ``want``.
 
-    Returns ``(tile, padded_dim)`` with ``padded_dim % tile == 0``. Prefers
-    an exact divisor of ``dim`` no smaller than ``unit``; otherwise keeps a
-    large tile and pads ``dim`` up to the next multiple. Warns when the
-    tile lands below the sublane width (only possible when ``dim`` itself
-    is that small — the grid still works, at reduced lane utilization).
+    Returns ``(tile, padded_dim)`` with ``padded_dim % tile == 0``. A dim
+    that fits in ``want`` is one tile (padded up to ``align``). Otherwise an
+    exact divisor of the aligned dim in ``[want/2, want]`` wins; failing
+    that, the fewest tiles of at most ``want`` are balanced and the dim is
+    padded to their sum, which bounds the padding below one ``align`` per
+    tile.
     """
-    t = min(want, dim)
-    if dim % t:
-        t = next((s for s in range(t, unit - 1, -1) if dim % s == 0), 0)
-        if not t:  # awkward (prime/odd) dim: pad instead of degrading to 1
-            # keep the pad waste bounded: halve the tile until the padding
-            # overhead drops to ~1/8, else take the least-wasteful candidate
-            cands = []
-            s = max(min(want, round_up(dim, unit)), unit)
-            while s >= unit:
-                cands.append(s)
-                s //= 2
-            waste = lambda s: round_up(dim, s) / dim - 1.0
-            t = next((s for s in cands if waste(s) <= 0.125),
-                     min(cands, key=waste))
-    if t < unit:
-        warnings.warn(
-            f"{kernel}: {name}={dim} forces tile {t} below the sublane "
-            f"width {unit}; expect poor lane utilization on this axis",
-            stacklevel=3)
-    return t, round_up(dim, t)
+    want = max(want // align * align, align)
+    dim_a = round_up(dim, align)
+    if dim_a <= want:
+        return dim_a, dim_a
+    for t in range(want, want // 2 - 1, -align):
+        if dim_a % t == 0:
+            return t, dim_a
+    n = -(-dim // want)
+    t = round_up(-(-dim // n), align)
+    return t, n * t

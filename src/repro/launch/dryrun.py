@@ -51,7 +51,7 @@ def _lower_compile(cell) -> Dict[str, Any]:
     compiled = lowered.compile()
     t2 = time.time()
     ma = compiled.memory_analysis()
-    ca = hlo_lib.cost_analysis_dict(compiled)
+    ca = compiled.cost_analysis()
     txt = compiled.as_text()
     coll = hlo_lib.collective_summary(txt)
     return {
@@ -154,7 +154,7 @@ def _cal_cost(arch, shape_name, mesh, scheme, mpd_mode, mpd_c,
                     out_shardings=(repl, cache_shard)
                     ).lower(params_sds, tok_sds, cache_sds).compile()
 
-    ca = hlo_lib.cost_analysis_dict(c)
+    ca = c.cost_analysis()
     return {"flops": float(ca.get("flops", 0.0)),
             "bytes": float(ca.get("bytes accessed", 0.0)),
             "L": n_layers, "T": seqlen}

@@ -32,19 +32,6 @@ _DTYPE_BYTES = {
 _SHAPE_RE = re.compile(r"(\w+)\[([\d,]*)\]")
 
 
-def cost_analysis_dict(compiled) -> Dict[str, float]:
-    """``compiled.cost_analysis()`` as a flat dict across jax versions.
-
-    Pre-0.5 jax returns a one-element list of per-program dicts; newer jax
-    returns the dict directly. Callers always want the dict (``.get("flops")``
-    etc.), so normalize here — the same API-drift family as the Pallas
-    ``TPUCompilerParams`` rename."""
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return ca or {}
-
-
 def shape_bytes(shape_str: str) -> int:
     """Bytes of one HLO shape string, incl. tuples: '(bf16[2,3], f32[4])'."""
     total = 0

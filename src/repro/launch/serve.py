@@ -90,31 +90,37 @@ def _static_main(args, cfg, model, params):
 
 
 def make_requests(cfg, *, n_requests, rate, prompt_len, gen, seed=0,
-                  shared_prefix=0):
+                  shared_prefix=0, n_shared=None, min_prompt_len=None,
+                  min_gen=None):
     """Synthetic Poisson request stream: exponential inter-arrivals at
-    ``rate`` req/s, prompt lengths in [prompt_len/2, prompt_len], output
-    budgets in [gen/2, gen]. ``shared_prefix`` forces the first that many
-    prompt tokens identical across requests (system-prompt shape), so the
-    paged engine's prefix trie gets real hits."""
+    ``rate`` req/s, prompt lengths in [min_prompt_len, prompt_len], output
+    budgets in [min_gen, gen] (the minima default to half the maxima).
+    ``shared_prefix`` forces the first that many prompt tokens identical
+    across the first ``n_shared`` requests (all by default; system-prompt
+    shape), so the paged engine's prefix trie gets real hits."""
     from repro.serve import Request, SamplingParams
 
     if rate <= 0:
         raise ValueError(f"arrival rate must be positive, got {rate}")
+    if min_prompt_len is None:
+        min_prompt_len = max(prompt_len // 2, 1)
+    if min_gen is None:
+        min_gen = max(gen // 2, 1)
     rng = np.random.default_rng(seed)
     data = SyntheticLM(vocab=cfg.vocab, seq_len=prompt_len,
                        global_batch=max(n_requests, 1), seed=seed)
     toks = np.asarray(data.next()["inputs"])
     if shared_prefix:
-        toks[:, :shared_prefix] = toks[0, :shared_prefix]
+        toks[:n_shared, :shared_prefix] = toks[0, :shared_prefix]
     t = 0.0
     out = []
     for i in range(n_requests):
         t += rng.exponential(1.0 / rate)
-        plen = int(rng.integers(max(prompt_len // 2, 1), prompt_len + 1))
+        plen = int(rng.integers(min_prompt_len, prompt_len + 1))
         plen = max(plen, min(shared_prefix, prompt_len))
         out.append(Request(
             id=i, prompt=toks[i, :plen],
-            max_new_tokens=int(rng.integers(max(gen // 2, 1), gen + 1)),
+            max_new_tokens=int(rng.integers(min_gen, gen + 1)),
             sampling=SamplingParams(temperature=0.0, seed=seed * 1000 + i),
             arrival_time=t))
     return out
@@ -175,10 +181,10 @@ def _build_resilience(args, *, chaos=True):
                       seed=args.chaos_seed)
 
 
-def _build_engine(args, model, params, *, chaos=True):
+def _build_engine(args, model, params, *, chaos=True, device=None):
     """Construct the continuous-batching engine from CLI flags. Shared by
-    the synthetic-stream driver and the ``--http`` frontend. Returns
-    ``(engine, mode_label)``."""
+    the synthetic-stream driver and the ``--http`` frontend. ``device``
+    places a router replica on one chip. Returns ``(engine, mode_label)``."""
     from repro.serve import Engine
 
     max_len = args.prompt_len + args.gen
@@ -193,11 +199,11 @@ def _build_engine(args, model, params, *, chaos=True):
                         n_pages=args.pages or None,
                         prefill_chunk_tokens=args.prefill_chunk or None,
                         spec_draft=spec_draft, spec_k=args.spec_k,
-                        resilience=res)
+                        resilience=res, device=device)
         mode = "paged+spec" if engine.spec_active else "paged"
     else:
         engine = Engine(model, params, n_slots=args.slots, max_len=max_len,
-                        resilience=res)
+                        resilience=res, device=device)
         mode = "continuous"
     return engine, mode
 
@@ -205,15 +211,20 @@ def _build_engine(args, model, params, *, chaos=True):
 def _build_serving(args, model, params, *, chaos=True):
     """One engine, or ``--replicas N`` of them behind a
     :class:`repro.serve.Router` — the facade is Engine-shaped either way,
-    so the stream driver and the HTTP frontend don't branch on it."""
-    engine, mode = _build_engine(args, model, params, chaos=chaos)
+    so the stream driver and the HTTP frontend don't branch on it.
+    Replica ``i`` lives on ``jax.devices()[i]`` (round-robin when there are
+    fewer devices than replicas); under ``--tp`` every replica spans the
+    one mesh instead."""
     if args.replicas <= 1:
-        return engine, mode
+        return _build_engine(args, model, params, chaos=chaos)
     from repro.serve import Router
 
-    engines = [engine]
-    for _ in range(args.replicas - 1):
-        engines.append(_build_engine(args, model, params, chaos=chaos)[0])
+    devices = jax.devices() if args.tp <= 1 else [None]
+    engines = []
+    for i in range(args.replicas):
+        engine, mode = _build_engine(args, model, params, chaos=chaos,
+                                     device=devices[i % len(devices)])
+        engines.append(engine)
     try:
         router = Router(engines, disagg=args.disagg,
                         n_prefill=args.n_prefill)
@@ -234,22 +245,33 @@ def _mesh_ctx(args):
     if args.tp <= 1:
         return contextlib.nullcontext()
     from repro.dist import sharding as sh
+    from repro.dist.mesh import make_mesh
 
     n_dev = len(jax.devices())
     if n_dev < args.tp:
         raise SystemExit(f"--tp {args.tp} needs {args.tp} devices, "
                          f"have {n_dev} (force host devices with "
                          f"XLA_FLAGS=--xla_force_host_platform_device_count)")
-    mesh = jax.make_mesh((args.tp,), ("model",))
+    mesh = make_mesh((args.tp,), ("model",))
     log.info("tensor parallel: %d-way model mesh over %s devices",
              args.tp, mesh.devices.size)
     return sh.use_mesh(mesh)
+
+
+def _warmup(engine, mode):
+    """Compile every program the engine will run before the first request,
+    so a lowering error stops startup instead of surfacing as a step fault
+    (or a dead replica) inside the serve loop's retry ladder."""
+    t0 = time.perf_counter()
+    engine.warmup()
+    log.info("%s: compiled in %.1f s", mode, time.perf_counter() - t0)
 
 
 def _continuous_main(args, cfg, model, params):
     from repro.kernels import ops
 
     engine, mode = _build_serving(args, model, params)
+    _warmup(engine, mode)
     # replica-count-agnostic reporting: a Router proxies metrics/summary;
     # per-engine internals (cache, prefill counters) read off replica 0
     eng0 = engine.replicas[0] if hasattr(engine, "replicas") else engine
@@ -329,6 +351,7 @@ def _http_main(args, cfg, model, params):
     from repro.serve import server as server_lib
 
     engine, mode = _build_serving(args, model, params)
+    _warmup(engine, mode)
     engine.metrics.clock = time.perf_counter
     log.info("http frontend over %s engine: %d slots, max_len %d",
              mode, engine.n_slots, engine.max_len)
@@ -422,7 +445,7 @@ def _load_model(args):
     return cfg, model, params
 
 
-def main(argv=None):
+def build_parser() -> argparse.ArgumentParser:
     p = argparse.ArgumentParser()
     p.add_argument("--arch", choices=ARCHS, required=True)
     p.add_argument("--smoke", action="store_true")
@@ -518,7 +541,14 @@ def main(argv=None):
                    "weights, nibble-packed at rest and unpacked to int8 at "
                    "deploy; scales stay f32); a quantized packed export "
                    "deploys its stored form automatically")
-    args = p.parse_args(argv)
+    return p
+
+
+def main(argv=None):
+    from repro.launch.compile_cache import enable_compile_cache
+
+    args = build_parser().parse_args(argv)
+    enable_compile_cache()
 
     logging.basicConfig(
         level=logging.INFO,

@@ -12,6 +12,8 @@ import jax
 from repro.configs.common import ARCHS, get_config
 from repro.data import SyntheticLM
 from repro.dist import sharding as sh
+from repro.dist.mesh import make_mesh
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import build
 from repro.optim import OptConfig
 from repro.train import TrainConfig, run
@@ -44,6 +46,7 @@ def main(argv=None):
     p.add_argument("--data-axis", type=int, default=0,
                    help="mesh data-axis size (0 = all devices)")
     args = p.parse_args(argv)
+    enable_compile_cache()
 
     over = {}
     if args.mpd_c:
@@ -73,7 +76,7 @@ def main(argv=None):
     n_data = args.data_axis or n_dev
     mesh = rules = None
     if n_dev > 1:
-        mesh = jax.make_mesh((n_data, n_dev // n_data), ("data", "model"))
+        mesh = make_mesh((n_data, n_dev // n_data), ("data", "model"))
         rules = sh.tp_rules()
     data = SyntheticLM(vocab=cfg.vocab, seq_len=args.seq_len,
                        global_batch=args.global_batch, seed=0)

@@ -132,7 +132,6 @@ def embed(params, ids):
         ways *= mesh.shape[a]
     # replicate ids when the (micro)batch doesn't divide the batch axes
     bspec = batch_axes if (batch_axes and ids.shape[0] % ways == 0) else None
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     def local(table, ids_l):
@@ -144,7 +143,7 @@ def embed(params, ids):
         vals = jnp.where(ok[..., None], vals, jnp.zeros((), table.dtype))
         return jax.lax.psum(vals, vax)
 
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(vax, None), P(bspec, None)),
         out_specs=P(bspec, None, None),

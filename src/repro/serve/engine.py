@@ -105,7 +105,7 @@ class Engine:
                  prefill_chunk_tokens: Optional[int] = None,
                  prefill_token_budget: Optional[int] = None,
                  spec_draft=None, spec_k: int = 4, preemption: bool = True,
-                 resilience: Optional[Resilience] = None):
+                 resilience: Optional[Resilience] = None, device=None):
         cfg = model.cfg
         if not cfg.causal:
             raise ValueError(f"{cfg.name}: encoder-only arch has no decode step")
@@ -126,6 +126,14 @@ class Engine:
         # shard_map closure it serves under (no first-request compile stall
         # per replica, no warm/serve program mismatch)
         self._mesh_ctx = dist_sh.current()
+        # one-chip replica placement: params, caches and sampling state are
+        # committed to ``device`` once built (below), and every step and
+        # warmup runs with it as the default device (see _mesh_scope), so
+        # per-step uploads land beside them
+        self.device = device
+        if device is not None and self._mesh_ctx[0] is not None:
+            raise ValueError("an engine is placed either on one device or "
+                             "on the active mesh, not both")
 
         # ---- resilience: the watchdog (per-step non-finite logit detection
         # + quarantine) is always on; the chaos injector and degradation
@@ -239,6 +247,16 @@ class Engine:
 
         self._decode = jax.jit(self._decode_impl)
         self._clear_slot = jax.jit(self._clear_slot_impl)
+
+        if device is not None:
+            self.params = jax.device_put(self.params, device)
+            self.cache.caches = jax.device_put(self.cache.caches, device)
+            if self.spec_active:
+                self.draft_params = jax.device_put(self.draft_params, device)
+                self.draft_cache.caches = jax.device_put(
+                    self.draft_cache.caches, device)
+            self._dev = jax.device_put(self._dev, device)
+            self._zero_poison = jax.device_put(self._zero_poison, device)
 
         # streaming hooks (the HTTP server wires these). token_cb fires for
         # every emitted token with its index in the request's output — a
@@ -607,6 +625,9 @@ class Engine:
         index 0 already streamed from the prefill replica."""
         h: Handoff = req.handoff
         assert h.prompt_len == len(req.prompt)
+        pages = h.pages
+        if self.device is not None:     # the payload left another replica
+            pages = jax.device_put(pages, self.device)
         matched = self.cache.admit_request(slot, req.prompt,
                                            req.max_new_tokens)
         # scatter ALL prompt pages, trie-matched ones included: the trie is
@@ -616,7 +637,7 @@ class Engine:
         ids = np.full((h.width,), NULL_PAGE, np.int32)
         ids[:h.n_pages] = self.cache.block_tables[slot][:h.n_pages]
         self.cache.caches = self._adopt(
-            self.cache.caches, h.pages, jnp.asarray(ids),
+            self.cache.caches, pages, jnp.asarray(ids),
             jnp.asarray(slot, jnp.int32),
             jnp.asarray(h.prompt_len, jnp.int32))
         sp = req.sampling
@@ -734,12 +755,15 @@ class Engine:
         return [w for w in self.decode_widths() if w >= w_min]
 
     def _mesh_scope(self):
-        """Re-enter the (mesh, rules) context captured at construction.
-        Every jit trace the engine triggers — warmup and the serve loop
-        alike — runs inside this scope, so the TP ``shard_map`` closure in
-        the paged attention ops resolves identically everywhere: warmup
-        compiles exactly the programs serving will run. Identity when the
-        engine was built without a mesh."""
+        """Re-enter the (mesh, rules) context captured at construction, or
+        the engine's device as JAX's default. Every jit trace the engine
+        triggers — warmup and the serve loop alike — runs inside this
+        scope, so the TP ``shard_map`` closure in the paged attention ops
+        resolves identically everywhere: warmup compiles exactly the
+        programs serving will run. Identity when the engine was built
+        without a mesh or device."""
+        if self.device is not None:
+            return jax.default_device(self.device)
         mesh, rules = self._mesh_ctx
         if mesh is None or rules is None:
             return contextlib.nullcontext()
@@ -760,6 +784,16 @@ class Engine:
         call time."""
         with self._mesh_scope():
             self._warmup_inner()
+
+    def lower_decode(self, width: int):
+        """The jitted paged decode step lowered at block-table width
+        ``width`` — the program serving runs, for inspecting what it
+        compiles to (e.g. that the Pallas kernels are in it)."""
+        with self._mesh_scope():
+            return self._decode_paged.lower(
+                self.params, self.cache.caches, self._dev,
+                jnp.zeros((self.n_slots, width), jnp.int32),
+                jnp.zeros((self.n_slots,), bool), self._zero_poison)
 
     def _warmup_inner(self) -> None:
         for w in self.decode_widths():

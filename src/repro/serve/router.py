@@ -51,6 +51,7 @@ decoding (the draft pool is not migrated).
 
 from __future__ import annotations
 
+import concurrent.futures
 import hashlib
 import logging
 import time
@@ -332,9 +333,17 @@ class Router:
                    for e, alive in zip(self.replicas, self.live))
 
     def warmup(self) -> None:
-        for e, alive in zip(self.replicas, self.live):
-            if alive:
-                e.warmup()
+        """Warm every live replica, all at once: each compiles the same
+        program ladder for its own device, and XLA compiles release the
+        GIL, so start-up costs about one replica's compile, not N.
+        Replicas placed on devices scope by JAX's thread-local default
+        device; replicas under one TP mesh all push the same (mesh, rules)
+        pair onto :mod:`repro.dist.sharding`'s context stack, so their
+        threads interleaving on it is harmless."""
+        live = [e for e, alive in zip(self.replicas, self.live) if alive]
+        with concurrent.futures.ThreadPoolExecutor(len(live)) as pool:
+            for f in [pool.submit(e.warmup) for e in live]:
+                f.result()
 
     def step(self) -> bool:
         """One pass over the live replicas, stepping each that has work.

@@ -144,14 +144,14 @@ def _run_ops(ops, caches, slack):
         assert c.reserved == 0
 
 
-@settings(max_examples=30)
+@settings(max_examples=30, deadline=None)  # first example builds a PagedCache (~0.3 s)
 @given(st.lists(st.integers(0, 1 << 30), min_size=10, max_size=60),
        st.integers(0, 4))
 def test_paged_cache_refcount_invariants(ops, slack):
     _run_ops(ops, [_mk_cache(slack=slack)], slack)
 
 
-@settings(max_examples=20)
+@settings(max_examples=20, deadline=None)
 @given(st.lists(st.integers(0, 1 << 30), min_size=10, max_size=60),
        st.integers(0, 4))
 def test_shared_trie_refcount_invariants(ops, slack):

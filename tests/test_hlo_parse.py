@@ -10,7 +10,8 @@ import jax, jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.launch import hlo as hlo_lib
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+from repro.dist.mesh import make_mesh
+mesh = make_mesh((2, 4), ("data", "model"))
 L, D = 6, 64
 w_sh = NamedSharding(mesh, P(None, None, "model"))
 x_sh = NamedSharding(mesh, P("data", None))

@@ -103,21 +103,35 @@ class TestTilePadding:
 
     def test_pick_tile_pads_prime_dim(self):
         from repro.kernels.tiling import pick_tile
-        tile, padded = pick_tile(131, 128)
-        assert tile >= 8 and padded % tile == 0 and padded >= 131
+        tile, padded = pick_tile(131, 128, 8)
+        assert tile % 8 == 0 and padded % tile == 0 and padded >= 131
+        assert (tile, padded) == (72, 144)    # 2 balanced tiles, not 128+128
 
     def test_pick_tile_exact_divisor_kept(self):
         from repro.kernels.tiling import pick_tile
-        assert pick_tile(130, 128) == (65, 130)   # divisor >= sublane wins
-        assert pick_tile(128, 128) == (128, 128)
+        assert pick_tile(192, 128, 8) == (96, 192)  # aligned divisor wins
+        assert pick_tile(128, 128, 8) == (128, 128)
+        assert pick_tile(6400, 512, 128) == (256, 6400)
 
-    def test_pick_tile_warns_below_sublane(self):
-        import warnings as w
-        from repro.kernels.tiling import pick_tile
-        with w.catch_warnings(record=True) as rec:
-            w.simplefilter("always")
-            pick_tile(3, 128)
-        assert any("sublane" in str(r.message) for r in rec)
+    @pytest.mark.parametrize("dim,want,dtype,rows,tile", [
+        # OLMo-1B packed unembed: bo = 50304/8 = 6288 lanes
+        (6288, 128, jnp.bfloat16, False, 128),
+        (6288, 512, jnp.bfloat16, False, 256),
+        # dtype sublane floors on the row axis (decode m = a few slots)
+        (3, 128, jnp.float32, True, 8),
+        (3, 128, jnp.bfloat16, True, 16),
+        (3, 128, jnp.int8, True, 32),
+        (40, 128, jnp.int8, True, 64),
+    ])
+    def test_tile_alignment(self, dim, want, dtype, rows, tile):
+        """Lane tiles are 128-multiples and row tiles dtype-sublane
+        multiples (8/16/32 rows for 4/2/1-byte types): a tile the TPU
+        lowering would refuse is never produced; the dim pads instead."""
+        from repro.kernels.tiling import LANE, pick_tile, sublane
+        align = sublane(dtype) if rows else LANE
+        t, padded = pick_tile(dim, want, align)
+        assert (t, t % align, padded % t) == (tile, 0, 0)
+        assert dim <= padded < dim + (padded // t) * align
 
     def test_bdmm_prime_m_matches_oracle(self):
         # m=131 used to degrade to a 131-step tile-1 grid

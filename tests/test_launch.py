@@ -15,9 +15,10 @@ try:
     make_production_mesh()
 except ValueError as e:
     assert "requires" in str(e) or "devices" in str(e)
-m = jax.make_mesh((2, 4), ("data", "model"))
+from repro.dist.mesh import make_mesh
+m = make_mesh((2, 4), ("data", "model"))
 assert data_axes(m) == ("data",)
-m2 = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+m2 = make_mesh((2, 2, 2), ("pod", "data", "model"))
 assert data_axes(m2) == ("pod", "data")
 print("OK")
 """)
@@ -32,7 +33,8 @@ from repro.launch import specs as specs_lib
 from repro.launch import hlo as hlo_lib
 
 # shrink a shape + arch so the cell compiles on 8 host devices
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+from repro.dist.mesh import make_mesh
+mesh = make_mesh((2, 4), ("data", "model"))
 cc.SHAPES = dict(cc.SHAPES)
 cc.SHAPES["train_4k"] = dataclasses.replace(SHAPES["train_4k"],
                                             seq_len=64, global_batch=8)
@@ -51,7 +53,7 @@ c = jax.jit(cell.fn, in_shardings=cell.in_shardings,
             out_shardings=cell.out_shardings).lower(*cell.args_sds).compile()
 ma = c.memory_analysis()
 assert ma.temp_size_in_bytes > 0
-ca = hlo_lib.cost_analysis_dict(c)  # list-of-dict on pre-0.5 jax
+ca = c.cost_analysis()
 assert ca.get("flops", 0) > 0
 coll = hlo_lib.collective_summary(c.as_text())
 assert coll.get("total", 0) > 0  # DP grad sync must appear
@@ -68,7 +70,8 @@ from repro.configs.common import SHAPES
 from repro.launch import specs as specs_lib
 from repro.launch import hlo as hlo_lib
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+from repro.dist.mesh import make_mesh
+mesh = make_mesh((2, 4), ("data", "model"))
 cc.SHAPES = dict(cc.SHAPES)
 cc.SHAPES["train_4k"] = dataclasses.replace(SHAPES["train_4k"],
                                             seq_len=128, global_batch=8)

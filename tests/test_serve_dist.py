@@ -81,7 +81,8 @@ import jax, jax.numpy as jnp, numpy as np
 from repro.dist import sharding as sh
 from repro.kernels import ops
 
-mesh = jax.make_mesh((2,), ("model",))
+from repro.dist.mesh import make_mesh
+mesh = make_mesh((2,), ("model",))
 
 def pools(kh, dh=8, n_pages=6, ps=4, seed=0):
     ks = jax.random.split(jax.random.PRNGKey(seed), 2)
@@ -160,7 +161,8 @@ for kwargs in (dict(n_heads=4, n_kv_heads=4), dict(n_heads=4, n_kv_heads=2)):
     m = build(cfg)
     p = m.init(jax.random.PRNGKey(0))
     base = run(m, p, None)
-    mesh = jax.make_mesh((2,), ("model",))
+    from repro.dist.mesh import make_mesh
+    mesh = make_mesh((2,), ("model",))
     tp = run(m, p, mesh)
     assert tp == base, (kwargs, tp, base)
 print("OK")
@@ -200,6 +202,58 @@ def test_router_matches_single_engine_tokens():
     base = _run(_engine(), _requests(6, seed=1))
     got = _run(Router([_engine(), _engine()]), reqs)
     assert got == base
+
+
+def test_router_warmup_compiles_every_replica():
+    """Router.warmup warms all replicas (concurrently): serving afterwards
+    compiles no decode or prefill-chunk program on any of them."""
+    engines = [_engine(), _engine()]
+    r = Router(engines)
+    r.warmup()
+
+    def sizes():
+        return [(e._decode_paged._cache_size(), e._chunk._cache_size())
+                for e in engines]
+
+    warm = sizes()
+    assert warm == [(len(e.decode_widths()), 2 * len(e.prefill_widths()))
+                    for e in engines]
+    _run(r, _requests(6, seed=3))
+    assert sizes() == warm
+
+
+def test_replicas_placed_one_per_device():
+    """``--replicas 4`` on four devices: replica i's params and KV pages
+    live on ``jax.devices()[i]``, and the fleet's greedy tokens equal one
+    engine's."""
+    _run_subprocess("""
+import jax
+from repro.launch import serve
+
+flags = ["--arch", "olmo-1b", "--smoke", "--paged", "--page-size", "8",
+         "--slots", "2", "--requests", "6", "--rate", "50",
+         "--prompt-len", "16", "--gen", "4"]
+
+def tokens(engine, args, cfg):
+    reqs = serve.make_requests(cfg, n_requests=args.requests, rate=args.rate,
+                               prompt_len=args.prompt_len, gen=args.gen)
+    serve.serve_stream(engine, reqs)
+    return [list(r.generated) for r in reqs]
+
+args = serve.build_parser().parse_args(flags + ["--replicas", "4"])
+cfg, model, params = serve._load_model(args)
+router, _ = serve._build_serving(args, model, params, chaos=False)
+for i, eng in enumerate(router.replicas):
+    held = {d for leaf in jax.tree.leaves((eng.params, eng.cache.caches))
+            for d in leaf.devices()}
+    assert held == {jax.devices()[i]}, (i, held)
+router.warmup()
+got = tokens(router, args, cfg)
+one, _ = serve._build_serving(serve.build_parser().parse_args(flags),
+                              model, params, chaos=False)
+assert got == tokens(one, args, cfg)
+print("OK")
+""", n_devices=4)
 
 
 def test_router_disagg_handoff_token_identical():

@@ -97,8 +97,8 @@ def test_synthetic_lm_learnable_structure():
     """The hidden Markov chain must make next-token prediction beat chance."""
     d = SyntheticLM(vocab=32, seq_len=64, global_batch=4, seed=0)
     b = d.next()
-    # oracle: labels[:, t] = trans[inputs[:, t-1], inputs[:, t]] (90% of the time)
-    pred = d._trans[b["inputs"][:, :-1], b["inputs"][:, 1:]]
+    # oracle: labels[:, t] = transition(inputs[:, t-1], inputs[:, t]) (90% of the time)
+    pred = d.transition(b["inputs"][:, :-1], b["inputs"][:, 1:])
     acc = float(np.mean(pred == b["labels"][:, 1:]))
     assert acc > 0.5  # noise level is 10%
 
@@ -272,7 +272,8 @@ import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import Mesh
 from repro.dist.pipeline import gpipe_forward
 
-mesh = jax.make_mesh((4, 2), ("pipe", "data"))
+from repro.dist.mesh import make_mesh
+mesh = make_mesh((4, 2), ("pipe", "data"))
 S, M, mb, d = 4, 6, 2, 8
 ws = jax.random.normal(jax.random.PRNGKey(0), (S, d, d)) / np.sqrt(d)
 xs = jax.random.normal(jax.random.PRNGKey(1), (M, mb, d))
@@ -300,7 +301,8 @@ from repro.train import TrainConfig, make_train_step
 from repro.optim import OptConfig, init_state
 from repro.dist import sharding as sh
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+from repro.dist.mesh import make_mesh
+mesh = make_mesh((2, 4), ("data", "model"))
 rules = sh.tp_rules()
 cfg = ModelConfig(n_layers=2, d_model=64, n_heads=4, n_kv_heads=4, d_ff=128,
                   vocab=64, mpd_c=4, q_chunk=1024)
