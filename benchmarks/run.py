@@ -19,7 +19,6 @@ Prints ``name,value,derived`` CSV rows. Sections:
                  decode tok/s vs the non-spec paged baseline, draft
                  acceptance, tokens/step, shared-trie prefix reuse
                  (BENCH_spec.json)
-  * roofline,* — per-cell roofline terms from the dry-run sweep (if present)
 
 ``--fast`` trims step counts for CI-style runs; the full run reproduces the
 numbers quoted in EXPERIMENTS.md.
@@ -33,7 +32,6 @@ than ``--check-threshold`` (default 25%) exits non-zero. See
 """
 
 import argparse
-import os
 import sys
 
 
@@ -41,10 +39,9 @@ def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--fast", action="store_true",
                     help="fewer train steps / masks (smoke-level)")
-    ap.add_argument("--skip-roofline", action="store_true")
     ap.add_argument("--sections", default="",
                     help="comma list: table1,fig4,fig5,speedup,kernels,"
-                         "serve,fused,quant,paged,spec,roofline")
+                         "serve,fused,quant,paged,spec")
     ap.add_argument("--check", action="store_true",
                     help="regression gate: re-run sections fresh and fail "
                          "if a headline drops >threshold vs the committed "
@@ -98,11 +95,6 @@ def main() -> None:
         rows += spec_bench.rows(smoke=args.fast)
     for r in rows:
         print(r)
-
-    if not args.skip_roofline and on("roofline") and os.path.isdir("results/dryrun"):
-        from benchmarks import roofline
-        for r in roofline.table("results/dryrun"):
-            print(r)
 
 
 if __name__ == "__main__":

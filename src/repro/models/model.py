@@ -105,6 +105,18 @@ class ModelConfig:
         return {"float32": jnp.float32, "bfloat16": jnp.bfloat16}[self.dtype]
 
 
+def scan_layers(body, x, xs):
+    """``lax.scan`` of ``body`` over stacked layers, under the named scopes
+    a device trace reads: every op carries ``layer_scan``, and the body's
+    own ops ``layer`` inside it, so the ops the scan adds to move each
+    layer's parameters and cache in and out are those without ``layer``."""
+    def scoped(carry, layer):
+        with jax.named_scope("layer"):
+            return body(carry, layer)
+    with jax.named_scope("layer_scan"):
+        return jax.lax.scan(scoped, x, xs)
+
+
 class Model:
     """Functional model: static specs here, params as plain pytrees."""
 
@@ -487,7 +499,7 @@ class Model:
                                            block_tables=block_tables,
                                            live=live)
                 return x, c2
-            x, c_new = jax.lax.scan(body, x, (pstack, cstack))
+            x, c_new = scan_layers(body, x, (pstack, cstack))
             new_caches.append(c_new)
         x = layers.apply_norm(cfg.norm, params["final_norm"], x)
         lg = self.unembed.apply(params["unembed"], x[:, 0])
@@ -549,7 +561,7 @@ class Model:
                 else:
                     y2 = spec["ffn"].apply(p["ffn"], h2)
                 return x + y2, c2
-            x, c_new = jax.lax.scan(body, x, (pstack, cstack))
+            x, c_new = scan_layers(body, x, (pstack, cstack))
             new_caches.append(c_new)
         x = layers.apply_norm(cfg.norm, params["final_norm"], x)
         lg = self.unembed.apply(params["unembed"], x)
@@ -641,7 +653,7 @@ class Model:
                     y2 = spec["ffn"].apply(p["ffn"], h2)
                 return x + y2, c2
 
-            x, c_new = jax.lax.scan(body, x, (pstack, cstack))
+            x, c_new = scan_layers(body, x, (pstack, cstack))
             new_caches.append(c_new)
         if not final:
             return None, new_caches

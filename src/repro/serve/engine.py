@@ -69,6 +69,14 @@ from .scheduler import Request, RequestState, Scheduler
 
 log = logging.getLogger("repro.serve.engine")
 
+# Host spans the engine writes into a profiler trace, on the device ops'
+# clock: one per step or per request event, never per slot or per token.
+# With no profiler running each costs one activity check.
+SPANS = ("engine.step", "engine.admit", "engine.prefill.chunk",
+         "engine.prefill.sync", "engine.decode.prep", "engine.decode.dispatch",
+         "engine.decode.sync", "engine.emit")
+_span = jax.profiler.TraceAnnotation
+
 
 def _next_pow2(n: int) -> int:
     return 1 << max(n - 1, 0).bit_length()
@@ -670,63 +678,77 @@ class Engine:
             plen = len(req.prompt)
             tc = self.chunk_tokens
             n_real = min(plen - pos, tc)
-            toks = np.zeros((1, tc), np.int32)
-            toks[0, :n_real] = req.prompt[pos:pos + n_real]
-            # the chunk attends over [0, pos + tc): hand it only that many
-            # block-table columns (power-of-two ladder, like decode), so
-            # chunk attention reads context proportional to actual depth
-            ctx_pages = min(_next_pow2(self.cache.pages_for(pos + tc)),
-                            self.cache.max_pages)
-            sp = req.sampling
-            final = pos + n_real >= plen
-            tok_dev, self.cache.caches, self._dev = self._chunk(
-                self.params, self.cache.caches, self._dev, jnp.asarray(toks),
-                jnp.asarray(self.cache.block_tables[req.slot][:ctx_pages]),
-                jnp.asarray(slot, jnp.int32), jnp.asarray(pos, jnp.int32),
-                jnp.asarray(n_real, jnp.int32),
-                jnp.asarray(sp.temperature, jnp.float32),
-                jnp.asarray(sp.top_k, jnp.int32),
-                sampling_lib.base_key(sp.seed), final=final)
-            # KV bytes the chunk's attention read: the flash kernel streams
-            # only pages at/below the causal horizon (∝ actual depth); the
-            # jnp gather path reads the whole laddered table width
-            if ops.prefill_backend() == "jnp":
-                pages_read = ctx_pages
-            else:
-                pages_read = min(self.cache.pages_for(pos + n_real), ctx_pages)
-            self.metrics.on_prefill_kv_read(
-                int(pages_read * self.cache.page_size
-                    * self.cache.token_bytes))
-            if self.spec_active:
-                # mirror the chunk into the draft's page pool (one extra
-                # dispatch; its logits are discarded — the target samples)
-                dctx = min(_next_pow2(self.draft_cache.pages_for(pos + tc)),
-                           self.draft_cache.max_pages)
-                self.draft_cache.caches = self._chunk_draft(
-                    self.draft_params, self.draft_cache.caches,
+            with _span("engine.prefill.chunk", req=req.id, pos=pos,
+                       n=n_real):
+                toks = np.zeros((1, tc), np.int32)
+                toks[0, :n_real] = req.prompt[pos:pos + n_real]
+                # the chunk attends over [0, pos + tc): hand it only that
+                # many block-table columns (power-of-two ladder, like
+                # decode), so chunk attention reads context proportional to
+                # actual depth
+                ctx_pages = min(_next_pow2(self.cache.pages_for(pos + tc)),
+                                self.cache.max_pages)
+                sp = req.sampling
+                final = pos + n_real >= plen
+                tok_dev, self.cache.caches, self._dev = self._chunk(
+                    self.params, self.cache.caches, self._dev,
                     jnp.asarray(toks),
-                    jnp.asarray(self.draft_cache.block_tables[slot][:dctx]),
+                    jnp.asarray(self.cache.block_tables[req.slot][:ctx_pages]),
                     jnp.asarray(slot, jnp.int32), jnp.asarray(pos, jnp.int32),
-                    jnp.asarray(n_real, jnp.int32))
-            req.prefill_pos = pos + n_real
-            self.n_prefill_chunks += 1
-            self.n_prefill_tokens += n_real
-            self.metrics.on_prefill_tokens(n_real)
-            budget -= tc
-            ran = True
-            # the chunk's full prompt pages now hold real K/V -> shareable
-            if self.spec_active:
-                publish_prefix_shared([self.cache, self.draft_cache],
-                                      req.prompt, slot, req.prefill_pos,
-                                      from_tokens=pos)
-            else:
-                self.cache.publish_prefix(req.prompt, slot, req.prefill_pos,
+                    jnp.asarray(n_real, jnp.int32),
+                    jnp.asarray(sp.temperature, jnp.float32),
+                    jnp.asarray(sp.top_k, jnp.int32),
+                    sampling_lib.base_key(sp.seed), final=final)
+                # KV bytes the chunk's attention read: the flash kernel
+                # streams only pages at/below the causal horizon (∝ actual
+                # depth); the jnp gather path reads the whole laddered table
+                # width
+                if ops.prefill_backend() == "jnp":
+                    pages_read = ctx_pages
+                else:
+                    pages_read = min(self.cache.pages_for(pos + n_real),
+                                     ctx_pages)
+                self.metrics.on_prefill_kv_read(
+                    int(pages_read * self.cache.page_size
+                        * self.cache.token_bytes))
+                if self.spec_active:
+                    # mirror the chunk into the draft's page pool (one extra
+                    # dispatch; its logits are discarded — the target
+                    # samples)
+                    dctx = min(
+                        _next_pow2(self.draft_cache.pages_for(pos + tc)),
+                        self.draft_cache.max_pages)
+                    self.draft_cache.caches = self._chunk_draft(
+                        self.draft_params, self.draft_cache.caches,
+                        jnp.asarray(toks),
+                        jnp.asarray(
+                            self.draft_cache.block_tables[slot][:dctx]),
+                        jnp.asarray(slot, jnp.int32),
+                        jnp.asarray(pos, jnp.int32),
+                        jnp.asarray(n_real, jnp.int32))
+                req.prefill_pos = pos + n_real
+                self.n_prefill_chunks += 1
+                self.n_prefill_tokens += n_real
+                self.metrics.on_prefill_tokens(n_real)
+                budget -= tc
+                ran = True
+                # the chunk's full prompt pages now hold real K/V ->
+                # shareable
+                if self.spec_active:
+                    publish_prefix_shared([self.cache, self.draft_cache],
+                                          req.prompt, slot, req.prefill_pos,
                                           from_tokens=pos)
+                else:
+                    self.cache.publish_prefix(req.prompt, slot,
+                                              req.prefill_pos,
+                                              from_tokens=pos)
             if req.prefill_pos >= plen:
                 self._prefill_queue.popleft()
                 self._live[slot] = True
                 req.state = RequestState.DECODE
-                self._emit(req, int(tok_dev))
+                with _span("engine.prefill.sync", req=req.id):
+                    tok = int(tok_dev)
+                self._emit(req, tok)
         return ran
 
     def decode_widths(self) -> List[int]:
@@ -1090,7 +1112,8 @@ class Engine:
         t0 = time.perf_counter()
         res.begin_step(self.step_count)
         try:
-            with self._mesh_scope():
+            with _span("engine.step", step=self.step_count), \
+                    self._mesh_scope():
                 return self._step_inner()
         finally:
             res.end_step(time.perf_counter() - t0)
@@ -1113,24 +1136,29 @@ class Engine:
                     page_blocked = True     # pressure signal for the ladder
                 return ok
             admitted = []
-            while True:
-                pairs = self.scheduler.admit(can_admit=_can, max_n=1,
-                                             eligible=self._retry_eligible)
-                if pairs:
-                    self._admit_one_paged(*pairs[0])
-                    admitted += pairs
-                    continue
-                # head blocked (slot or page pressure): preempt the
-                # lowest-priority running request if it outranks, then
-                # retry — each eviction returns capacity the predicate
-                # re-checks
-                if not self._preempt_for_head():
-                    break
+            with _span("engine.admit") as span:
+                while True:
+                    pairs = self.scheduler.admit(
+                        can_admit=_can, max_n=1,
+                        eligible=self._retry_eligible)
+                    if pairs:
+                        self._admit_one_paged(*pairs[0])
+                        admitted += pairs
+                        continue
+                    # head blocked (slot or page pressure): preempt the
+                    # lowest-priority running request if it outranks, then
+                    # retry — each eviction returns capacity the predicate
+                    # re-checks
+                    if not self._preempt_for_head():
+                        break
+                span.set_metadata(n=len(admitted))
             prefilled = self._prefill_chunks()
         else:
-            admitted = self.scheduler.admit(eligible=self._retry_eligible)
-            for req, slot in admitted:
-                self._admit_one(req, slot)
+            with _span("engine.admit") as span:
+                admitted = self.scheduler.admit(eligible=self._retry_eligible)
+                for req, slot in admitted:
+                    self._admit_one(req, slot)
+                span.set_metadata(n=len(admitted))
             prefilled = False
         self.step_count += 1
         self.metrics.on_queue_depth(len(self.scheduler.waiting))
@@ -1146,63 +1174,73 @@ class Engine:
 
         res = self.resilience
         if self.paged:
-            # materialize this step's write pages and size the active
-            # block-table width to the deepest live sequence
-            needed = 1
-            wpos_arr = np.zeros((self.n_slots,), np.int32)
-            for slot in np.nonzero(self._live)[0]:
-                req = self.scheduler.running.get(int(slot))
-                if req is None:
-                    continue
-                wpos = self._kv_len(req)
-                wpos_arr[slot] = wpos
-                self.cache.ensure_decode_page(int(slot), wpos)
-                needed = max(needed, self.cache.pages_used(int(slot),
-                                                           wpos + 1))
-            width = min(_next_pow2(needed), self.cache.max_pages)
-            bt = self._block_tables_dev(width)
-            if self.spec_active:
-                # suspended-spec interlude: verify leaves cache ``pos`` at
-                # the window entry depth, so the device counter the plain
-                # decode trusts is stale after a spec step — resync it to
-                # the host-authoritative accepted depth or this step writes
-                # K/V over accepted positions
-                self.cache.caches = self.model.set_paged_pos(
-                    self.cache.caches, jnp.asarray(wpos_arr))
-            # live mask is load-bearing: mid-prefill slots hold real block
-            # tables + carried state that an unmasked decode would corrupt
+            live_slots = np.nonzero(self._live)[0]
+            with _span("engine.decode.prep") as span:
+                # materialize this step's write pages and size the active
+                # block-table width to the deepest live sequence
+                needed = 1
+                wpos_arr = np.zeros((self.n_slots,), np.int32)
+                for slot in live_slots:
+                    req = self.scheduler.running.get(int(slot))
+                    if req is None:
+                        continue
+                    wpos = self._kv_len(req)
+                    wpos_arr[slot] = wpos
+                    self.cache.ensure_decode_page(int(slot), wpos)
+                    needed = max(needed, self.cache.pages_used(int(slot),
+                                                               wpos + 1))
+                width = min(_next_pow2(needed), self.cache.max_pages)
+                span.set_metadata(live=len(live_slots), width=width)
+                bt = self._block_tables_dev(width)
+                # live mask is load-bearing: mid-prefill slots hold real
+                # block tables + carried state that an unmasked decode
+                # would corrupt
+                live = self._live_mask_dev()
+                if self.spec_active:
+                    # suspended-spec interlude: verify leaves cache ``pos``
+                    # at the window entry depth, so the device counter the
+                    # plain decode trusts is stale after a spec step —
+                    # resync it to the host-authoritative accepted depth or
+                    # this step writes K/V over accepted positions
+                    self.cache.caches = self.model.set_paged_pos(
+                        self.cache.caches, jnp.asarray(wpos_arr))
             try:
-                if res.injector is not None:
-                    res.injector.check("engine_step")
-                self._dev, self.cache.caches, ok_dev = self._decode_paged(
-                    self.params, self.cache.caches, self._dev, bt,
-                    self._live_mask_dev(), self._poison_dev("decode_logits"))
+                with _span("engine.decode.dispatch"):
+                    if res.injector is not None:
+                        res.injector.check("engine_step")
+                    self._dev, self.cache.caches, ok_dev = self._decode_paged(
+                        self.params, self.cache.caches, self._dev, bt, live,
+                        self._poison_dev("decode_logits"))
             except Exception as e:          # noqa: BLE001 — bounded retry
                 return self._handle_step_fault(e)
         else:
             try:
-                if res.injector is not None:
-                    res.injector.check("engine_step")
-                self._dev, self.cache.caches, ok_dev = self._decode(
-                    self.params, self.cache.caches, self._dev,
-                    self._poison_dev("decode_logits"))
+                with _span("engine.decode.dispatch"):
+                    if res.injector is not None:
+                        res.injector.check("engine_step")
+                    self._dev, self.cache.caches, ok_dev = self._decode(
+                        self.params, self.cache.caches, self._dev,
+                        self._poison_dev("decode_logits"))
             except Exception as e:          # noqa: BLE001 — bounded retry
                 return self._handle_step_fault(e)
         res.consecutive_step_faults = 0
-        next_np = np.asarray(self._dev["tokens"])
-        ok_np = np.asarray(ok_dev)
+        with _span("engine.decode.sync"):
+            next_np = np.asarray(self._dev["tokens"])
+            ok_np = np.asarray(ok_dev)
 
         self.metrics.on_step(int(self._live.sum()), self.n_slots)
         self._report_kv()
-        for slot in np.nonzero(self._live)[0]:
-            req = self.scheduler.running.get(int(slot))
-            if req is None:
-                continue
-            if not ok_np[slot]:
-                self._quarantine(req)
-                continue
-            self.metrics.on_decode_step(req.id, 1)
-            self._emit(req, int(next_np[slot]))
+        live_slots = np.nonzero(self._live)[0]
+        with _span("engine.emit", n=len(live_slots)):
+            for slot in live_slots:
+                req = self.scheduler.running.get(int(slot))
+                if req is None:
+                    continue
+                if not ok_np[slot]:
+                    self._quarantine(req)
+                    continue
+                self.metrics.on_decode_step(req.id, 1)
+                self._emit(req, int(next_np[slot]))
         return True
 
     def _step_spec(self) -> bool:
@@ -1212,72 +1250,80 @@ class Engine:
         stop checks anywhere inside the accepted window, and roll both
         caches back to the accepted depth."""
         k = self.spec_k
-        pos0 = np.zeros((self.n_slots,), np.int32)
-        needed = 1
-        for slot in np.nonzero(self._live)[0]:
-            req = self.scheduler.running.get(int(slot))
-            if req is None:
-                continue
-            wpos = self._kv_len(req)
-            pos0[slot] = wpos
-            # target writes window positions wpos..wpos+k; the draft only
-            # wpos..wpos+k-1 — materialize each range against the slack
-            # reservation
-            for t in range(k + 1):
-                self.cache.ensure_decode_page(int(slot), wpos + t)
-                if t < k:
-                    self.draft_cache.ensure_decode_page(int(slot), wpos + t)
-            needed = max(needed, self.cache.pages_used(int(slot),
-                                                       wpos + k + 1))
-        width = min(_next_pow2(needed), self.cache.max_pages)
-        bt = self._block_tables_dev(width)
-        dbt = self._draft_block_tables_dev(width)
-        live = self._live_mask_dev()
-        pos0_dev = jnp.asarray(pos0)
+        live_slots = np.nonzero(self._live)[0]
+        with _span("engine.decode.prep") as span:
+            pos0 = np.zeros((self.n_slots,), np.int32)
+            needed = 1
+            for slot in live_slots:
+                req = self.scheduler.running.get(int(slot))
+                if req is None:
+                    continue
+                wpos = self._kv_len(req)
+                pos0[slot] = wpos
+                # target writes window positions wpos..wpos+k; the draft
+                # only wpos..wpos+k-1 — materialize each range against the
+                # slack reservation
+                for t in range(k + 1):
+                    self.cache.ensure_decode_page(int(slot), wpos + t)
+                    if t < k:
+                        self.draft_cache.ensure_decode_page(int(slot),
+                                                            wpos + t)
+                needed = max(needed, self.cache.pages_used(int(slot),
+                                                           wpos + k + 1))
+            width = min(_next_pow2(needed), self.cache.max_pages)
+            span.set_metadata(live=len(live_slots), width=width)
+            bt = self._block_tables_dev(width)
+            dbt = self._draft_block_tables_dev(width)
+            live = self._live_mask_dev()
+            pos0_dev = jnp.asarray(pos0)
 
         res = self.resilience
         try:
-            if res.injector is not None:
-                res.injector.check("engine_step")
-            # propose-then-verify retries as a unit: a fault after the
-            # draft assignment only leaves rewritten draft window pages,
-            # which the re-run re-scatters with identical values
-            draft_toks, draft_q, self.draft_cache.caches = self._propose(
-                self.draft_params, self.draft_cache.caches, self._dev, dbt,
-                live, pos0_dev, self._poison_dev("draft_logits"))
-            self._dev, self.cache.caches, out_dev, n_acc_dev, ok_dev = \
-                self._verify(self.params, self.cache.caches, self._dev, bt,
-                             live, pos0_dev, draft_toks, draft_q,
-                             self._poison_dev("decode_logits"))
+            with _span("engine.decode.dispatch"):
+                if res.injector is not None:
+                    res.injector.check("engine_step")
+                # propose-then-verify retries as a unit: a fault after the
+                # draft assignment only leaves rewritten draft window pages,
+                # which the re-run re-scatters with identical values
+                draft_toks, draft_q, self.draft_cache.caches = self._propose(
+                    self.draft_params, self.draft_cache.caches, self._dev,
+                    dbt, live, pos0_dev, self._poison_dev("draft_logits"))
+                self._dev, self.cache.caches, out_dev, n_acc_dev, ok_dev = \
+                    self._verify(self.params, self.cache.caches, self._dev,
+                                 bt, live, pos0_dev, draft_toks, draft_q,
+                                 self._poison_dev("decode_logits"))
         except Exception as e:              # noqa: BLE001 — bounded retry
             return self._handle_step_fault(e)
         res.consecutive_step_faults = 0
-        out_np = np.asarray(out_dev)
-        n_acc_np = np.asarray(n_acc_dev)
-        ok_np = np.asarray(ok_dev)
+        with _span("engine.decode.sync"):
+            out_np = np.asarray(out_dev)
+            n_acc_np = np.asarray(n_acc_dev)
+            ok_np = np.asarray(ok_dev)
 
         self.metrics.on_step(int(self._live.sum()), self.n_slots)
         self._report_kv()
-        for slot in np.nonzero(self._live)[0]:
-            req = self.scheduler.running.get(int(slot))
-            if req is None:
-                continue
-            if not ok_np[slot]:
-                self._quarantine(req)
-                continue
-            n = int(n_acc_np[slot])
-            self.metrics.on_decode_step(req.id, n + 1, n_proposed=k,
-                                        n_accepted=n)
-            for i in range(n + 1):
-                self._emit(req, int(out_np[slot, i]))
-                if req.state == RequestState.DONE:
-                    break           # EOS/max inside the window: drop the rest
-            if req.state != RequestState.DONE:
-                # truncate both block tables to the accepted depth — pages
-                # past it hold rejected-window K/V (re-ensured next step)
-                keep = self._kv_len(req)
-                self.cache.rollback(int(slot), keep)
-                self.draft_cache.rollback(int(slot), keep)
+        with _span("engine.emit", n=len(live_slots)):
+            for slot in live_slots:
+                req = self.scheduler.running.get(int(slot))
+                if req is None:
+                    continue
+                if not ok_np[slot]:
+                    self._quarantine(req)
+                    continue
+                n = int(n_acc_np[slot])
+                self.metrics.on_decode_step(req.id, n + 1, n_proposed=k,
+                                            n_accepted=n)
+                for i in range(n + 1):
+                    self._emit(req, int(out_np[slot, i]))
+                    if req.state == RequestState.DONE:
+                        break       # EOS/max inside the window: drop the rest
+                if req.state != RequestState.DONE:
+                    # truncate both block tables to the accepted depth —
+                    # pages past it hold rejected-window K/V (re-ensured
+                    # next step)
+                    keep = self._kv_len(req)
+                    self.cache.rollback(int(slot), keep)
+                    self.draft_cache.rollback(int(slot), keep)
         return True
 
     def run(self, requests: Sequence[Request],

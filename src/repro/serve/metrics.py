@@ -4,6 +4,12 @@ The engine reports events through :class:`ServeMetrics` with an injectable
 clock (tests pass a fake; production uses ``time.perf_counter``). Nothing
 here touches the device.
 
+Compiles: one process-wide listener on JAX's backend-compile event keeps
+each compile's end time (``time.perf_counter``) in :data:`COMPILE_TIMES`,
+so a serving window can be checked for compiles inside it;
+:attr:`ServeMetrics.n_compiles` counts those since the metrics object was
+made.
+
 SLO observability: requests carry optional TTFT / end-to-end deadline
 annotations and a priority class; :meth:`ServeMetrics.summary` reports
 per-class latency percentiles and SLO *attainment* (fraction of finished
@@ -19,7 +25,22 @@ import math
 import time
 from typing import Callable, Dict, List, Optional
 
+import jax.monitoring
+
 PRIORITY_CLASSES = ("interactive", "batch")
+
+# JAX records this around every executable it builds (a compile or a
+# persistent-cache load)
+BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+COMPILE_TIMES: List[float] = []
+
+
+def _on_compile(event: str, duration_secs: float, **kwargs) -> None:
+    if event == BACKEND_COMPILE_EVENT:
+        COMPILE_TIMES.append(time.perf_counter())
+
+
+jax.monitoring.register_event_duration_secs_listener(_on_compile)
 
 
 @dataclasses.dataclass
@@ -137,6 +158,13 @@ class ServeMetrics:
         self.n_step_faults = 0                # engine-step exceptions caught
         self.degradation_stage = 0
         self.degradation_transitions = 0
+        self._compiles_at_start = len(COMPILE_TIMES)
+
+    @property
+    def n_compiles(self) -> int:
+        """Executables JAX built in this process since these metrics were
+        made."""
+        return len(COMPILE_TIMES) - self._compiles_at_start
 
     # ---------------------------------------------------------------- events
     def on_submit(self, req_id: int, n_prompt: int,
@@ -436,6 +464,10 @@ class ServeMetrics:
         metric("repro_serve_degradation_transitions_total", "counter",
                "Degradation-ladder stage transitions.",
                [({}, self.degradation_transitions)])
+        metric("repro_serve_compiles_total", "counter",
+               "Executables built (compiled or loaded from the persistent "
+               "cache) in this process since the engine started; one "
+               "while serving is a stall.", [({}, self.n_compiles)])
         metric("repro_serve_slo_attainment", "gauge",
                "Fraction of finished deadline-carrying requests that met "
                "their deadline (1.0 when none carry one).",
@@ -587,6 +619,11 @@ class RouterMetrics:
         aggregate tok/s with the exact same schema as one engine."""
         out = ServeMetrics(clock=self._clock)
         out.requests = self.requests
+        # compiles are counted per process, not per replica: the fleet's
+        # count runs from its first replica's start
+        out._compiles_at_start = min(
+            (m._compiles_at_start for m in self.replicas),
+            default=out._compiles_at_start)
         for m in self.replicas:
             if m.t_start is not None:
                 out.t_start = (m.t_start if out.t_start is None
@@ -636,12 +673,17 @@ class RouterMetrics:
     def families(self, extra_gauges: Optional[Dict[str, float]] = None
                  ) -> List[tuple]:
         fams: List[tuple] = []
+        merged = self.merged()
         for i, m in enumerate(self.replicas):
             for name, mtype, help_, samples in m.families():
-                fams.append((name, mtype, help_,
-                             [({**lab, "replica": str(i)}, v)
-                              for lab, v in samples]))
-        fleet = self.merged().summary()
+                if name == "repro_serve_compiles_total":
+                    # one count per process, whichever replica compiled
+                    samples = [({}, merged.n_compiles)] if i == 0 else []
+                else:
+                    samples = [({**lab, "replica": str(i)}, v)
+                               for lab, v in samples]
+                fams.append((name, mtype, help_, samples))
+        fleet = merged.summary()
         router = [
             ("repro_serve_router_replicas", "gauge",
              "Engine replicas configured.", len(self.replicas)),

@@ -14,6 +14,7 @@ Two layers of exactness guarantees:
 """
 
 import functools
+import re
 
 import jax
 import numpy as np
@@ -362,8 +363,11 @@ def test_router_metrics_one_scrape_per_family():
     text = rm.prometheus({"repro_serve_slots_total": 4.0})
     # every family renders exactly one HELP/TYPE header...
     for fam in ("repro_serve_requests_total", "repro_serve_tokens_generated"
-                "_total", "repro_serve_router_agg_tok_s"):
+                "_total", "repro_serve_router_agg_tok_s",
+                "repro_serve_compiles_total"):
         assert text.count(f"# TYPE {fam} ") == 1, fam
+    # compiles are per process: one fleet sample, not one per replica
+    assert len(re.findall(r"^repro_serve_compiles_total", text, re.M)) == 1
     # ...with per-replica samples distinguished by label
     assert 'replica="0"' in text and 'replica="1"' in text
     assert "repro_serve_router_replica_occupancy" in text

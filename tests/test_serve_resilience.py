@@ -554,3 +554,6 @@ def test_prometheus_chaos_series():
     assert eng.n_quarantines >= 1
     assert "repro_serve_degradation_stage 1" in text
     assert "repro_serve_degradation_transitions_total 1" in text
+    # the engine compiled its programs while serving (no warmup)
+    assert eng.metrics.n_compiles >= 1
+    assert f"repro_serve_compiles_total {eng.metrics.n_compiles}" in text
