@@ -433,7 +433,8 @@ def _tp_head_parallel(fn, head_axis, kernel, q, k_pages, v_pages, *rest):
     """Head-parallel ``shard_map`` wrapper shared by the three paged ops.
 
     Queries shard on ``head_axis``; the K/V pools shard on their KV-head
-    axis (dim 2 — matching ``paged_cache_axes``, so sharded pools are
+    axis (dim 2 — dim 3 of the layer-stacked pool ``paged_cache_axes``
+    places, whose two major axes the model merges, so sharded pools are
     consumed in place with zero resharding); block tables, lengths, and
     chunk offsets are host-authoritative and replicated. Each shard runs
     the routed kernel on its local head group — per-head arithmetic is

@@ -16,6 +16,9 @@ Layout
 * ``block_tables`` ``(B, P)`` int32    — physical page id per logical page
 * ``lengths``      ``(B,)`` int32      — valid KV depth per row (>= 1)
 
+Each page is cast to ``q``'s dtype as it is read, so a pool of another
+dtype is never copied whole.
+
 TPU mapping
 -----------
 Grid ``(B, P)`` with the page axis innermost ("arbitrary" semantics).
@@ -71,8 +74,8 @@ def _paged_attn_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
     @pl.when(base < length)
     def _page():
         q = q_ref[0]                             # (H, Dh)
-        k = k_ref[0]                             # (page_size, Kh, Dh)
-        v = v_ref[0]
+        k = k_ref[0].astype(q.dtype)             # (page_size, Kh, Dh)
+        v = v_ref[0].astype(q.dtype)
         kv_pos = base + jax.lax.broadcasted_iota(jnp.int32, (1, page_size), 1)
         valid = kv_pos < length                  # (1, page_size)
         scale = Dh ** -0.5
@@ -129,8 +132,8 @@ def _paged_verify_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
     @pl.when(base < length)
     def _page():
         q = q_ref[0]                             # (n_q, H, Dh)
-        k = k_ref[0]                             # (page_size, Kh, Dh)
-        v = v_ref[0]
+        k = k_ref[0].astype(q.dtype)             # (page_size, Kh, Dh)
+        v = v_ref[0].astype(q.dtype)
         kv_pos = base + jax.lax.broadcasted_iota(
             jnp.int32, (rows, page_size), 1)
         t_row = jax.lax.broadcasted_iota(

@@ -18,6 +18,9 @@ Layout
 * ``chunk_len`` scalar int32 — real tokens in the chunk (< ``Tc`` on the
                 right-padded final chunk)
 
+Each page is cast to ``q``'s dtype as it is read, so a pool of another
+dtype is never copied whole.
+
 TPU mapping
 -----------
 Grid ``(Tc // q_tile, P)`` — query-row tiles "parallel", the page axis
@@ -91,8 +94,8 @@ def _paged_prefill_kernel(bt_ref, info_ref, q_ref, k_ref, v_ref, o_ref,
     @pl.when((base < depth) & (base <= q_hi))
     def _page():
         q = q_ref[...]                           # (q_tile, H, Dh)
-        k = k_ref[0]                             # (page_size, Kh, Dh)
-        v = v_ref[0]
+        k = k_ref[0].astype(q.dtype)             # (page_size, Kh, Dh)
+        v = v_ref[0].astype(q.dtype)
         kv_pos = base + jax.lax.broadcasted_iota(
             jnp.int32, (rows, page_size), 1)
         t_row = jax.lax.broadcasted_iota(
@@ -146,10 +149,6 @@ def paged_prefill_attention(q, k_pages, v_pages, bt_row, start, chunk_len, *,
     assert bt_row.ndim == 1, bt_row.shape
     assert H % n_kv == 0, (H, n_kv)
     g = H // n_kv
-    if k_pages.dtype != q.dtype:
-        k_pages = k_pages.astype(q.dtype)
-    if v_pages.dtype != q.dtype:
-        v_pages = v_pages.astype(q.dtype)
     if q_tile is None:
         q_tile = q_tile_for(Tc)
     assert Tc % q_tile == 0, (Tc, q_tile)

@@ -215,6 +215,16 @@ def _update_rows(cache, new, pos):
     )(cache, new, pos)
 
 
+def _flat_pool(pool):
+    """The stacked page pool ``(L, n_pages, page_size, Kh, Dh)`` as one pool
+    of ``L * n_pages`` pages, in which layer ``l``'s page ``i`` is page
+    ``l * n_pages + i``: the kernels read a layer's pages where they lie
+    from its block-table entries plus that offset. Merging the two major
+    axes is a bitcast on the TPU's tiled layout (the tiles span the two
+    minor axes only)."""
+    return pool.reshape((-1,) + pool.shape[2:])
+
+
 def apply_decode(spec: AttentionSpec, params, x, cache):
     """One decode step. x: (B, 1, D); cache K/V: (B, S, Kh, Dh).
 
@@ -253,17 +263,18 @@ def apply_decode(spec: AttentionSpec, params, x, cache):
     return y, new_cache
 
 
-def apply_decode_paged(spec: AttentionSpec, params, x, cache, block_tables,
-                       live=None):
+def apply_decode_paged(spec: AttentionSpec, params, x, cache, layer,
+                       block_tables, live=None):
     """One decode step against the paged KV pool. x: (B, 1, D).
 
-    ``cache``: {"kp"/"vp": (n_pages, page_size, Kh, Dh), "pos": (B,)} —
-    the pool is shared by all slots; each slot's pages are named by its
-    ``block_tables`` row (B, P). The new K/V is scattered into
-    ``(page, offset)`` derived from the per-row ``pos``, then attention
-    runs through :func:`repro.kernels.ops.paged_attention` — jnp-route
-    bitwise-identical to :func:`apply_decode` on the same sequences,
-    Pallas-route an online-softmax page stream.
+    ``cache``: {"kp"/"vp": (L, n_pages, page_size, Kh, Dh), "pos": (B,)} —
+    the pools of every layer, of which this call reads and writes layer
+    ``layer``'s; each slot's pages are named by its ``block_tables`` row
+    (B, P), the same for every layer. The new K/V is scattered into
+    ``(layer, page, offset)`` derived from the per-row ``pos``, then
+    attention runs through :func:`repro.kernels.ops.paged_attention` —
+    jnp-route bitwise-identical to :func:`apply_decode` on the same
+    sequences, Pallas-route an online-softmax page stream.
 
     ``live`` (B,) bool masks rows that are actually decoding. This is
     load-bearing, not hygiene: unlike the slot-dense cache (where a dead
@@ -271,14 +282,15 @@ def apply_decode_paged(spec: AttentionSpec, params, x, cache, block_tables,
     — a non-live row (mid-chunked-prefill, or freshly admitted with a
     stale ``pos``) holds a real block table, and its clipped page index
     can alias onto an already-prefilled (possibly trie-shared) page.
-    Non-live rows scatter to the null page and their ``pos`` freezes.
+    Non-live rows scatter to the layer's null page and their ``pos``
+    freezes.
     """
     from repro.kernels import ops
 
     B, T, _ = x.shape
     assert T == 1
     kp, vp = cache["kp"], cache["vp"]
-    page_size = kp.shape[1]
+    page_size = kp.shape[2]
     P = block_tables.shape[1]
     pos = cache["pos"]                                        # (B,)
     if spec.rope == "mrope":
@@ -292,27 +304,29 @@ def apply_decode_paged(spec: AttentionSpec, params, x, cache, block_tables,
     if live is not None:
         pages = jnp.where(live, pages, 0)                     # -> null page
     offs = pos % page_size
-    kp = kp.at[pages, offs].set(k_new[:, 0].astype(kp.dtype))
-    vp = vp.at[pages, offs].set(v_new[:, 0].astype(vp.dtype))
-    o = ops.paged_attention(q[:, 0], kp.astype(q.dtype), vp.astype(q.dtype),
-                            block_tables, pos + 1)
+    kp = kp.at[layer, pages, offs].set(k_new[:, 0].astype(kp.dtype))
+    vp = vp.at[layer, pages, offs].set(v_new[:, 0].astype(vp.dtype))
+    base = layer * kp.shape[1]             # the layer's page 0, flattened
+    o = ops.paged_attention(q[:, 0], _flat_pool(kp), _flat_pool(vp),
+                            block_tables + base, pos + 1)
     o = shard(o[:, None], "batch", None, "heads", None)
     y = spec.wo.apply(params["wo"], o.reshape(B, 1, spec.n_heads * spec.head_dim))
     new_pos = pos + 1 if live is None else pos + live.astype(pos.dtype)
     return y, {"kp": kp, "vp": vp, "pos": new_pos}
 
 
-def apply_verify_paged(spec: AttentionSpec, params, x, cache, block_tables,
-                       live=None):
+def apply_verify_paged(spec: AttentionSpec, params, x, cache, layer,
+                       block_tables, live=None):
     """Speculative-verify window against the paged KV pool. x: (B, Tq, D).
 
     The window's ``Tq`` tokens sit at absolute positions ``pos .. pos+Tq-1``
     where ``pos = cache["pos"]`` (B,) is the *accepted* depth — the engine
     sets it host-authoritatively before each spec step, which is also what
     makes rollback free: rejected tokens are simply re-scattered over next
-    step. Each window token's K/V is scattered to its ``(page, offset)``
-    (non-live rows to the null page, same aliasing argument as
-    :func:`apply_decode_paged`), then all ``Tq`` queries attend in one
+    step. Each window token's K/V is scattered to its ``(layer, page,
+    offset)`` (non-live rows to the layer's null page, same aliasing
+    argument as :func:`apply_decode_paged`), then all ``Tq`` queries
+    attend in one
     :func:`repro.kernels.ops.paged_attention_verify` dispatch, causally
     masked inside the window. Returns ``pos`` UNCHANGED — in spec mode the
     host owns the depth (the engine learns the accepted count and rolls
@@ -322,7 +336,7 @@ def apply_verify_paged(spec: AttentionSpec, params, x, cache, block_tables,
 
     B, Tq, _ = x.shape
     kp, vp = cache["kp"], cache["vp"]
-    page_size = kp.shape[1]
+    page_size = kp.shape[2]
     P = block_tables.shape[1]
     pos = cache["pos"]                                        # (B,)
     pos_bt = pos[:, None] + jnp.arange(Tq)[None, :]           # (B, Tq)
@@ -336,26 +350,29 @@ def apply_verify_paged(spec: AttentionSpec, params, x, cache, block_tables,
     if live is not None:
         pages = jnp.where(live[:, None], pages, 0)            # -> null page
     offs = pos_bt % page_size
-    kp = kp.at[pages, offs].set(k_new.astype(kp.dtype))
-    vp = vp.at[pages, offs].set(v_new.astype(vp.dtype))
-    o = ops.paged_attention_verify(q, kp.astype(q.dtype), vp.astype(q.dtype),
-                                   block_tables, pos + Tq)
+    kp = kp.at[layer, pages, offs].set(k_new.astype(kp.dtype))
+    vp = vp.at[layer, pages, offs].set(v_new.astype(vp.dtype))
+    base = layer * kp.shape[1]             # the layer's page 0, flattened
+    o = ops.paged_attention_verify(q, _flat_pool(kp), _flat_pool(vp),
+                                   block_tables + base, pos + Tq)
     o = shard(o, "batch", None, "heads", None)
     y = spec.wo.apply(params["wo"],
                       o.reshape(B, Tq, spec.n_heads * spec.head_dim))
     return y, {"kp": kp, "vp": vp, "pos": pos}
 
 
-def prefill_chunk_paged(spec: AttentionSpec, params, x, cache, bt_row, slot,
-                        start, chunk_len):
+def prefill_chunk_paged(spec: AttentionSpec, params, x, cache, layer, bt_row,
+                        slot, start, chunk_len):
     """One page-aligned prefill chunk of a single request (batch 1).
 
     ``x: (1, Tc, D)`` with ``Tc`` a page multiple and ``start`` (the global
     position of the chunk's first token) page-aligned; ``chunk_len <= Tc``
     is the number of real tokens (the final chunk is right-padded).
-    ``bt_row: (P,)`` is the request's block-table row. The chunk's K/V is
-    scattered into its pages, then the chunk queries attend causally over
-    the request's whole cached context (reused prefix pages included)
+    ``bt_row: (P,)`` is the request's block-table row and ``cache``'s pools
+    hold every layer, as in :func:`apply_decode_paged`. The chunk's K/V is
+    scattered into layer ``layer``'s pages, then the chunk queries attend
+    causally over the request's whole cached context (reused prefix pages
+    included)
     through :func:`repro.kernels.ops.paged_prefill_attention` — the jnp
     oracle reproduces the old block-table gather + dense ``_attend``
     bitwise (masked columns are exact zeros), so the result stays bitwise
@@ -367,7 +384,7 @@ def prefill_chunk_paged(spec: AttentionSpec, params, x, cache, bt_row, slot,
     B, Tc, _ = x.shape
     assert B == 1
     kp, vp = cache["kp"], cache["vp"]
-    page_size = kp.shape[1]
+    page_size = kp.shape[2]
     P = bt_row.shape[0]
     n_chunk_pages = Tc // page_size
     assert Tc % page_size == 0, (Tc, page_size)
@@ -385,13 +402,15 @@ def prefill_chunk_paged(spec: AttentionSpec, params, x, cache, bt_row, slot,
     idx = start // page_size + jnp.arange(n_chunk_pages)
     page_ids = jnp.where(idx < P, bt_row[jnp.clip(idx, 0, P - 1)], 0)
     Kh, Dh = spec.n_kv_heads, spec.head_dim
-    kp = kp.at[page_ids].set(
+    kp = kp.at[layer, page_ids].set(
         k[0].reshape(n_chunk_pages, page_size, Kh, Dh).astype(kp.dtype))
-    vp = vp.at[page_ids].set(
+    vp = vp.at[layer, page_ids].set(
         v[0].reshape(n_chunk_pages, page_size, Kh, Dh).astype(vp.dtype))
     # chunk queries attend over this request's full context (prefix + the
     # chunk just written) straight off the page pool — no gathered view
-    o = ops.paged_prefill_attention(q[0], kp, vp, bt_row, start, chunk_len)
+    base = layer * kp.shape[1]             # the layer's page 0, flattened
+    o = ops.paged_prefill_attention(q[0], _flat_pool(kp), _flat_pool(vp),
+                                    bt_row + base, start, chunk_len)
     o = shard(o[None], "batch", None, "heads", None)
     y = spec.wo.apply(params["wo"], o.reshape(1, Tc, spec.n_heads * spec.head_dim))
     pos = cache["pos"].at[slot].set(start + chunk_len)

@@ -105,16 +105,39 @@ class ModelConfig:
         return {"float32": jnp.float32, "bfloat16": jnp.bfloat16}[self.dtype]
 
 
-def scan_layers(body, x, xs):
-    """``lax.scan`` of ``body`` over stacked layers, under the named scopes
-    a device trace reads: every op carries ``layer_scan``, and the body's
-    own ops ``layer`` inside it, so the ops the scan adds to move each
-    layer's parameters and cache in and out are those without ``layer``."""
-    def scoped(carry, layer):
+# the leaves of a paged attention cache that hold its page pool
+POOL_LEAVES = ("kp", "vp")
+
+
+def scan_layers(body, x, params, cache):
+    """``lax.scan`` of ``body(x, p, c, layer)`` over stacked layers, under
+    the named scopes a device trace reads: every op carries ``layer_scan``,
+    and the body's own ops ``layer`` inside it, so the ops the scan adds to
+    move each layer's parameters and cache rows in and out are those
+    without ``layer``.
+
+    ``p`` is the layer's slice of ``params``, ``layer`` its index. The
+    cache's page pools (``POOL_LEAVES``) ride the scan's carry whole: ``c``
+    holds them with their layer axis, and the body writes and reads its own
+    layer's pages in place, so no step copies a layer's pool out or back.
+    Every other cache leaf (``pos``, dense slot caches, recurrent state) is
+    sliced per layer and stacked back."""
+    pools = {k: v for k, v in cache.items() if k in POOL_LEAVES}
+    rows = {k: v for k, v in cache.items() if k not in POOL_LEAVES}
+
+    def scoped(carry, per_layer):
+        x, pools = carry
+        p, c, layer = per_layer
         with jax.named_scope("layer"):
-            return body(carry, layer)
+            x, c2 = body(x, p, {**c, **pools}, layer)
+        return ((x, {k: c2[k] for k in pools}),
+                {k: v for k, v in c2.items() if k not in pools})
+
+    n = jax.tree.leaves(params)[0].shape[0]
     with jax.named_scope("layer_scan"):
-        return jax.lax.scan(scoped, x, xs)
+        (x, pools), rows = jax.lax.scan(
+            scoped, (x, pools), (params, rows, jnp.arange(n, dtype=jnp.int32)))
+    return x, {**rows, **pools}
 
 
 class Model:
@@ -428,7 +451,8 @@ class Model:
                              "x_cm": ("layers", "batch", None, None)})
         return axes
 
-    def _decode_block(self, spec, p, x, cache, block_tables=None, live=None):
+    def _decode_block(self, spec, p, x, cache, layer, block_tables=None,
+                      live=None):
         cfg = self.cfg
         kind = spec["kind"]
 
@@ -446,7 +470,7 @@ class Model:
         if kind in ("attn", "attn_moe"):
             if block_tables is not None:
                 y, cache = attn_lib.apply_decode_paged(
-                    spec["mixer"], p["mixer"], h, cache, block_tables,
+                    spec["mixer"], p["mixer"], h, cache, layer, block_tables,
                     live=live)
             else:
                 y, cache = attn_lib.apply_decode(spec["mixer"], p["mixer"],
@@ -493,13 +517,11 @@ class Model:
             x = tokens.astype(cfg.jdtype)
         new_caches = []
         for spec, pstack, cstack in zip(self.block_specs, params["blocks"], caches):
-            def body(x, pc, spec=spec):
-                p, c = pc
-                x, c2 = self._decode_block(spec, p, x, c,
-                                           block_tables=block_tables,
-                                           live=live)
-                return x, c2
-            x, c_new = scan_layers(body, x, (pstack, cstack))
+            def body(x, p, c, layer, spec=spec):
+                return self._decode_block(spec, p, x, c, layer,
+                                          block_tables=block_tables,
+                                          live=live)
+            x, c_new = scan_layers(body, x, pstack, cstack)
             new_caches.append(c_new)
         x = layers.apply_norm(cfg.norm, params["final_norm"], x)
         lg = self.unembed.apply(params["unembed"], x[:, 0])
@@ -549,11 +571,11 @@ class Model:
         new_caches = []
         for spec, pstack, cstack in zip(self.block_specs, params["blocks"],
                                         caches):
-            def body(x, pc, spec=spec):
-                p, c = pc
+            def body(x, p, c, layer, spec=spec):
                 h = layers.apply_norm(cfg.norm, p["norm1"], x)
                 y, c2 = attn_lib.apply_verify_paged(
-                    spec["mixer"], p["mixer"], h, c, block_tables, live=live)
+                    spec["mixer"], p["mixer"], h, c, layer, block_tables,
+                    live=live)
                 x = x + y
                 h2 = layers.apply_norm(cfg.norm, p["norm2"], x)
                 if spec["kind"].endswith("_moe"):
@@ -561,7 +583,7 @@ class Model:
                 else:
                     y2 = spec["ffn"].apply(p["ffn"], h2)
                 return x + y2, c2
-            x, c_new = scan_layers(body, x, (pstack, cstack))
+            x, c_new = scan_layers(body, x, pstack, cstack)
             new_caches.append(c_new)
         x = layers.apply_norm(cfg.norm, params["final_norm"], x)
         lg = self.unembed.apply(params["unembed"], x)
@@ -613,13 +635,12 @@ class Model:
                                         caches):
             kind = spec["kind"]
 
-            def body(x, pc, spec=spec, kind=kind):
-                p, c = pc
+            def body(x, p, c, layer, spec=spec, kind=kind):
                 h = layers.apply_norm(cfg.norm, p["norm1"], x)
                 if kind in ("attn", "attn_moe"):
                     y, c2 = attn_lib.prefill_chunk_paged(
-                        spec["mixer"], p["mixer"], h, c, bt_row, slot, start,
-                        chunk_len)
+                        spec["mixer"], p["mixer"], h, c, layer, bt_row, slot,
+                        start, chunk_len)
                     x = x + y
                 elif kind in ("mamba", "mamba_moe"):
                     mix = spec["mixer"]
@@ -653,7 +674,7 @@ class Model:
                     y2 = spec["ffn"].apply(p["ffn"], h2)
                 return x + y2, c2
 
-            x, c_new = scan_layers(body, x, (pstack, cstack))
+            x, c_new = scan_layers(body, x, pstack, cstack)
             new_caches.append(c_new)
         if not final:
             return None, new_caches

@@ -34,7 +34,14 @@ ARCHS = ("olmo-1b", "rwkv6-3b", "mamba-tiny")
 
 @functools.lru_cache(maxsize=None)
 def _model(arch):
-    cfg = MAMBA if arch == "mamba-tiny" else common.get_config(arch, smoke=True)
+    """A smoke model; ``<arch>-3l`` is the same arch three layers deep, so
+    K/V written to or read from another layer's pages shows."""
+    if arch == "mamba-tiny":
+        cfg = MAMBA
+    elif arch.endswith("-3l"):
+        cfg = common.get_config(arch[:-3], smoke=True, n_layers=3)
+    else:
+        cfg = common.get_config(arch, smoke=True)
     m = build(cfg)
     return m, m.init(jax.random.PRNGKey(0))
 
@@ -81,23 +88,90 @@ def test_paged_matches_static_greedy(arch):
         assert 0 < s["kv_bytes_allocated_peak"] < s["kv_bytes_reserved"]
 
 
-@pytest.mark.parametrize("arch", ["olmo-1b", "rwkv6-3b"])
-def test_paged_staggered_admission(arch):
+def _watch_null_pages(eng):
+    """Wrap the engine's decode and chunk programs to record, per call and
+    per attention layer, which offsets of the null page (page 0) changed,
+    and check each against the rows that may write there: a decode's
+    non-live rows (at ``pos % page_size``) and a chunk's pages that the
+    block table names null (its padded tail past the table, or a warm-up
+    on an all-null table). Returns the (layers, page_size) mask of offsets
+    the decodes wrote over the run."""
+    ps = eng.cache.page_size
+    seen = []
+
+    def null_page(caches):
+        return np.stack([np.asarray(c["kp"][:, NULL_PAGE])
+                         for c in caches if "kp" in c])    # (blocks, L, ...)
+
+    def changed(before, after):
+        return (before != after).any(axis=(-2, -1))      # (blocks, L, ps)
+
+    decode, chunk = eng._decode_paged, eng._chunk
+
+    def watched_decode(params, caches, dev, bt, live, poison):
+        before = null_page(caches)
+        out = decode(params, caches, dev, bt, live, poison)
+        pos = np.asarray(next(c for c in caches if "kp" in c)["pos"][0])
+        allowed = np.zeros(ps, bool)
+        allowed[pos[~np.asarray(live)] % ps] = True
+        hit = changed(before, null_page(out[1]))
+        assert not (hit & ~allowed).any(), "live K/V on the null page"
+        seen.append(hit)
+        return out
+
+    def watched_chunk(params, caches, dev, toks, bt_row, slot, start, n,
+                      *rest, **kw):
+        before = null_page(caches)
+        out = chunk(params, caches, dev, toks, bt_row, slot, start, n,
+                    *rest, **kw)
+        idx = int(start) // ps + np.arange(toks.shape[1] // ps)
+        row = np.asarray(bt_row)
+        named = np.where(idx < len(row), row[np.minimum(idx, len(row) - 1)],
+                         NULL_PAGE)
+        hit = changed(before, null_page(out[1]))
+        assert (named == NULL_PAGE).any() or not hit.any(), \
+            "chunk K/V on the null page"
+        return out
+
+    eng._decode_paged, eng._chunk = watched_decode, watched_chunk
+    return lambda: np.logical_or.reduce(seen)
+
+
+@pytest.mark.parametrize("arch,backend", [
+    pytest.param("olmo-1b", "jnp", id="olmo-1b"),
+    pytest.param("rwkv6-3b", "jnp", id="rwkv6-3b"),
+    pytest.param("olmo-1b-3l", "jnp", id="olmo-1b-3l-jnp"),
+    pytest.param("olmo-1b-3l", "interpret", id="olmo-1b-3l-interpret"),
+])
+def test_paged_staggered_admission(arch, backend):
     """Requests landing mid-decode of others (chunked prefill interleaved
-    with running decodes) must not perturb anyone's tokens."""
+    with running decodes) must not perturb anyone's tokens. Three layers
+    deep, every layer's null page takes the non-live rows' writes and no
+    live token's K/V."""
+    from repro.kernels import ops
     m, p = _model(arch)
     reqs = _requests(m.cfg, 3, seed=2, max_gen=12)
-    eng = Engine(m, p, n_slots=3, max_len=64, paged=True, page_size=8)
-    eng.submit(reqs[0])
-    for _ in range(3):
+    saved = ops._BACKEND
+    ops.set_backend(backend)
+    try:
+        eng = Engine(m, p, n_slots=3, max_len=64, paged=True, page_size=8)
+        written = _watch_null_pages(eng) if arch.endswith("-3l") else None
+        eng.submit(reqs[0])
+        for _ in range(3):
+            eng.step()
+        eng.submit(reqs[1])
         eng.step()
-    eng.submit(reqs[1])
-    eng.step()
-    eng.submit(reqs[2])
-    while eng.has_work():
-        eng.step()
+        eng.submit(reqs[2])
+        while eng.has_work():
+            eng.step()
+    finally:
+        ops._BACKEND = saved
     for r in reqs:
         assert list(r.generated) == _reference(m, p, r), (arch, r.id)
+    if written is not None:
+        # idle slots decode as non-live rows from the first step on: each
+        # layer's own null page takes their writes
+        assert written().any(axis=-1).all(), written()
 
 
 def test_paged_page_reuse_single_slot():

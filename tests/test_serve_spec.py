@@ -34,9 +34,16 @@ MAMBA = ModelConfig(name="mamba-tiny", n_layers=2, d_model=64, n_heads=4,
                     mpd_c=4)
 
 
+def _config(arch, **kw):
+    """A smoke config; ``<arch>-3l`` is the same arch three layers deep."""
+    if arch.endswith("-3l"):
+        return common.get_config(arch[:-3], smoke=True, n_layers=3, **kw)
+    return common.get_config(arch, smoke=True, **kw)
+
+
 @functools.lru_cache(maxsize=None)
 def _model(arch):
-    cfg = MAMBA if arch == "mamba-tiny" else common.get_config(arch, smoke=True)
+    cfg = MAMBA if arch == "mamba-tiny" else _config(arch)
     m = build(cfg)
     return m, m.init(jax.random.PRNGKey(0))
 
@@ -47,7 +54,7 @@ def _drafts(arch):
     MPD-compressed packed artifact — the intended deployment), and skewed
     (different weights — low acceptance, exercises rollback)."""
     m, p = _model(arch)
-    cfg = common.get_config(arch, smoke=True, mpd_mode="masked_dense")
+    cfg = _config(arch, mpd_mode="masked_dense")
     md = build(cfg)
     pd = md.init(jax.random.PRNGKey(0))
     return {"perfect": (m, p),
@@ -78,16 +85,22 @@ def _run(m, p, reqs, *, spec_draft=None, spec_k=4, n_slots=2):
 
 # ------------------------------------------------------------------ exactness
 
-@pytest.mark.parametrize("draft", ["perfect", "int8", "skewed"])
-def test_spec_greedy_matches_paged(draft):
+@pytest.mark.parametrize("arch,draft", [
+    pytest.param("olmo-1b", "perfect", id="perfect"),
+    pytest.param("olmo-1b", "int8", id="int8"),
+    pytest.param("olmo-1b", "skewed", id="skewed"),
+    pytest.param("olmo-1b-3l", "skewed", id="skewed-3l"),
+])
+def test_spec_greedy_matches_paged(arch, draft):
     """Greedy spec output == non-spec paged greedy, token for token, with
     staggered admission (6 requests, 2 slots). The skewed draft rejects
     often — every mismatch forces a paged rollback — yet exactness must
-    hold; the perfect draft must accept everything."""
-    m, p = _model("olmo-1b")
+    hold; the perfect draft must accept everything. Three layers deep, a
+    window's K/V written to or read from another layer's pages shows."""
+    m, p = _model(arch)
     reqs = _requests(m.cfg, 6, seed=1)
     base, _ = _run(m, p, reqs)
-    out, eng = _run(m, p, reqs, spec_draft=_drafts("olmo-1b")[draft])
+    out, eng = _run(m, p, reqs, spec_draft=_drafts(arch)[draft])
     assert eng.spec_active
     assert out == base
     s = eng.metrics.summary()

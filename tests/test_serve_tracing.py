@@ -9,6 +9,7 @@ the compile counter.
   spans a caller wraps around the step.
 * **Scopes** — in the compiled decode and chunk programs the ops the layer
   scan adds carry ``layer_scan`` and not ``layer``; the body's carry both.
+  None of the scan's own ops moves a layer's page pool.
 * **Compiles** — ``ServeMetrics.n_compiles`` rises on a new decode width
   and not on a repeat.
 """
@@ -124,15 +125,24 @@ def _compiled_text(eng, program):
 
 @pytest.mark.parametrize("program", ["decode", "chunk"])
 def test_layer_scan_scopes(program):
-    scopes = re.findall(r'op_name="([^"]*)"', _compiled_text(_engine(),
-                                                             program))
+    eng = _engine()
+    ops = re.findall(r'^\s*(?:ROOT\s+)?%?\S+\s*=\s*(\S+)\s.*'
+                     r'\bop_name="([^"]*)"', _compiled_text(eng, program),
+                     re.M)
+    scopes = [s for _, s in ops]
     scan_only = [s for s in scopes if trace_marks.scan_only(s)]
     body = [s for s in scopes if "layer" in s.split("/")]
-    # the scan slices each layer's stacks in and writes the cache back
+    # the scan slices each layer's parameters and ``pos`` row in and stacks
+    # ``pos`` back; the page pool rides the carry, and the body reads only
+    # the pages its block tables name, so no op, the scan's own or the
+    # body's, has the shape of one layer's pool
     assert any(s.endswith("/dynamic_slice") for s in scan_only), scan_only
     assert any(s.endswith("/dynamic_update_slice") for s in scan_only)
     assert any("dot_general" in s for s in body)
     assert not any("dot_general" in s for s in scan_only)
+    dims = ",".join(map(str, eng.cache.caches[0]["kp"].shape[1:]))
+    layer_pool = re.compile(rf"\[(?:1,)?{dims}\]")
+    assert not [(shape, s) for shape, s in ops if layer_pool.search(shape)]
 
 
 def test_compile_counter_rises_on_new_width_only():

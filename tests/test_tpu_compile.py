@@ -14,6 +14,7 @@ imports this file.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -97,3 +98,122 @@ def test_paged_prefill_attention_compiles_for_v5e(one_chip):
     pool = s((pages, ps, heads, dh), BF16)
     _compile(pp_kernel.paged_prefill_attention, s((chunk, heads, dh), BF16),
              pool, pool, s((width,), I32), s((), I32), s((), I32))
+
+
+# --------------------------------------------------- the layer scan's pools
+#
+# The paged decode and chunk programs carry each attention block's stacked
+# page pool through the layer scan whole and update it in place. In the
+# compiled program that leaves one copy of each pool at entry (the input
+# pool is not donated) and none per layer: no slice of one layer's pool out
+# of the stack, no write of it back, and inside the loop no pool-sized
+# result but the in-place scatter and views of the pool. The pool is larger
+# than the chip's VMEM (3 x 768 pages of 64 KiB), as a served one is, so
+# the compiler keeps it in HBM as it would in service.
+
+N_LAYERS, N_PAGES = 3, 768
+INSTR = re.compile(r"^\s*(?:ROOT\s+)?%(\S+)\s*=\s*(.*?)\b([a-z][a-z0-9-]*)\(")
+CALLED = re.compile(r"\b(?:calls|to_apply|body|condition)=%([\w.-]+)")
+# ops that may give a pool-sized result inside the loop: views of the carried
+# pool, the scatter that updates it in place, and the fusion around that
+# scatter (whose own ops are checked as part of the loop)
+IN_PLACE = {"parameter", "get-tuple-element", "tuple", "bitcast", "scatter",
+            "fusion"}
+
+
+def _computations(text):
+    """``{computation: [(opcode, result shape, line)]}`` of compiled HLO
+    text, and the computations each one calls."""
+    comps, calls, name = {}, {}, None
+    for line in text.splitlines():
+        head = re.match(r"^(?:ENTRY\s+)?%([\w.-]+)\s.*\{\s*$", line)
+        if head:
+            name = head.group(1)
+            comps[name], calls[name] = [], set()
+            continue
+        m = INSTR.match(line)
+        if name is None or m is None:
+            continue
+        comps[name].append((m.group(3), m.group(2), line))
+        calls[name] |= set(CALLED.findall(line))
+    return comps, calls
+
+
+def _loop_computations(comps, calls):
+    """Every computation a ``while`` body runs, fusions included."""
+    todo = [re.search(r"\bbody=%([\w.-]+)", line).group(1)
+            for ins in comps.values() for op, _, line in ins if op == "while"]
+    seen = set()
+    while todo:
+        c = todo.pop()
+        if c not in seen:
+            seen.add(c)
+            todo += calls.get(c, ())
+    return seen
+
+
+def _paged_program(program, one_chip):
+    """The compiled HLO of the paged decode step or the final prefill
+    chunk of a 3-layer OLMo-1B (packed c=8, int8 weights, bf16 K/V) on the
+    Pallas route."""
+    from repro.configs.common import get_config
+    from repro.core.export import quantize_packed
+    from repro.kernels import ops
+    from repro.models import build
+
+    cfg = get_config("olmo-1b", n_layers=N_LAYERS, dtype="bfloat16",
+                     mpd_c=8, mpd_fuse=True)
+    model = build(cfg)
+    params = jax.eval_shape(
+        lambda: quantize_packed(model, model.init(jax.random.PRNGKey(0)),
+                                bits=8, compute_report=False)[0])
+    slots, ps, width, chunk = 8, 16, 32, 256
+    caches = jax.eval_shape(
+        lambda: model.init_paged_caches(slots, N_PAGES, ps, BF16))
+    place = lambda t: jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), t)
+    s = lambda shape, dt=I32: jax.ShapeDtypeStruct(shape, dt,
+                                                   sharding=one_chip)
+    saved = ops._BACKEND
+    ops.set_backend("pallas")
+    try:
+        if program == "decode":
+            low = jax.jit(lambda p, c, t, bt, live: model.decode_step(
+                p, t, c, block_tables=bt, live=live)).lower(
+                place(params), place(caches), s((slots,)),
+                s((slots, width)), s((slots,), jnp.bool_))
+        else:
+            low = jax.jit(lambda p, c, t, bt, st, cl: model.prefill_chunk(
+                p, t, c, bt, 0, st, cl, final=True)).lower(
+                place(params), place(caches), s((1, chunk)), s((width,)),
+                s(()), s(()))
+        return low.compile().as_text(), cfg
+    finally:
+        ops._BACKEND = saved
+
+
+@pytest.mark.parametrize("program", ["decode", "chunk"])
+def test_layer_scan_updates_pool_in_place_for_v5e(one_chip, program):
+    text, cfg = _paged_program(program, one_chip)
+    tail = f"16,{cfg.n_kv_heads},{cfg.hd}]"
+    layer_pool = re.compile(rf"\[(?:1,)?{N_PAGES},{tail}")
+    whole_pool = re.compile(
+        rf"\[(?:{N_LAYERS},{N_PAGES}|{N_LAYERS * N_PAGES}),{tail}")
+    comps, calls = _computations(text)
+    assert "tpu_custom_call" in text
+    ins = [(c, op, shape, line) for c, lines in comps.items()
+           for op, shape, line in lines]
+    sliced = [line for _, op, _, line in ins
+              if op in ("dynamic-slice", "dynamic-update-slice")
+              and layer_pool.search(line)]
+    assert not sliced, sliced[:2]
+    loop = _loop_computations(comps, calls)
+    assert loop
+    pool_sized = lambda shape: layer_pool.search(shape) or \
+        whole_pool.search(shape)
+    moved = [line for c, op, shape, line in ins
+             if c in loop and op not in IN_PLACE and pool_sized(shape)]
+    assert not moved, moved[:2]
+    copies = [line for _, op, shape, line in ins
+              if op in ("copy", "copy-start") and pool_sized(shape)]
+    assert len(copies) <= 2, copies       # at most one per pool (K and V)
