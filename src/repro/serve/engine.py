@@ -1178,7 +1178,7 @@ class Engine:
             with _span("engine.decode.prep") as span:
                 # materialize this step's write pages and size the active
                 # block-table width to the deepest live sequence
-                needed = 1
+                needed, kv_tokens = 1, 0
                 wpos_arr = np.zeros((self.n_slots,), np.int32)
                 for slot in live_slots:
                     req = self.scheduler.running.get(int(slot))
@@ -1186,11 +1186,13 @@ class Engine:
                         continue
                     wpos = self._kv_len(req)
                     wpos_arr[slot] = wpos
+                    kv_tokens += wpos + 1      # the kernel's length for it
                     self.cache.ensure_decode_page(int(slot), wpos)
                     needed = max(needed, self.cache.pages_used(int(slot),
                                                                wpos + 1))
                 width = min(_next_pow2(needed), self.cache.max_pages)
-                span.set_metadata(live=len(live_slots), width=width)
+                span.set_metadata(live=len(live_slots), width=width,
+                                  kv_tokens=kv_tokens)
                 bt = self._block_tables_dev(width)
                 # live mask is load-bearing: mid-prefill slots hold real
                 # block tables + carried state that an unmasked decode
@@ -1229,6 +1231,8 @@ class Engine:
             ok_np = np.asarray(ok_dev)
 
         self.metrics.on_step(int(self._live.sum()), self.n_slots)
+        if self.paged:
+            self.metrics.on_kv_tokens_attended(kv_tokens)
         self._report_kv()
         live_slots = np.nonzero(self._live)[0]
         with _span("engine.emit", n=len(live_slots)):
@@ -1253,13 +1257,14 @@ class Engine:
         live_slots = np.nonzero(self._live)[0]
         with _span("engine.decode.prep") as span:
             pos0 = np.zeros((self.n_slots,), np.int32)
-            needed = 1
+            needed, kv_tokens = 1, 0
             for slot in live_slots:
                 req = self.scheduler.running.get(int(slot))
                 if req is None:
                     continue
                 wpos = self._kv_len(req)
                 pos0[slot] = wpos
+                kv_tokens += wpos + k + 1      # the verify kernel's length
                 # target writes window positions wpos..wpos+k; the draft
                 # only wpos..wpos+k-1 — materialize each range against the
                 # slack reservation
@@ -1271,7 +1276,8 @@ class Engine:
                 needed = max(needed, self.cache.pages_used(int(slot),
                                                            wpos + k + 1))
             width = min(_next_pow2(needed), self.cache.max_pages)
-            span.set_metadata(live=len(live_slots), width=width)
+            span.set_metadata(live=len(live_slots), width=width,
+                              kv_tokens=kv_tokens)
             bt = self._block_tables_dev(width)
             dbt = self._draft_block_tables_dev(width)
             live = self._live_mask_dev()
@@ -1301,6 +1307,7 @@ class Engine:
             ok_np = np.asarray(ok_dev)
 
         self.metrics.on_step(int(self._live.sum()), self.n_slots)
+        self.metrics.on_kv_tokens_attended(kv_tokens)
         self._report_kv()
         with _span("engine.emit", n=len(live_slots)):
             for slot in live_slots:

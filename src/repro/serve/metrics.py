@@ -139,6 +139,7 @@ class ServeMetrics:
         self._occupancy: List[float] = []     # live-slot fraction per step
         self.prefill_tokens_computed = 0      # excludes prefix-reused tokens
         self.prefill_kv_bytes_read = 0        # KV streamed by chunk attention
+        self.kv_tokens_attended = 0           # decode kernels' lengths, summed
         self.kv_bytes_reserved = 0            # dense n_slots*max_len equiv
         self.kv_bytes_allocated_peak = 0
         self.kv_bytes_logical_peak = 0
@@ -279,6 +280,13 @@ class ServeMetrics:
         the dense gather path reads the full laddered block-table width
         per chunk, so the ratio between the two is the kernel's win."""
         self.prefill_kv_bytes_read += nbytes
+
+    def on_kv_tokens_attended(self, n: int) -> None:
+        """KV tokens one paged decode step attends: the lengths its
+        attention kernel is handed, summed over the live rows (the whole
+        verify window's in spec mode). Every layer reads that many keys
+        and values per KV head."""
+        self.kv_tokens_attended += n
 
     def on_kv(self, allocated_bytes: int, logical_bytes: int,
               reserved_bytes: int) -> None:
@@ -464,6 +472,10 @@ class ServeMetrics:
         metric("repro_serve_degradation_transitions_total", "counter",
                "Degradation-ladder stage transitions.",
                [({}, self.degradation_transitions)])
+        metric("repro_serve_kv_tokens_attended_total", "counter",
+               "KV tokens the paged decode steps attended: the lengths "
+               "handed to the decode attention kernel, summed over live "
+               "rows and steps.", [({}, self.kv_tokens_attended)])
         metric("repro_serve_compiles_total", "counter",
                "Executables built (compiled or loaded from the persistent "
                "cache) in this process since the engine started; one "
@@ -634,6 +646,7 @@ class RouterMetrics:
             out._occupancy.extend(m._occupancy)
             out.prefill_tokens_computed += m.prefill_tokens_computed
             out.prefill_kv_bytes_read += m.prefill_kv_bytes_read
+            out.kv_tokens_attended += m.kv_tokens_attended
             out.kv_bytes_reserved += m.kv_bytes_reserved
             out.kv_bytes_allocated_peak += m.kv_bytes_allocated_peak
             out.kv_bytes_logical_peak += m.kv_bytes_logical_peak

@@ -615,3 +615,47 @@ def test_cache_dtype_routes_through_config():
     spec = m.block_specs[0]["mixer"]
     assert attn_lib.init_cache(spec, 1, 8)["k"].dtype == jnp.float32
     assert attn_lib.init_paged_cache(spec, 1, 4, 8)["kp"].dtype == jnp.float32
+
+
+def _scans(jaxpr):
+    """The outermost ``scan`` equations of a jaxpr (the layer scans), under
+    any other call; not the scans inside a layer."""
+    for e in jaxpr.eqns:
+        if e.primitive.name == "scan":
+            yield e
+            continue
+        for v in e.params.values():
+            for sub in (v if isinstance(v, (list, tuple)) else (v,)):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    yield from _scans(inner)
+
+
+@pytest.mark.parametrize("arch", ["granite-8b", "rwkv6-3b", "mamba-tiny"])
+def test_serving_programs_carry_the_residual_in_float32(arch):
+    """In a bfloat16 model the paged decode step and the final prefill
+    chunk carry the residual stream through the layer scan in float32;
+    the caches keep the compute dtype and the logits come out in it."""
+    import dataclasses
+
+    cfg = MAMBA if arch == "mamba-tiny" else common.get_config(arch,
+                                                               smoke=True)
+    m = build(dataclasses.replace(cfg, dtype="bfloat16"))
+    params = jax.eval_shape(m.init, jax.random.PRNGKey(0))
+    caches = jax.eval_shape(lambda: m.init_paged_caches(2, 8, 8))
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)
+    decode = jax.make_jaxpr(lambda p, c, t, bt: m.decode_step(
+        p, t, c, block_tables=bt, live=jnp.ones((2,), bool)))(
+        params, caches, i32(2), i32(2, 4))
+    chunk = jax.make_jaxpr(lambda p, c, t, bt: m.prefill_chunk(
+        p, t, c, bt, 0, 0, 8, final=True))(params, caches, i32(1, 16), i32(4))
+    for program in (decode, chunk):
+        scans = list(_scans(program.jaxpr))
+        assert scans
+        for e in scans:
+            x = e.invars[e.params["num_consts"]].aval
+            assert x.dtype == jnp.float32 and x.shape[-1] == cfg.d_model
+        logits, *new_caches = jax.tree.leaves(program.out_avals)
+        assert logits.dtype == jnp.bfloat16
+        assert [c.dtype for c in new_caches] == [
+            c.dtype for c in jax.tree.leaves(caches)]

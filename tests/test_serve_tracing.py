@@ -10,6 +10,9 @@ the compile counter.
 * **Scopes** — in the compiled decode and chunk programs the ops the layer
   scan adds carry ``layer_scan`` and not ``layer``; the body's carry both.
   None of the scan's own ops moves a layer's page pool.
+* **KV tokens** — ``kv_tokens`` on the decode prep span, and
+  ``ServeMetrics.kv_tokens_attended``, are the lengths the decode
+  attention kernel is handed, summed over live rows.
 * **Compiles** — ``ServeMetrics.n_compiles`` rises on a new decode width
   and not on a repeat.
 """
@@ -102,6 +105,54 @@ def test_engine_spans_nest_under_step(tmp_path, spec):
                for a in args["engine.decode.prep"])
     assert all("n" in a for a in args["engine.admit"] + args["engine.emit"])
     assert sum(a["n"] for a in args["engine.admit"]) == len(reqs)
+
+
+@pytest.mark.parametrize("spec", [False, True], ids=["paged", "spec"])
+def test_kv_tokens_are_the_decode_kernel_lengths(tmp_path, spec):
+    """``kv_tokens`` on each ``engine.decode.prep`` span is the sum over
+    live rows of the lengths the step's attention kernel is handed (plain:
+    the cache ``pos`` + 1; spec: the verify window's end, ``pos0`` + k +
+    1), read here from the arguments of the dispatched program; the
+    metrics counter and its Prometheus sample hold the same total."""
+    import numpy as np
+
+    m, p = _model("olmo-1b")
+    eng = Engine(m, p, n_slots=2, max_len=64, paged=True, page_size=8,
+                 prefill_chunk_tokens=8,
+                 spec_draft=_drafts("olmo-1b")["perfect"] if spec else None)
+    handed = []
+    decode, verify = eng._decode_paged, getattr(eng, "_verify", None)
+
+    def on_decode(params, caches, dev, bt, live, poison):
+        pos = np.asarray(caches[0]["pos"][0])
+        handed.append(int((pos + 1)[np.asarray(live)].sum()))
+        return decode(params, caches, dev, bt, live, poison)
+
+    def on_verify(params, caches, dev, bt, live, pos0, toks, q, poison):
+        window = toks.shape[1] + 1
+        handed.append(int((np.asarray(pos0) + window)[np.asarray(live)]
+                          .sum()))
+        return verify(params, caches, dev, bt, live, pos0, toks, q, poison)
+
+    eng._decode_paged = on_decode
+    if spec:
+        eng._verify = on_verify
+    for r in _requests(m.cfg, 4, seed=5, max_prompt=24):
+        eng.submit(r)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        while eng.has_work():
+            eng.step()
+    finally:
+        jax.profiler.stop_trace()
+
+    xplane = next(tmp_path.glob("**/*.xplane.pb"))
+    preps = trace_marks.host_spans(str(xplane), {"engine.decode.prep"})
+    got = [a["kv_tokens"] for _, _, _, a in preps]
+    assert handed and got == handed
+    assert eng.metrics.kv_tokens_attended == sum(handed)
+    assert (f"repro_serve_kv_tokens_attended_total {sum(handed)}"
+            in eng.metrics.prometheus())
 
 
 def _engine():

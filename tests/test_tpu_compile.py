@@ -152,24 +152,43 @@ def _loop_computations(comps, calls):
     return seen
 
 
-def _paged_program(program, one_chip):
-    """The compiled HLO of the paged decode step or the final prefill
-    chunk of a 3-layer OLMo-1B (packed c=8, int8 weights, bf16 K/V) on the
-    Pallas route."""
+# Granite-8B-Code's cell: bf16 weights unfused, a bias on every projection,
+# 32 query heads over 8 KV heads, and the cell's pool of 16 slots x 64
+# pages plus the null page. Its pages are half OLMo's (8 KV heads), so it
+# takes four layers for the pool to outgrow VMEM (4 x 1025 pages of 32
+# KiB); at three the compiler stages one pool through VMEM, which a served
+# 36-layer pool never is.
+GRANITE_LAYERS, GRANITE_PAGES = 4, 1025
+
+
+def _paged_program(program, one_chip, arch="olmo-1b"):
+    """The compiled paged decode step or final prefill chunk on the Pallas
+    route, bf16 K/V: a 3-layer OLMo-1B packed c=8 with the Fig-3 fusion
+    and int8 weights, or a 4-layer Granite-8B-Code packed c=8 in bf16 with
+    its biases. Returns the HLO text, the config, the pool's page count
+    and the compiled program's memory analysis."""
     from repro.configs.common import get_config
     from repro.core.export import quantize_packed
     from repro.kernels import ops
     from repro.models import build
 
-    cfg = get_config("olmo-1b", n_layers=N_LAYERS, dtype="bfloat16",
-                     mpd_c=8, mpd_fuse=True)
-    model = build(cfg)
-    params = jax.eval_shape(
-        lambda: quantize_packed(model, model.init(jax.random.PRNGKey(0)),
-                                bits=8, compute_report=False)[0])
-    slots, ps, width, chunk = 8, 16, 32, 256
+    if arch == "olmo-1b":
+        cfg = get_config(arch, n_layers=N_LAYERS, dtype="bfloat16",
+                         mpd_c=8, mpd_fuse=True)
+        model = build(cfg)
+        params = jax.eval_shape(
+            lambda: quantize_packed(model, model.init(jax.random.PRNGKey(0)),
+                                    bits=8, compute_report=False)[0])
+        slots, pages, width = 8, N_PAGES, 32
+    else:
+        cfg = get_config(arch, n_layers=GRANITE_LAYERS)
+        assert cfg.use_bias and cfg.n_heads == 4 * cfg.n_kv_heads
+        model = build(cfg)
+        params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+        slots, pages, width = 16, GRANITE_PAGES, 64
+    ps, chunk = 16, 256
     caches = jax.eval_shape(
-        lambda: model.init_paged_caches(slots, N_PAGES, ps, BF16))
+        lambda: model.init_paged_caches(slots, pages, ps, BF16))
     place = lambda t: jax.tree.map(
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), t)
     s = lambda shape, dt=I32: jax.ShapeDtypeStruct(shape, dt,
@@ -187,18 +206,28 @@ def _paged_program(program, one_chip):
                 p, t, c, bt, 0, st, cl, final=True)).lower(
                 place(params), place(caches), s((1, chunk)), s((width,)),
                 s(()), s(()))
-        return low.compile().as_text(), cfg
+        compiled = low.compile()
+        return compiled.as_text(), cfg, pages, compiled.memory_analysis()
     finally:
         ops._BACKEND = saved
 
 
-@pytest.mark.parametrize("program", ["decode", "chunk"])
-def test_layer_scan_updates_pool_in_place_for_v5e(one_chip, program):
-    text, cfg = _paged_program(program, one_chip)
+@pytest.mark.parametrize("arch,program", [
+    pytest.param("olmo-1b", "decode", id="decode"),
+    pytest.param("olmo-1b", "chunk", id="chunk"),
+    pytest.param("granite-8b", "decode", id="granite-decode"),
+    pytest.param("granite-8b", "chunk", id="granite-chunk")])
+def test_layer_scan_updates_pool_in_place_for_v5e(one_chip, arch, program):
+    """Granite's programs also take every projection's bias through the
+    bdmm epilogue (the decode grid at 16 rows, the general grid at a
+    256-token chunk) and the GQA kernels at 4 query heads per KV head.
+    Their temporaries stay under one layer's pool: nothing pool-sized is
+    made beside the carried pools."""
+    text, cfg, pages, mem = _paged_program(program, one_chip, arch)
     tail = f"16,{cfg.n_kv_heads},{cfg.hd}]"
-    layer_pool = re.compile(rf"\[(?:1,)?{N_PAGES},{tail}")
-    whole_pool = re.compile(
-        rf"\[(?:{N_LAYERS},{N_PAGES}|{N_LAYERS * N_PAGES}),{tail}")
+    layer_pool = re.compile(rf"\[(?:1,)?{pages},{tail}")
+    n_l = cfg.n_layers
+    whole_pool = re.compile(rf"\[(?:{n_l},{pages}|{n_l * pages}),{tail}")
     comps, calls = _computations(text)
     assert "tpu_custom_call" in text
     ins = [(c, op, shape, line) for c, lines in comps.items()
@@ -217,3 +246,7 @@ def test_layer_scan_updates_pool_in_place_for_v5e(one_chip, program):
     copies = [line for _, op, shape, line in ins
               if op in ("copy", "copy-start") and pool_sized(shape)]
     assert len(copies) <= 2, copies       # at most one per pool (K and V)
+    layer_pool_bytes = pages * 16 * cfg.n_kv_heads * cfg.hd * 2
+    print(f"{arch} {program}: temporaries {mem.temp_size_in_bytes} B, "
+          f"one layer's pool {layer_pool_bytes} B")
+    assert mem.temp_size_in_bytes < layer_pool_bytes
