@@ -33,6 +33,11 @@ config, so a reloaded checkpoint re-derives it (see
    quantization" combined pipeline, enabled by the block structure (dense,
    aligned blocks make per-``(block, channel)`` scales natural).
 
+5. for serving, keep each packed bias in packed order too (:func:`pack_biases`):
+   the serving engine does it when it loads its weights, so no program
+   gathers a bias at run time; checkpoints keep ``b`` alone, in natural
+   order.
+
 Model structure is walked through :meth:`repro.models.Model._block_linears`
 (late import — core stays importable without the model zoo).
 """
@@ -282,6 +287,39 @@ def map_quantized_leaves(model_pk, params, fn):
         if "w_q" in leaf:
             parent[key] = dict(leaf, w_q=fn(leaf["w_q"], lin))
     return out
+
+
+def pack_biases(model_pk, params) -> Tuple[Dict[str, Any], int]:
+    """Give every packed linear's bias a copy in the kernel's packed output
+    order, for serving.
+
+    Each leaf holding ``b`` comes back holding ``bp = b[..., invert(out_perm)]``
+    beside it (stacked leading axes kept), made with the spec's current
+    ``out_perm``, so leaves :func:`apply_perm_fusion` rewrote come out right.
+    :func:`repro.core.mpd.packed_bias` then hands ``bp`` to the epilogue
+    as it is, where a leaf with ``b`` alone is gathered on every call.
+    ``b`` stays the source: ``bp`` is made from it anew on every call of
+    the pass, so a tree whose ``b`` was edited serves the edit. All the
+    gathers run as one jitted call; every other leaf of the result is the
+    input's own array.
+
+    Returns ``(params, n)``, ``n`` the number of per-layer biases the
+    result holds in packed order (a stacked leaf counts once per layer).
+    Training and checkpoints keep ``b`` alone, in natural order.
+    """
+    out = _copy_tree(params)
+    todo, n = [], 0
+    for parent, key, lin, _tag in _iter_packed_leaves(model_pk, out):
+        if lin.spec.use_bias:
+            todo.append((parent, key, permute.invert(lin.spec.mask.out_perm)))
+            n += int(np.prod(np.shape(parent[key]["b"])[:-1]))
+    if todo:
+        gather = jax.jit(lambda bs: [permute.apply(inv, b) for b, (_, _, inv)
+                                     in zip(bs, todo)])
+        packed = gather([parent[key]["b"] for parent, key, _ in todo])
+        for (parent, key, _), bp in zip(todo, packed):
+            parent[key] = dict(parent[key], bp=bp)
+    return out, n
 
 
 def apply_perm_fusion(model_pk, params: Optional[Dict[str, Any]] = None):

@@ -133,9 +133,11 @@ def apply(spec: MPDLinearSpec, params: Params, x, *,
     dispatch on the Pallas routes — instead of composing as separate XLA
     ops around it. ``extra_bias`` lets callers fold an additional additive
     term into the same epilogue (e.g. Mamba's ``dt_bias``); it combines
-    with the layer's own bias when both exist. On the packed mode the bias
-    is re-indexed into packed order (epilogues run pre-unpack; elementwise
-    activations commute with the output permutation).
+    with the layer's own bias when both exist. On the packed mode the
+    epilogue takes the bias in packed order (it runs pre-unpack;
+    elementwise activations commute with the output permutation), from
+    :func:`packed_bias`: the ``bp`` leaf the serving engine makes beside
+    ``b`` when it loads its weights, or ``b`` gathered on this call.
     """
     from repro.kernels import ops, ref  # late import: kernels optional at import time
 
@@ -154,7 +156,7 @@ def apply(spec: MPDLinearSpec, params: Params, x, *,
     else:  # packed
         m = spec.mask
         xp = fold_lib.pack_inputs(m, x, skip=spec.skip_in_perm)
-        bp = None if b is None else permute.apply(permute.invert(m.out_perm), b)
+        bp = packed_bias(spec, params, b)
         from repro.kernels.quant import is_quantized
         if is_quantized(params):
             # quantized deployment artifact (repro.core.export quantize
@@ -168,6 +170,24 @@ def apply(spec: MPDLinearSpec, params: Params, x, *,
                           precision=precision)
         y = fold_lib.unpack_outputs(m, yp, skip=spec.skip_out_perm)
     return y
+
+
+def packed_bias(spec: MPDLinearSpec, params: Params, b):
+    """``b``, a packed linear's bias in natural order, in the kernel's
+    packed output order (``b[..., invert(out_perm)]``); ``None`` stays
+    ``None``.
+
+    Where ``b`` is the leaf's own ``b`` and the leaf also holds ``bp``, the
+    same bias already in that order (:func:`repro.core.export.pack_biases`,
+    which the serving engine runs when it loads its weights), ``bp`` is
+    returned as it is. Any other bias is gathered on this call: training
+    and checkpoint trees hold no ``bp``, and a sum with Mamba's
+    ``extra_bias`` is not the leaf's own."""
+    if b is None:
+        return None
+    if "bp" in params and b is params.get("b"):
+        return params["bp"]
+    return permute.apply(permute.invert(spec.mask.out_perm), b)
 
 
 def reapply_mask(spec: MPDLinearSpec, params: Params) -> Params:

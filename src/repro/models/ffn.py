@@ -110,11 +110,13 @@ class FFNSpec:
         return True
 
     def _packed_bias(self, lin, p):
-        """Layer bias re-indexed into the kernel's packed output order."""
+        """Layer bias in the kernel's packed output order: the ``bp`` leaf
+        the serving engine makes when it loads its weights, or ``b``
+        gathered on this call (:func:`repro.core.mpd.packed_bias`)."""
         if not lin.spec.use_bias:
             return None
-        from repro.core import permute
-        return permute.apply(permute.invert(lin.spec.mask.out_perm), p["b"])
+        from repro.core.mpd import packed_bias
+        return packed_bias(lin.spec, p, p["b"])
 
     def _apply_fused(self, params, x):
         from repro.core import fold as fold_lib

@@ -57,6 +57,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.export import pack_biases
 from repro.dist import sharding as dist_sh
 from repro.kernels import ops
 
@@ -122,7 +123,7 @@ class Engine:
                 f"{cfg.name}: the engine serves token frontends only "
                 "(embed-frontend archs have no incremental token stream)")
         self.model = model
-        self.params = params
+        self.params = params        # biases also in packed order (the setter)
         self.n_slots = n_slots
         self.max_len = max_len
         self.paged = paged
@@ -179,7 +180,7 @@ class Engine:
             if model.spec_decode_supported and draft_model.spec_decode_supported:
                 self.spec_active = True
                 self.draft_model = draft_model
-                self.draft_params = draft_params
+                self.draft_params, _ = pack_biases(draft_model, draft_params)
             else:
                 log.info("recurrent blocks cannot re-score a token window — "
                          "speculative decoding disabled, using the plain "
@@ -284,6 +285,21 @@ class Engine:
         # interactive-over-batch preemption needs page eviction: paged only
         self.preemption = bool(preemption) and paged
         self.n_preemptions = 0
+
+    @property
+    def params(self):
+        """The weights every program of this engine runs on: the tree the
+        caller handed in, each packed linear's bias ``b`` with a copy
+        ``bp`` in packed order beside it
+        (:func:`repro.core.export.pack_biases`), so no program gathers a
+        bias. Assigning a tree makes its ``bp`` from its ``b`` anew, so a
+        tree read here, its ``b`` changed and assigned back serves the
+        change."""
+        return self._params
+
+    @params.setter
+    def params(self, params) -> None:
+        self._params, self.n_packed_biases = pack_biases(self.model, params)
 
     # ------------------------------------------------------------ jitted ops
     def _admit_impl(self, params, caches, dev, padded, length, slot, temp,
@@ -481,6 +497,9 @@ class Engine:
             "repro_serve_slots_live": float(self._live.sum()),
             "repro_serve_slots_total": float(self.n_slots),
             "repro_serve_engine_steps_total": float(self.step_count),
+            # per-layer linear biases served in packed order: the load-time
+            # pass engaged (Granite-8B: 36 layers x 7 projections)
+            "repro_serve_packed_biases": float(self.n_packed_biases),
         }
         if self.paged:
             g["repro_serve_kv_pages_allocated"] = float(

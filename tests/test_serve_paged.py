@@ -659,3 +659,92 @@ def test_serving_programs_carry_the_residual_in_float32(arch):
         assert logits.dtype == jnp.bfloat16
         assert [c.dtype for c in new_caches] == [
             c.dtype for c in jax.tree.leaves(caches)]
+
+
+def _gathers(jaxpr, ndim=None):
+    """``gather`` equations of a jaxpr, inside any call or scan; with
+    ``ndim``, those whose operand is a float array of that many axes (1: a
+    layer's bias permuted into packed order; the activations' take 2)."""
+    for e in jaxpr.eqns:
+        x = e.invars[0].aval if e.primitive.name == "gather" else None
+        if x is not None and (ndim is None or (
+                x.ndim == ndim and jnp.issubdtype(x.dtype, jnp.floating))):
+            yield e
+        for v in e.params.values():
+            for sub in (v if isinstance(v, (list, tuple)) else (v,)):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    yield from _gathers(inner, ndim)
+
+
+@pytest.mark.parametrize("arch", ["granite-8b", "olmo-1b"])
+def test_serving_programs_gather_no_bias_once_packed(arch):
+    """On raw parameters every layer of the paged decode step and of a
+    prefill chunk permutes its seven biases into packed order; on the
+    engine's ``pack_biases`` form none does. A model without biases
+    traces the same programs either way."""
+    from repro.core.export import pack_biases
+
+    m = build(common.get_config(arch, smoke=True))
+    raw = jax.eval_shape(m.init, jax.random.PRNGKey(0))
+    packed = jax.eval_shape(lambda p: pack_biases(m, p)[0], raw)
+    caches = jax.eval_shape(lambda: m.init_paged_caches(2, 8, 8))
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)
+
+    def programs(params):
+        decode = jax.make_jaxpr(lambda p, c, t, bt: m.decode_step(
+            p, t, c, block_tables=bt, live=jnp.ones((2,), bool)))(
+            params, caches, i32(2), i32(2, 4))
+        chunk = jax.make_jaxpr(lambda p, c, t, bt: m.prefill_chunk(
+            p, t, c, bt, 0, 0, 8, final=True))(params, caches, i32(1, 16),
+                                                i32(4))
+        return decode, chunk
+
+    for before, after in zip(programs(raw), programs(packed)):
+        if not m.cfg.use_bias:
+            assert str(after) == str(before)
+            continue
+        for program, per_body in ((before, 7), (after, 0)):
+            scans = list(_scans(program.jaxpr))
+            assert scans
+            for e in scans:
+                body = e.params["jaxpr"].jaxpr
+                assert len(list(_gathers(body, ndim=1))) == per_body
+
+
+@pytest.mark.parametrize("arch,per_layer", [("granite-8b", 7), ("olmo-1b", 0)])
+def test_engine_serves_packed_biases(arch, per_layer):
+    """The paged engine holds each packed bias in packed order too,
+    reports how many it serves that way (also on /metrics), serves an edit
+    of ``b`` assigned back through ``params``, and its greedy tokens equal
+    the model's own decode on the raw parameters."""
+    m = build(common.get_config(arch, smoke=True))
+    key = jax.random.PRNGKey(42)   # per-index biases: zeros hide the order
+    raw = jax.tree_util.tree_map_with_path(
+        lambda path, x: x + 0.1 * jax.random.normal(key, x.shape, x.dtype)
+        if getattr(path[-1], "key", None) == "b" else x,
+        m.init(jax.random.PRNGKey(0)))
+    eng = Engine(m, raw, n_slots=2, max_len=64, paged=True, page_size=8)
+    want = m.cfg.n_layers * per_layer
+    assert eng.stats_gauges()["repro_serve_packed_biases"] == want
+    text = eng.metrics.prometheus(extra_gauges=eng.stats_gauges())
+    assert f"\nrepro_serve_packed_biases {want:g}\n" in text
+    n_bp = lambda: sum("bp" in str(k) for k, _ in
+                       jax.tree_util.tree_leaves_with_path(eng.params))
+    assert n_bp() == (7 if per_layer else 0)
+    eng.params = raw                  # weights swapped in are packed too
+    assert n_bp() == (7 if per_layer else 0)
+    if not per_layer:
+        return
+    # ``b`` stays the source: zeroed and assigned back, it zeroes ``bp``
+    eng.params = jax.tree_util.tree_map_with_path(
+        lambda path, x: x * 0 if getattr(path[-1], "key", None) == "b"
+        else x, eng.params)
+    bps = [x for k, x in jax.tree_util.tree_leaves_with_path(eng.params)
+           if "bp" in str(k)]
+    assert len(bps) == 7 and not any(np.asarray(x).any() for x in bps)
+    eng.params = raw
+    reqs = _requests(m.cfg, 4, seed=3)
+    out = eng.run(reqs)
+    for r in reqs:
+        assert out[r.id] == _reference(m, raw, r), r.id

@@ -315,6 +315,7 @@ def test_metrics_and_healthz_endpoints():
                    "repro_serve_queue_depth",
                    "repro_serve_preemptions_total",
                    "repro_serve_kv_pages_free",
+                   "repro_serve_packed_biases 0",
                    "# TYPE repro_serve_ttft_seconds summary"):
         assert series in metrics, series
     assert json.loads(health.split("\r\n\r\n", 1)[1])["n_slots"] == 2

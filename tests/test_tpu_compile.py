@@ -165,10 +165,11 @@ def _paged_program(program, one_chip, arch="olmo-1b"):
     """The compiled paged decode step or final prefill chunk on the Pallas
     route, bf16 K/V: a 3-layer OLMo-1B packed c=8 with the Fig-3 fusion
     and int8 weights, or a 4-layer Granite-8B-Code packed c=8 in bf16 with
-    its biases. Returns the HLO text, the config, the pool's page count
-    and the compiled program's memory analysis."""
+    its biases, also in packed order as the serving engine holds them.
+    Returns the HLO text, the config, the pool's page count and the
+    compiled program's memory analysis."""
     from repro.configs.common import get_config
-    from repro.core.export import quantize_packed
+    from repro.core.export import pack_biases, quantize_packed
     from repro.kernels import ops
     from repro.models import build
 
@@ -184,7 +185,8 @@ def _paged_program(program, one_chip, arch="olmo-1b"):
         cfg = get_config(arch, n_layers=GRANITE_LAYERS)
         assert cfg.use_bias and cfg.n_heads == 4 * cfg.n_kv_heads
         model = build(cfg)
-        params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+        params = jax.eval_shape(
+            lambda: pack_biases(model, model.init(jax.random.PRNGKey(0)))[0])
         slots, pages, width = 16, GRANITE_PAGES, 64
     ps, chunk = 16, 256
     caches = jax.eval_shape(
