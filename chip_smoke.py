@@ -10,7 +10,7 @@ Drives the functions ``python -m repro.launch.serve`` runs
 on ``olmo-1b`` (16 layers, d_model 2048, 16 heads, d_ff 8192, vocab 50304,
 bf16, packed ``mpd_c=8``) with random weights from ``PRNGKey(0)``:
 
-* phase A — packed bf16, ``--paged --page-size 16 --slots 8``;
+* phase A — packed bf16, ``--page-size 16 --slots 8``;
 * phase B — the same traffic with ``--mpd-fuse --quantize int8`` (the fused
   FFN and bdmm kernels on int8 weights).
 
@@ -42,7 +42,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)),
 
 
 def traffic(prompt_len: int) -> list:
-    return ["--arch", "olmo-1b", "--paged", "--page-size", "16",
+    return ["--arch", "olmo-1b", "--page-size", "16",
             "--slots", "8", "--requests", "16", "--rate", "8",
             "--prompt-len", str(prompt_len), "--gen", "32",
             "--shared-prefix", "64"]

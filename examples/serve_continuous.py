@@ -1,13 +1,14 @@
 """Continuous-batching twin of serve_batched.py: a stream of variable-length
 requests flows through the ``repro.serve`` engine — FCFS admission into cache
-slots, bucketed prompt padding, per-request stops — instead of one lockstep
-batch. Greedy output is token-for-token identical to the static path.
+slots, chunked prefill into a KV page pool, per-request stops — instead of one
+lockstep batch.
 
-The closing section re-serves the same stream through the *paged* memory
-model (``Engine(..., paged=True)``: KV page pool + block tables + prefix
-reuse + chunked prefill) and checks the greedy rows match token-for-token.
+The closing section re-decodes the greedy requests one at a time on the
+static path (``prefill`` + ``decode_step`` over a contiguous cache) and checks
+the engine's rows match token-for-token.
 """
 import jax
+import jax.numpy as jnp
 import numpy as np
 
 from repro.models import ModelConfig, build
@@ -32,7 +33,7 @@ requests = [
     for i in range(12)
 ]
 
-engine = Engine(model, params, n_slots=4, max_len=64)
+engine = Engine(model, params, n_slots=4, max_len=64, page_size=8)
 outputs = engine.run(requests)          # submit + step until drained
 
 for req in requests:
@@ -46,16 +47,18 @@ print(f"{s['n_done']} requests, {s['total_tokens']} tokens, "
       f"ttft p50 {s['ttft_p50_s']*1e3:.0f} ms, "
       f"occupancy {s['occupancy_mean']*100:.0f}%")
 
-# same stream through the paged memory model: pages are allocated to actual
-# depth (the dense engine would reserve n_slots x max_len up front), and
-# greedy rows must match the slot-dense engine token-for-token
-paged = Engine(model, params, n_slots=4, max_len=128, paged=True, page_size=8)
-outputs_paged = paged.run(requests)
+# greedy rows are deterministic: the static lockstep decode of each one alone
+# gives the same tokens
+prefill, decode = jax.jit(model.prefill), jax.jit(model.decode_step)
 for req in requests:
-    if req.sampling.temperature == 0:       # greedy rows are deterministic
-        assert outputs_paged[req.id] == outputs[req.id], req.id
-sp = paged.metrics.summary()
-print(f"paged: kv allocated peak {sp['kv_bytes_allocated_peak']/1e3:.0f} KB "
-      f"vs dense reservation {sp['kv_bytes_reserved']/1e3:.0f} KB "
-      f"(greedy rows identical)")
+    if req.sampling.temperature > 0:
+        continue
+    caches = model.init_caches(1, 64)
+    logits, caches = prefill(params, jnp.asarray(req.prompt)[None], caches)
+    toks = [int(jnp.argmax(logits[0]))]
+    while len(toks) < len(outputs[req.id]):
+        logits, caches = decode(params, jnp.asarray(toks[-1:]), caches)
+        toks.append(int(jnp.argmax(logits[0])))
+    assert toks == outputs[req.id], req.id
+print("greedy rows identical to the static decode")
 print("serve_continuous OK")

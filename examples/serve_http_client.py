@@ -3,7 +3,7 @@
 Start a server first, e.g.::
 
     PYTHONPATH=src python -m repro.launch.serve --arch olmo-1b --smoke \
-        --paged --http --port 8077
+        --http --port 8077
 
 then stream two concurrent requests (one per priority class)::
 
@@ -122,7 +122,7 @@ def reference_tokens(ckpt_dir, prompts, max_new):
     model, params = ckpt_lib.load_packed(ckpt_dir)
     max_len = max(len(p) for p in prompts) + max_new
     engine = Engine(model, params, n_slots=len(prompts), max_len=max_len,
-                    paged=True, page_size=8)
+                    page_size=8)
     reqs = [Request(id=i, prompt=np.asarray(p, np.int32),
                     max_new_tokens=max_new) for i, p in enumerate(prompts)]
     return engine.run(reqs)

@@ -481,7 +481,7 @@ def paged_attention(q, k_pages, v_pages, block_tables, lengths):
     :mod:`repro.kernels.paged_attention` for layout). Inference-only — no
     custom VJP: decode never differentiates.
 
-    On the jnp route the oracle is bitwise-stable against the slot-dense
+    On the jnp route the oracle is bitwise-stable against the contiguous
     decode path (the serve exactness contract); the Pallas routes stream
     pages via scalar-prefetched block tables with an online-softmax combine.
     Under an active mesh whose rules shard ``"kv_heads"``, the dispatch runs

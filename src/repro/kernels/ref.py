@@ -129,7 +129,7 @@ def paged_attention_ref(q, k_pages, v_pages, block_tables, lengths):
     Gathers each row's pages into a contiguous KV view and runs exactly the
     dense decode computation (same einsum contraction order, f32 softmax,
     ``-1e30`` masking) — so on the jnp route a paged decode is bitwise
-    identical to the slot-dense decode of the same sequences: masked columns
+    identical to the contiguous decode of the same sequences: masked columns
     exp-underflow to exact zeros, which are exact under any reduction order.
 
     ``q: (B, H, Dh)``; ``k_pages/v_pages: (n_pages, page_size, Kh, Dh)``;

@@ -3,14 +3,13 @@
 Default mode drives the ``repro.serve`` continuous-batching engine from a
 synthetic Poisson request stream: requests with variable prompt/output
 lengths arrive over wall-clock time, are admitted FCFS into cache slots,
-and decode as one fixed-shape batch with per-request stop conditions.
+and decode as one fixed-shape batch with per-request stop conditions. The
+engine keeps K/V in a paged cache (global page pool + block tables +
+prefix-reuse trie + chunked prefill; see ``repro.serve.cache.PagedCache``)
+and the run reports page-level KV accounting next to the latency
+percentiles.
 
-``--paged`` swaps the engine's memory model to the paged KV cache (global
-page pool + block tables + prefix-reuse trie + chunked prefill; see
-``repro.serve.cache.PagedCache``) and reports page-level KV accounting
-next to the latency percentiles.
-
-``--spec-draft <dir>`` (paged only) turns on speculative decoding: the
+``--spec-draft <dir>`` turns on speculative decoding: the
 packed export in ``<dir>`` — typically the target's own MPD-folded int8
 artifact — proposes ``--spec-k`` tokens per step against its own page
 pool, and the target verifies the whole window in one dispatch. Greedy
@@ -23,14 +22,10 @@ from ``POST /v1/generate``, honours ``interactive``/``batch`` priority
 classes (interactive preempts batch under page pressure), applies
 bounded-queue backpressure (``--queue-limit`` -> 429 + Retry-After), and
 exposes ``GET /metrics`` (Prometheus text) + ``GET /healthz``. Composes
-with ``--paged`` / ``--spec-draft``.
+with ``--spec-draft``.
 
-``--static`` keeps the legacy path: prefill one fixed batch, decode it in
-lockstep (no admission, no per-request stop) — the baseline the engine is
-benchmarked against in ``benchmarks/serve_bench.py``.
-
-On a real slice pass a mesh via ``repro.dist`` (engine slot caches shard
-through ``Model.slot_cache_axes()`` + the active rule table).
+On a real slice pass a mesh via ``repro.dist`` (the engine's page pools
+shard through ``Model.paged_cache_axes()`` + the active rule table).
 """
 
 import argparse
@@ -40,7 +35,6 @@ import logging
 import time
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.common import ARCHS, get_config
@@ -48,45 +42,6 @@ from repro.data import SyntheticLM
 from repro.models import build
 
 log = logging.getLogger("repro.serve.launch")
-
-
-def _static_main(args, cfg, model, params):
-    """Legacy static-batch path: one prefill, lockstep decode."""
-    maxlen = args.prompt_len + args.gen
-    if cfg.frontend == "token":
-        data = SyntheticLM(vocab=cfg.vocab, seq_len=args.prompt_len,
-                           global_batch=args.batch, seed=0)
-        prompts = jnp.asarray(data.next()["inputs"])
-    else:
-        prompts = jax.random.normal(
-            jax.random.PRNGKey(1), (args.batch, args.prompt_len, cfg.d_model))
-
-    caches = model.init_caches(args.batch, maxlen)
-    prefill = jax.jit(model.prefill)
-    decode = jax.jit(model.decode_step)
-
-    t0 = time.perf_counter()
-    logits, caches = prefill(params, prompts, caches)
-    jax.block_until_ready(logits)
-    log.info("prefill %dx%d: %.1f ms", args.batch, args.prompt_len,
-             (time.perf_counter() - t0) * 1e3)
-
-    if cfg.frontend != "token":
-        # embed frontends have no incremental token stream to feed back;
-        # timing an empty loop would report a bogus decode rate.
-        log.info("decode: skipped (embed frontend — no autoregressive "
-                 "token stream)")
-        return
-
-    tok = jnp.argmax(logits, -1)
-    t0 = time.perf_counter()
-    for _ in range(args.gen - 1):
-        logits, caches = decode(params, tok, caches)
-        tok = jnp.argmax(logits, -1)
-    jax.block_until_ready(tok)
-    dt = time.perf_counter() - t0
-    log.info("decode %d steps: %.1f ms (%.0f tok/s)", args.gen - 1,
-             dt * 1e3, args.batch * (args.gen - 1) / max(dt, 1e-9))
 
 
 def make_requests(cfg, *, n_requests, rate, prompt_len, gen, seed=0,
@@ -97,7 +52,7 @@ def make_requests(cfg, *, n_requests, rate, prompt_len, gen, seed=0,
     budgets in [min_gen, gen] (the minima default to half the maxima).
     ``shared_prefix`` forces the first that many prompt tokens identical
     across the first ``n_shared`` requests (all by default; system-prompt
-    shape), so the paged engine's prefix trie gets real hits."""
+    shape), so the engine's prefix trie gets real hits."""
     from repro.serve import Request, SamplingParams
 
     if rate <= 0:
@@ -188,23 +143,17 @@ def _build_engine(args, model, params, *, chaos=True, device=None):
     from repro.serve import Engine
 
     max_len = args.prompt_len + args.gen
-    if args.spec_draft and not args.paged:
-        raise SystemExit("--spec-draft requires --paged (the verify window "
-                         "scatters into paged KV)")
     res = _build_resilience(args, chaos=chaos)
-    if args.paged:
-        spec_draft = _load_spec_draft(args) if args.spec_draft else None
+    spec_draft = _load_spec_draft(args) if args.spec_draft else None
+    try:
         engine = Engine(model, params, n_slots=args.slots, max_len=max_len,
-                        paged=True, page_size=args.page_size,
-                        n_pages=args.pages or None,
+                        page_size=args.page_size, n_pages=args.pages or None,
                         prefill_chunk_tokens=args.prefill_chunk or None,
                         spec_draft=spec_draft, spec_k=args.spec_k,
                         resilience=res, device=device)
-        mode = "paged+spec" if engine.spec_active else "paged"
-    else:
-        engine = Engine(model, params, n_slots=args.slots, max_len=max_len,
-                        resilience=res, device=device)
-        mode = "continuous"
+    except ValueError as e:
+        raise SystemExit(str(e))
+    mode = "paged+spec" if engine.spec_active else "paged"
     return engine, mode
 
 
@@ -289,23 +238,22 @@ def _continuous_main(args, cfg, model, params):
              summary["ttft_p95_s"] * 1e3, summary["queue_wait_p50_s"] * 1e3,
              summary["queue_wait_p95_s"] * 1e3, summary["e2e_p50_s"] * 1e3,
              summary["e2e_p95_s"] * 1e3, summary["occupancy_mean"] * 100)
-    if args.paged:
-        c = eng0.cache
-        log.info("paged kv: page_size=%d, pool=%d pages/replica; allocated "
-                 "peak %.2f MB vs dense reservation %.2f MB; prefill tokens "
-                 "computed %d (+%d reused via prefix cache); prefill kv "
-                 "read %.2f MB [%s kernel]",
-                 c.page_size, c.n_pages,
-                 summary["kv_bytes_allocated_peak"] / 1e6,
-                 summary["kv_bytes_reserved"] / 1e6,
-                 eng0.n_prefill_tokens, eng0.n_prefill_tokens_skipped,
-                 summary["prefill_kv_bytes_read"] / 1e6,
-                 ops.prefill_backend())
-        if eng0.spec_active:
-            log.info("spec decode: k=%d, %.2f tokens/step, %.0f%% draft "
-                     "acceptance", eng0.spec_k,
-                     summary["tokens_per_step_mean"],
-                     summary["draft_acceptance_rate"] * 100)
+    c = eng0.cache
+    log.info("paged kv: page_size=%d, pool=%d pages/replica; allocated "
+             "peak %.2f MB vs dense reservation %.2f MB; prefill tokens "
+             "computed %d (+%d reused via prefix cache); prefill kv "
+             "read %.2f MB [%s kernel]",
+             c.page_size, c.n_pages,
+             summary["kv_bytes_allocated_peak"] / 1e6,
+             summary["kv_bytes_reserved"] / 1e6,
+             eng0.n_prefill_tokens, eng0.n_prefill_tokens_skipped,
+             summary["prefill_kv_bytes_read"] / 1e6,
+             ops.prefill_backend())
+    if eng0.spec_active:
+        log.info("spec decode: k=%d, %.2f tokens/step, %.0f%% draft "
+                 "acceptance", eng0.spec_k,
+                 summary["tokens_per_step_mean"],
+                 summary["draft_acceptance_rate"] * 100)
     if hasattr(engine, "replicas"):
         log.info("router: %d replicas (%d live), affinity hit rate %.0f%%, "
                  "%d handoffs, per-replica busy %s s",
@@ -449,9 +397,6 @@ def build_parser() -> argparse.ArgumentParser:
     p = argparse.ArgumentParser()
     p.add_argument("--arch", choices=ARCHS, required=True)
     p.add_argument("--smoke", action="store_true")
-    p.add_argument("--static", action="store_true",
-                   help="legacy fixed-batch lockstep path")
-    p.add_argument("--batch", type=int, default=4, help="static-mode batch")
     p.add_argument("--prompt-len", type=int, default=32)
     p.add_argument("--gen", type=int, default=16)
     p.add_argument("--requests", type=int, default=16,
@@ -460,21 +405,16 @@ def build_parser() -> argparse.ArgumentParser:
                    help="continuous-mode Poisson arrival rate (req/s)")
     p.add_argument("--slots", type=int, default=4,
                    help="continuous-mode decode slots")
-    p.add_argument("--paged", action="store_true",
-                   help="paged KV cache: page-pool memory, block tables, "
-                   "prefix reuse, chunked prefill (vs dense per-slot "
-                   "max_len reservation)")
     p.add_argument("--page-size", type=int, default=16,
-                   help="paged-mode tokens per KV page")
+                   help="tokens per KV page")
     p.add_argument("--pages", type=int, default=0,
-                   help="paged-mode pool size; 0 = dense-equivalent "
+                   help="KV page-pool size; 0 = dense-equivalent "
                    "(n_slots * max_len / page_size + 1)")
     p.add_argument("--prefill-chunk", type=int, default=0,
-                   help="paged-mode prefill chunk tokens (page multiple); "
-                   "0 = 4 pages")
+                   help="prefill chunk tokens (page multiple); 0 = 4 pages")
     p.add_argument("--prefill-kernel", default="",
                    choices=("", "pallas", "interpret", "jnp"),
-                   help="chunked-prefill attention backend (paged mode): "
+                   help="chunked-prefill attention backend: "
                    "pallas = flash paged-prefill kernel (TPU), interpret = "
                    "same kernel in Pallas interpret mode (CPU-testable, "
                    "slow), jnp = dense gather oracle (bitwise-stable "
@@ -482,9 +422,9 @@ def build_parser() -> argparse.ArgumentParser:
                    "kernel backend")
     p.add_argument("--shared-prefix", type=int, default=0,
                    help="force the first N prompt tokens identical across "
-                   "synthetic requests (exercises the paged prefix trie)")
+                   "synthetic requests (exercises the prefix trie)")
     p.add_argument("--spec-draft", default="",
-                   help="speculative decoding (requires --paged): directory "
+                   help="speculative decoding: directory "
                    "with a packed export to deploy as the draft model — "
                    "typically the target's own MPD-folded int8 artifact")
     p.add_argument("--spec-k", type=int, default=4,
@@ -508,7 +448,7 @@ def build_parser() -> argparse.ArgumentParser:
                    "paged attention kernels M-way over a 'model' mesh axis "
                    "(greedy output stays bit-identical to --tp 1)")
     p.add_argument("--disagg", action="store_true",
-                   help="prefill/decode disaggregation (needs --paged and "
+                   help="prefill/decode disaggregation (needs "
                    "--replicas >= 2): dedicated prefill replicas hand "
                    "requests to decode replicas at the first token, "
                    "migrating KV pages through the router")
@@ -557,16 +497,7 @@ def main(argv=None):
     cfg0 = get_config(args.arch, smoke=args.smoke)
     if not cfg0.causal:
         raise SystemExit(f"{args.arch} is encoder-only (no decode)")
-    if args.static and args.paged:
-        raise SystemExit("--static and --paged are mutually exclusive "
-                         "(paged is a continuous-engine memory model)")
-    if args.http and args.static:
-        raise SystemExit("--http serves the continuous engine; it cannot "
-                         "combine with --static")
     if args.prefill_kernel:
-        if not args.paged:
-            raise SystemExit("--prefill-kernel routes paged chunked "
-                             "prefill; combine with --paged")
         # must happen before the engine builds/warms its jits — the
         # backend is read at trace time
         from repro.kernels import ops
@@ -575,14 +506,9 @@ def main(argv=None):
         raise SystemExit(f"--replicas must be >= 1, got {args.replicas}")
     if args.tp < 1:
         raise SystemExit(f"--tp must be >= 1, got {args.tp}")
-    if args.replicas > 1 and args.static:
-        raise SystemExit("--replicas routes the continuous engine; it "
-                         "cannot combine with --static")
     if args.disagg and args.replicas < 2:
         raise SystemExit("--disagg needs --replicas >= 2 (dedicated "
                          "prefill and decode replicas)")
-    if args.disagg and not args.paged:
-        raise SystemExit("--disagg migrates KV pages; combine with --paged")
     if args.disagg and args.spec_draft:
         raise SystemExit("--disagg cannot combine with --spec-draft (the "
                          "draft page pool is not migrated)")
@@ -602,20 +528,13 @@ def main(argv=None):
     log.info("serving %s: %s params (mode=%s)", cfg.name,
              f"{model.param_count():,}", cfg.mpd_mode)
 
-    if args.static:
-        _static_main(args, cfg, model, params)
-    else:
-        if cfg.frontend != "token":
-            raise SystemExit(
-                f"{args.arch} has an embed frontend — the continuous engine "
-                "serves token streams; use --static for prefill timing")
-        # the mesh context stays active for the whole serving lifetime:
-        # engines capture it at construction and re-enter it per step
-        with _mesh_ctx(args):
-            if args.http:
-                _http_main(args, cfg, model, params)
-            else:
-                _continuous_main(args, cfg, model, params)
+    # the mesh context stays active for the whole serving lifetime:
+    # engines capture it at construction and re-enter it per step
+    with _mesh_ctx(args):
+        if args.http:
+            _http_main(args, cfg, model, params)
+        else:
+            _continuous_main(args, cfg, model, params)
 
 
 if __name__ == "__main__":

@@ -206,15 +206,6 @@ def init_paged_cache(spec: AttentionSpec, n_slots: int, n_pages: int,
             "pos": jnp.zeros((n_slots,), jnp.int32)}
 
 
-def _update_rows(cache, new, pos):
-    """Write ``new`` (B, 1, Kh, Dh) into ``cache`` (B, S, Kh, Dh) at a
-    *per-row* sequence position ``pos`` (B,) — the slot-cache write used by
-    continuous batching, where every slot sits at its own depth."""
-    return jax.vmap(
-        lambda c, n, p: jax.lax.dynamic_update_slice_in_dim(c, n, p, axis=0)
-    )(cache, new, pos)
-
-
 def _flat_pool(pool):
     """The stacked page pool ``(L, n_pages, page_size, Kh, Dh)`` as one pool
     of ``L * n_pages`` pages, in which layer ``l``'s page ``i`` is page
@@ -228,10 +219,9 @@ def _flat_pool(pool):
 def apply_decode(spec: AttentionSpec, params, x, cache):
     """One decode step. x: (B, 1, D); cache K/V: (B, S, Kh, Dh).
 
-    ``cache["pos"]`` is either a scalar (lockstep static batch — every row at
-    the same depth) or a (B,) vector (slot-based continuous batching — every
-    row advances independently; RoPE, the K/V write, and the validity mask
-    are all per-row).
+    ``cache["pos"]`` is a scalar: a lockstep static batch, every row at the
+    same depth (the engine's per-slot depths live in the paged cache, see
+    :func:`apply_decode_paged`).
 
     When the cache's S axis is sharded (long-context cells), the f32 softmax
     reductions below are partitioned by GSPMD into per-shard partials plus an
@@ -240,20 +230,15 @@ def apply_decode(spec: AttentionSpec, params, x, cache):
     B, T, _ = x.shape
     assert T == 1
     pos = cache["pos"]
-    per_row = jnp.ndim(pos) == 1
-    pos_b = pos if per_row else jnp.broadcast_to(pos[None], (B,))
+    pos_b = jnp.broadcast_to(pos[None], (B,))
     if spec.rope == "mrope":
         p = pos_b[:, None]
         positions = jnp.stack([p, p, p])
     else:
         positions = pos_b[:, None]
     q, k_new, v_new = _qkv(spec, params, x, positions)
-    if per_row:
-        k = _update_rows(cache["k"], k_new.astype(cache["k"].dtype), pos)
-        v = _update_rows(cache["v"], v_new.astype(cache["v"].dtype), pos)
-    else:
-        k = jax.lax.dynamic_update_slice_in_dim(cache["k"], k_new.astype(cache["k"].dtype), pos, axis=1)
-        v = jax.lax.dynamic_update_slice_in_dim(cache["v"], v_new.astype(cache["v"].dtype), pos, axis=1)
+    k = jax.lax.dynamic_update_slice_in_dim(cache["k"], k_new.astype(cache["k"].dtype), pos, axis=1)
+    v = jax.lax.dynamic_update_slice_in_dim(cache["v"], v_new.astype(cache["v"].dtype), pos, axis=1)
     S = k.shape[1]
     kv_valid = jnp.arange(S)[None, :] <= pos_b[:, None]
     o = _attend(q, k.astype(q.dtype), v.astype(q.dtype),
@@ -277,13 +262,11 @@ def apply_decode_paged(spec: AttentionSpec, params, x, cache, layer,
     sequences, Pallas-route an online-softmax page stream.
 
     ``live`` (B,) bool masks rows that are actually decoding. This is
-    load-bearing, not hygiene: unlike the slot-dense cache (where a dead
-    row scatters harmlessly into its own reservation), the pool is shared
-    — a non-live row (mid-chunked-prefill, or freshly admitted with a
-    stale ``pos``) holds a real block table, and its clipped page index
-    can alias onto an already-prefilled (possibly trie-shared) page.
-    Non-live rows scatter to the layer's null page and their ``pos``
-    freezes.
+    load-bearing, not hygiene: the pool is shared — a non-live row
+    (mid-chunked-prefill, or freshly admitted with a stale ``pos``) holds a
+    real block table, and its clipped page index can alias onto an
+    already-prefilled (possibly trie-shared) page. Non-live rows scatter to
+    the layer's null page and their ``pos`` freezes.
     """
     from repro.kernels import ops
 

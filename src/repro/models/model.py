@@ -120,7 +120,7 @@ def scan_layers(body, x, params, cache):
     cache's page pools (``POOL_LEAVES``) ride the scan's carry whole: ``c``
     holds them with their layer axis, and the body writes and reads its own
     layer's pages in place, so no step copies a layer's pool out or back.
-    Every other cache leaf (``pos``, dense slot caches, recurrent state) is
+    Every other cache leaf (``pos``, contiguous K/V, recurrent state) is
     sliced per layer and stacked back."""
     pools = {k: v for k, v in cache.items() if k in POOL_LEAVES}
     rows = {k: v for k, v in cache.items() if k not in POOL_LEAVES}
@@ -382,20 +382,6 @@ class Model:
             )
         return caches
 
-    def init_slot_caches(self, n_slots: int, max_len: int, dtype=None):
-        """Slot-major decode caches for the continuous-batching engine
-        (``repro.serve``): identical to :meth:`init_caches` except the
-        attention ``pos`` counter is per-slot, shape (layers, n_slots), so
-        every slot advances at its own depth (see ``apply_decode``)."""
-        caches = self.init_caches(n_slots, max_len, dtype)
-        out = []
-        for spec, c in zip(self.block_specs, caches):
-            if spec["kind"] in ("attn", "attn_moe"):
-                c = dict(c, pos=jnp.zeros((c["pos"].shape[0], n_slots),
-                                          jnp.int32))
-            out.append(c)
-        return out
-
     def init_paged_caches(self, n_slots: int, n_pages: int, page_size: int,
                           dtype=None):
         """Paged decode caches for the continuous-batching engine: attention
@@ -403,8 +389,8 @@ class Model:
         per layer (page 0 reserved as the null page), indexed per request by
         a block table the engine owns; ``pos`` stays per-slot. Recurrent
         layers (mamba/rwkv) carry O(1) state, i.e. a single *pinned page*
-        per slot — identical rows to :meth:`init_slot_caches` — so the
-        engine drives all three block families uniformly."""
+        per slot — the rows of :meth:`init_caches` — so the engine drives
+        all three block families uniformly."""
         if dtype is None:
             dtype = self.cfg.jdtype
         caches = []
@@ -429,21 +415,11 @@ class Model:
         every block-table lookup into a collective); KV heads shard as
         usual, recurrent pinned pages ride the ``batch`` rules."""
         axes = []
-        for spec, a in zip(self.block_specs, self.slot_cache_axes()):
+        for spec, a in zip(self.block_specs, self.cache_axes()):
             if spec["kind"] in ("attn", "attn_moe"):
                 a = {"kp": ("layers", None, None, "kv_heads", None),
                      "vp": ("layers", None, None, "kv_heads", None),
                      "pos": ("layers", "batch")}
-            axes.append(a)
-        return axes
-
-    def slot_cache_axes(self):
-        """Logical axes matching :meth:`init_slot_caches` (the per-slot axis
-        is the cache "batch" axis, so slot caches shard like batch)."""
-        axes = []
-        for spec, a in zip(self.block_specs, self.cache_axes()):
-            if spec["kind"] in ("attn", "attn_moe"):
-                a = dict(a, pos=("layers", "batch"))
             axes.append(a)
         return axes
 
@@ -700,25 +676,12 @@ class Model:
         lg = self.unembed.apply(params["unembed"], x_last)
         return lg, new_caches
 
-    def prefill(self, params, inputs, caches, lengths=None):
+    def prefill(self, params, inputs, caches):
         """Process a full prompt, filling caches. Returns (last-token logits,
-        caches). inputs: (B,T) tokens or (B,T,D) embeds.
-
-        ``lengths`` (B,) enables right-padded prompts with per-row true
-        lengths (continuous-batching admission with bucketed padding): the
-        returned logits are read at each row's last *real* token, recurrent
-        states freeze at padded steps, and the attention cache ``pos``
-        becomes a per-row vector — exactly the state an unpadded prefill of
-        each row would produce. Padding must be on the right; padded K/V
-        entries are written but masked by ``pos`` during decode.
-        """
+        caches). inputs: (B,T) tokens or (B,T,D) embeds."""
         cfg = self.cfg
         x = self._stream(self._embed_inputs(params, inputs))
         B, T = x.shape[:2]
-        valid = None
-        if lengths is not None:
-            lengths = jnp.asarray(lengths, jnp.int32)
-            valid = jnp.arange(T)[None, :] < lengths[:, None]    # (B, T)
         new_caches = []
         for spec, pstack, cstack in zip(self.block_specs, params["blocks"], caches):
             kind = spec["kind"]
@@ -740,21 +703,18 @@ class Model:
                     y = spec["mixer"].wo.apply(p["mixer"]["wo"],
                                                o.reshape(B, T, -1))
                     x = x + y
-                    c2 = {"k": kc, "v": vc,
-                          "pos": (jnp.asarray(T, jnp.int32) if lengths is None
-                                  else lengths)}
+                    c2 = {"k": kc, "v": vc, "pos": jnp.asarray(T, jnp.int32)}
                 elif kind in ("mamba", "mamba_moe"):
-                    y, c2 = spec["mixer"].apply(p["mixer"], h, None, valid=valid)
+                    y, c2 = spec["mixer"].apply(p["mixer"], h, None)
                     x = x + y
                 else:
                     mix = spec["mixer"]
                     st = mix.init_state(B, cfg.jdtype)
                     y, s_new, x_tm = mix.time_mix(p["mixer"], h, st["S"],
-                                                  st["x_tm"], valid=valid)
+                                                  st["x_tm"])
                     x = x + y
                     h2 = self._norm(p["norm2"], x)
-                    y2, x_cm = mix.channel_mix(p["mixer"], h2, st["x_cm"],
-                                               valid=valid)
+                    y2, x_cm = mix.channel_mix(p["mixer"], h2, st["x_cm"])
                     return x + y2, {"S": s_new, "x_tm": x_tm, "x_cm": x_cm}
                 h2 = self._norm(p["norm2"], x)
                 if kind.endswith("_moe"):
@@ -766,12 +726,7 @@ class Model:
             x, c_new = jax.lax.scan(body, x, (pstack, cstack))
             new_caches.append(c_new)
         x = self._norm(params["final_norm"], x)
-        if lengths is None:
-            x_last = x[:, -1]
-        else:
-            x_last = jnp.take_along_axis(
-                x, (lengths - 1)[:, None, None], axis=1)[:, 0]
-        lg = self.unembed.apply(params["unembed"], x_last)
+        lg = self.unembed.apply(params["unembed"], x[:, -1])
         return lg, new_caches
 
     # -------------------------------------------------- mask projection

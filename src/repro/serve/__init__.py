@@ -1,18 +1,17 @@
 """repro.serve — continuous-batching inference engine.
 
-Serving on top of the model zoo's ``prefill`` / ``decode_step``: a
+Serving on top of the model zoo's ``prefill_chunk`` / ``decode_step``: a
 fixed-shape decode batch of ``n_slots`` sequences, priority-class
 admission (interactive/batch, FCFS within a class, preemption by page
 eviction under pressure), per-request sampling/stop, and caches that
 shard through ``repro.dist`` logical-axis rules. ``server.GenerateServer``
-puts an HTTP/SSE streaming frontend in front of the engine. Two memory models (see ``engine.Engine``): slot-dense
-(``SlotCache`` — per-slot ``max_len`` reservation, bucketed one-shot
-prefill) and paged (``PagedCache`` — global KV page pool, block tables,
-ref-counted prefix reuse, chunked prefill, paged-attention decode).
-Speculative decoding (``Engine(..., spec_draft=(model, params))``) rides
-on the paged model: a draft proposes k tokens against its own page pool,
-the target verifies the window in one dispatch, and draft+target share
-one prefix trie.
+puts an HTTP/SSE streaming frontend in front of the engine. The engine
+keeps K/V in a paged cache (``PagedCache`` — global KV page pool, block
+tables, ref-counted prefix reuse, chunked prefill, paged-attention
+decode). Speculative decoding (``Engine(..., spec_draft=(model, params))``)
+adds a draft that proposes k tokens against its own page pool; the target
+verifies the window in one dispatch, and draft+target share one prefix
+trie.
 
 ``resilience`` adds the fault-tolerance layer: a deterministic seeded
 ``FaultInjector`` (chaos testing), a per-slot watchdog that quarantines
@@ -22,8 +21,8 @@ deadline/retry policy — all bundled into ``Resilience`` and passed as
 ``Engine(..., resilience=...)``.
 """
 
-from .cache import (PagedCache, PagePool, PrefixTrie, SlotCache,
-                    publish_prefix_shared, share_trie)
+from .cache import (PagedCache, PagePool, PrefixTrie, publish_prefix_shared,
+                    share_trie)
 from .engine import Engine, Handoff
 from .metrics import (RequestMetrics, RouterMetrics, ServeMetrics,
                       merge_request_metrics, render_prometheus)
@@ -32,18 +31,17 @@ from .resilience import (STAGE_NAMES, DegradationLadder, FaultInjector,
                          FaultSpec, InjectedFault, Resilience, parse_schedule,
                          storm_schedule)
 from .sampling import SamplingParams, sample, spec_accept
-from .scheduler import (PRIORITIES, Request, RequestState, Scheduler,
-                        make_buckets)
+from .scheduler import PRIORITIES, Request, RequestState, Scheduler
 from .server import GenerateServer
 
 __all__ = [
-    "Engine", "SlotCache", "PagedCache", "PagePool", "PrefixTrie",
+    "Engine", "PagedCache", "PagePool", "PrefixTrie",
     "share_trie", "publish_prefix_shared",
     "ServeMetrics", "RequestMetrics", "RouterMetrics", "GenerateServer",
     "Router", "Handoff", "prefix_affinity_key", "render_prometheus",
     "merge_request_metrics",
     "SamplingParams", "sample", "spec_accept", "Request", "RequestState",
-    "Scheduler", "make_buckets", "PRIORITIES",
+    "Scheduler", "PRIORITIES",
     "FaultInjector", "FaultSpec", "InjectedFault", "DegradationLadder",
     "Resilience", "parse_schedule", "storm_schedule", "STAGE_NAMES",
 ]

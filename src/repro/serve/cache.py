@@ -1,26 +1,19 @@
-"""Decode-cache managers for the continuous-batching engine.
+"""The paged decode cache of the continuous-batching engine.
 
-Two memory models:
-
-* :class:`SlotCache` — slot-dense: every slot reserves ``max_len`` rows of
-  K/V per layer up front. Simple, but HBM cost and decode bandwidth scale
-  with ``max_len`` instead of actual sequence depth.
-* :class:`PagedCache` — paged: attention K/V lives in a global pool of
-  fixed-size pages per layer (the serving-side dual of the paper's
-  block-structured weights), each request holds an ordered list of page
-  ids (its *block table*), a host-side free list hands pages out, and a
-  ref-counted prefix trie keyed on page-aligned prompt chunks lets
-  requests that share a prompt prefix reuse already-prefilled pages.
-  Cached pages are immutable — extending a shared prefix allocates fresh
-  pages (copy-on-write without the copy, since sharing is only ever
-  whole-page). Recurrent layers (mamba/rwkv) keep their O(1) state as a
-  single pinned page per slot, so the engine treats all block families
-  uniformly.
+:class:`PagedCache`: attention K/V lives in a global pool of fixed-size
+pages per layer (the serving-side dual of the paper's block-structured
+weights), each request holds an ordered list of page ids (its *block
+table*), a host-side free list hands pages out, and a ref-counted prefix
+trie keyed on page-aligned prompt chunks lets requests that share a
+prompt prefix reuse already-prefilled pages. Cached pages are immutable —
+extending a shared prefix allocates fresh pages (copy-on-write without the
+copy, since sharing is only ever whole-page). Recurrent layers
+(mamba/rwkv) keep their O(1) state as a single pinned page per slot, so
+the engine treats all block families uniformly.
 
 Sharding: leaves are placed via ``repro.dist`` logical-axis rules
-(``Model.slot_cache_axes()`` / ``Model.paged_cache_axes()``) when a mesh
-is active — KV heads shard as usual; the page axis stays unsharded (pages
-are fetched by id).
+(``Model.paged_cache_axes()``) when a mesh is active — KV heads shard as
+usual; the page axis stays unsharded (pages are fetched by id).
 """
 
 from __future__ import annotations
@@ -29,89 +22,9 @@ import math
 from typing import Any, Dict, List, Optional, Tuple
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 from repro.dist import sharding as sh
-
-
-def _batch_axis_tree(model) -> List[Any]:
-    """Per-leaf index of the slot ("batch") axis, shaped like the caches."""
-    return jax.tree.map(
-        lambda names: names.index("batch"),
-        model.slot_cache_axes(),
-        is_leaf=lambda t: isinstance(t, tuple) and all(
-            x is None or isinstance(x, str) for x in t))
-
-
-class SlotCache:
-    """Owns the device-side slot caches and the two jitted maintenance ops
-    (per-slot writeback, per-slot reset)."""
-
-    def __init__(self, model, n_slots: int, max_len: int, dtype=None):
-        self.model = model
-        self.n_slots = n_slots
-        self.max_len = max_len
-        self.dtype = dtype
-        caches = model.init_slot_caches(n_slots, max_len, dtype)
-        mesh, rules = sh.current()
-        if mesh is not None and rules is not None:
-            placements = sh.tree_shardings(mesh, rules,
-                                           model.slot_cache_axes(), like=caches)
-            caches = jax.device_put(caches, placements)
-        self.caches = caches
-        # attention KV footprint (the dense reservation the paged model is
-        # benchmarked against); recurrent state is excluded — it is the
-        # same fixed size under both memory models
-        self.kv_bytes = sum(
-            c["k"].nbytes + c["v"].nbytes
-            for spec, c in zip(model.block_specs, caches)
-            if spec["kind"] in ("attn", "attn_moe"))
-        self.token_bytes = self.kv_bytes / (n_slots * max_len)
-        self._batch_ix = _batch_axis_tree(model)
-        # jitted lazily: the engine fuses _write_impl into its admission
-        # program, so standalone wrappers are only compiled if actually used
-        self._write = None
-        self._reset = None
-
-    # ----------------------------------------------------------------- ops
-    def _write_impl(self, caches, new, slot):
-        """Write batch-1 prefill caches into row ``slot`` of every leaf."""
-        def upd(big, small, bix):
-            starts = [jnp.zeros((), jnp.int32)] * big.ndim
-            starts[bix] = slot
-            return jax.lax.dynamic_update_slice(
-                big, small.astype(big.dtype), tuple(starts))
-        return jax.tree.map(upd, caches, new, self._batch_ix)
-
-    def _reset_impl(self, caches, slot):
-        """Zero row ``slot`` (hygiene on eviction; admission writeback fully
-        overwrites a slot anyway, so this is optional)."""
-        def upd(big, bix):
-            shape = list(big.shape)
-            shape[bix] = 1
-            starts = [jnp.zeros((), jnp.int32)] * big.ndim
-            starts[bix] = slot
-            return jax.lax.dynamic_update_slice(
-                big, jnp.zeros(shape, big.dtype), tuple(starts))
-        return jax.tree.map(upd, caches, self._batch_ix)
-
-    # ------------------------------------------------------------- interface
-    def write_slot(self, prefill_caches, slot: int) -> None:
-        if self._write is None:
-            self._write = jax.jit(self._write_impl)
-        self.caches = self._write(self.caches, prefill_caches,
-                                  jnp.asarray(slot, jnp.int32))
-
-    def reset_slot(self, slot: int) -> None:
-        if self._reset is None:
-            self._reset = jax.jit(self._reset_impl)
-        self.caches = self._reset(self.caches, jnp.asarray(slot, jnp.int32))
-
-
-# ==========================================================================
-# paged memory model
-# ==========================================================================
 
 NULL_PAGE = 0
 
@@ -363,8 +276,8 @@ class PagedCache:
         # stays exact under any schedule
         self.injector = None
 
-        # bytes accounting (attention K/V only — recurrent state is the
-        # same fixed size under both memory models)
+        # bytes accounting (attention K/V only — recurrent state is a
+        # fixed size per slot whatever the depth)
         page_bytes = 0
         for spec, c in zip(model.block_specs, self.caches):
             if spec["kind"] in ("attn", "attn_moe"):

@@ -1,36 +1,27 @@
-"""Continuous-batching inference engine.
+"""Continuous-batching inference engine over a paged KV cache.
 
 The engine serves a stream of variable-length requests through the model's
-``prefill`` / ``decode_step`` with jit-stable shapes:
+``prefill_chunk`` / ``decode_step`` with jit-stable shapes:
 
 * the decode batch is always ``n_slots`` rows (free slots carry inert
   filler — row-independent block families make their garbage harmless);
-* each step interleaves: admit waiting requests into free slots, (paged
-  mode) run prefill chunks under the token budget, then one batched decode
-  of every live slot with per-slot sampling params and per-request stop
-  conditions (EOS id, max_new_tokens); finished slots are evicted and
-  backfilled from the queue on the next step.
+* each step interleaves: admit waiting requests into free slots, run
+  prefill chunks under the token budget, then one batched decode of every
+  live slot with per-slot sampling params and per-request stop conditions
+  (EOS id, max_new_tokens); finished slots are evicted and backfilled from
+  the queue on the next step.
 
-Two memory models select at construction:
-
-* **slot-dense** (default): admission prefills one request at a time,
-  bucket-padded (one compile per bucket) with the length-aware
-  ``prefill(lengths=...)``, samples the first token in the same dispatch,
-  then writes the batch-1 caches into the assigned slot
-  (:class:`SlotCache`). One blocking dispatch per admission; every slot
-  reserves ``max_len`` KV rows.
-* **paged** (``paged=True``): attention K/V lives in a global page pool
-  (:class:`PagedCache`). Admission only builds the request's block table
-  (reusing trie-cached prefix pages — a shared system prompt is prefilled
-  once); the prompt is then processed in fixed-shape page-multiple
-  *chunks* interleaved with decode under a per-step token budget, so a
-  long prompt never head-of-line-blocks running decodes and there is no
-  largest-bucket rejection (ONE prefill compile total, vs one per
-  bucket). Decode runs through the paged-attention op over an *active*
-  block-table width that tracks the deepest live sequence (power-of-two
-  ladder — a handful of compiles), so decode bandwidth follows actual
-  depth, not ``max_len``. Admission blocks on page-pool pressure, not
-  just free slots; eviction returns a request's non-shared pages.
+Attention K/V lives in a global page pool (:class:`PagedCache`).
+Admission only builds the request's block table (reusing trie-cached
+prefix pages — a shared system prompt is prefilled once); the prompt is
+then processed in fixed-shape page-multiple *chunks* interleaved with
+decode under a per-step token budget, so a long prompt never
+head-of-line-blocks running decodes. Decode runs through the
+paged-attention op over an *active* block-table width that tracks the
+deepest live sequence (power-of-two ladder — a handful of compiles), so
+decode bandwidth follows actual depth, not ``max_len``. Admission blocks
+on page-pool pressure, not just free slots; eviction returns a request's
+non-shared pages.
 
 Per-slot sampling state (current token, temperature, top-k, PRNG key,
 generation counter) lives on device and round-trips through the single
@@ -39,9 +30,8 @@ token transfer for the host-side stop checks.
 
 Exactness contract: for row-independent architectures (everything except
 capacity-constrained MoE routing) greedy output is token-for-token
-identical to a static batched decode of the same prompts — in BOTH memory
-models — verified in ``tests/test_serve_engine.py`` /
-``tests/test_serve_paged.py``.
+identical to a static batched decode of the same prompts — verified in
+``tests/test_serve_engine.py`` / ``tests/test_serve_paged.py``.
 """
 
 from __future__ import annotations
@@ -62,8 +52,7 @@ from repro.dist import sharding as dist_sh
 from repro.kernels import ops
 
 from . import sampling as sampling_lib
-from .cache import NULL_PAGE, PagedCache, SlotCache, publish_prefix_shared, \
-    share_trie
+from .cache import NULL_PAGE, PagedCache, publish_prefix_shared, share_trie
 from .metrics import ServeMetrics
 from .resilience import STAGE_NAMES, InjectedFault, Resilience
 from .scheduler import Request, RequestState, Scheduler
@@ -104,18 +93,23 @@ class Handoff:
 
 
 class Engine:
-    """Slot-based continuous-batching engine around one model + params."""
+    """Slot-based continuous-batching engine around one model + params.
+
+    ``paged`` is accepted for callers that still name the memory model;
+    paged is the only one, and ``paged=False`` is refused."""
 
     def __init__(self, model, params, *, n_slots: int = 8, max_len: int = 128,
-                 min_bucket: int = 16, buckets: Optional[Sequence[int]] = None,
                  dtype=None, metrics: Optional[ServeMetrics] = None,
-                 paged: bool = False, page_size: int = 16,
+                 paged: bool = True, page_size: int = 16,
                  n_pages: Optional[int] = None,
                  prefill_chunk_tokens: Optional[int] = None,
                  prefill_token_budget: Optional[int] = None,
                  spec_draft=None, spec_k: int = 4, preemption: bool = True,
                  resilience: Optional[Resilience] = None, device=None):
         cfg = model.cfg
+        if not paged:
+            raise ValueError("the slot-dense memory model was removed: the "
+                             "engine serves from a paged KV cache only")
         if not cfg.causal:
             raise ValueError(f"{cfg.name}: encoder-only arch has no decode step")
         if cfg.frontend != "token":
@@ -126,7 +120,6 @@ class Engine:
         self.params = params        # biases also in packed order (the setter)
         self.n_slots = n_slots
         self.max_len = max_len
-        self.paged = paged
         self.metrics = metrics if metrics is not None else ServeMetrics()
         self.step_count = 0
         # the (mesh, rules) pair active at construction: every trace the
@@ -157,7 +150,7 @@ class Engine:
         self.n_fault_failures = 0
         self.n_deadline_aborts = 0
 
-        # ---- speculative decoding (paged only): a compressed draft model
+        # ---- speculative decoding: a compressed draft model
         # proposes spec_k tokens per step; the target verifies the window in
         # one dispatch and the step advances by 1..spec_k+1 tokens. Archs
         # with recurrent state (mamba/rwkv) cannot roll state back cheaply:
@@ -167,9 +160,6 @@ class Engine:
         self.draft_model = self.draft_params = None
         self.draft_cache: Optional[PagedCache] = None
         if spec_draft is not None:
-            if not paged:
-                raise ValueError("spec_draft requires paged=True (rollback "
-                                 "is block-table truncation)")
             if self.spec_k < 1:
                 raise ValueError(f"spec_k must be >= 1, got {spec_k}")
             draft_model, draft_params = spec_draft
@@ -186,60 +176,52 @@ class Engine:
                          "speculative decoding disabled, using the plain "
                          "decode loop")
 
-        if paged:
-            slack = self.spec_k if self.spec_active else 0
-            self.cache = PagedCache(model, n_slots, max_len,
-                                    page_size=page_size, n_pages=n_pages,
-                                    dtype=dtype, slack_tokens=slack)
-            self.cache.injector = self.resilience.injector
-            if self.spec_active:
-                self.draft_cache = PagedCache(
-                    self.draft_model, n_slots, max_len, page_size=page_size,
-                    n_pages=n_pages, dtype=dtype, slack_tokens=slack)
-                self.draft_cache.injector = self.resilience.injector
-                # ONE token-keyed trie across both pools: draft and target
-                # hit shared prefixes as a unit (trie hit counted once)
-                share_trie([self.cache, self.draft_cache])
-                self._propose = jax.jit(self._propose_impl)
-                self._verify = jax.jit(self._verify_impl)
-                self._chunk_draft = jax.jit(self._prefill_chunk_draft_impl)
-                self._dbt_dev: Dict[int, jax.Array] = {}
-            # chunks replace buckets: no largest-bucket rejection, one
-            # prefill compile instead of one per bucket
-            self.scheduler = Scheduler(n_slots, max_len, strict_buckets=False)
-            ps = self.cache.page_size
-            if prefill_chunk_tokens is None:
-                prefill_chunk_tokens = min(4 * ps, self.cache.max_pages * ps)
-            if prefill_chunk_tokens % ps:
-                raise ValueError(
-                    f"prefill_chunk_tokens({prefill_chunk_tokens}) must be a "
-                    f"multiple of page_size({ps})")
-            self.chunk_tokens = prefill_chunk_tokens
-            self.prefill_token_budget = (prefill_token_budget
-                                         or prefill_chunk_tokens)
-            self._prefill_queue: Deque[Request] = collections.deque()
-            self._chunk = jax.jit(self._prefill_chunk_impl,
-                                  static_argnames=("final",))
-            self._decode_paged = jax.jit(self._decode_paged_impl)
-            self._bt_dev: Dict[int, jax.Array] = {}
-            # disaggregated-serving handoff ops (compiled per pow-2 width
-            # on first use): page-content gather on the prefill side,
-            # scatter-adopt + slot arming on the decode side
-            self._gather_pages = jax.jit(self._gather_pages_impl)
-            self._adopt = jax.jit(self._adopt_impl)
-            self._arm_slot = jax.jit(self._set_slot_impl)
-            # observability for the prefix-reuse contract (tests assert a
-            # shared-prefix batch skips chunks)
-            self.n_prefill_chunks = 0
-            self.n_prefill_tokens = 0          # computed
-            self.n_prefill_tokens_skipped = 0  # reused from the trie
-        else:
-            self.scheduler = Scheduler(n_slots, max_len, min_bucket=min_bucket,
-                                       buckets=buckets)
-            self.cache = SlotCache(model, n_slots, max_len, dtype)
-            self._admit = jax.jit(self._admit_impl)  # one compile per bucket
+        slack = self.spec_k if self.spec_active else 0
+        self.cache = PagedCache(model, n_slots, max_len,
+                                page_size=page_size, n_pages=n_pages,
+                                dtype=dtype, slack_tokens=slack)
+        self.cache.injector = self.resilience.injector
+        if self.spec_active:
+            self.draft_cache = PagedCache(
+                self.draft_model, n_slots, max_len, page_size=page_size,
+                n_pages=n_pages, dtype=dtype, slack_tokens=slack)
+            self.draft_cache.injector = self.resilience.injector
+            # ONE token-keyed trie across both pools: draft and target
+            # hit shared prefixes as a unit (trie hit counted once)
+            share_trie([self.cache, self.draft_cache])
+            self._propose = jax.jit(self._propose_impl)
+            self._verify = jax.jit(self._verify_impl)
+            self._chunk_draft = jax.jit(self._prefill_chunk_draft_impl)
+            self._dbt_dev: Dict[int, jax.Array] = {}
+        self.scheduler = Scheduler(n_slots, max_len)
+        ps = self.cache.page_size
+        if prefill_chunk_tokens is None:
+            prefill_chunk_tokens = min(4 * ps, self.cache.max_pages * ps)
+        if prefill_chunk_tokens % ps:
+            raise ValueError(
+                f"prefill_chunk_tokens({prefill_chunk_tokens}) must be a "
+                f"multiple of page_size({ps})")
+        self.chunk_tokens = prefill_chunk_tokens
+        self.prefill_token_budget = (prefill_token_budget
+                                     or prefill_chunk_tokens)
+        self._prefill_queue: Deque[Request] = collections.deque()
+        self._chunk = jax.jit(self._prefill_chunk_impl,
+                              static_argnames=("final",))
+        self._decode_paged = jax.jit(self._decode_paged_impl)
+        self._bt_dev: Dict[int, jax.Array] = {}
+        # disaggregated-serving handoff ops (compiled per pow-2 width on
+        # first use): page-content gather on the prefill side, scatter-adopt
+        # + slot arming on the decode side
+        self._gather_pages = jax.jit(self._gather_pages_impl)
+        self._adopt = jax.jit(self._adopt_impl)
+        self._arm_slot = jax.jit(self._set_slot_impl)
+        # observability for the prefix-reuse contract (tests assert a
+        # shared-prefix batch skips chunks)
+        self.n_prefill_chunks = 0
+        self.n_prefill_tokens = 0          # computed
+        self.n_prefill_tokens_skipped = 0  # reused from the trie
 
-        # device-side per-slot sampling state (round-trips through _decode)
+        # device-side per-slot sampling state (round-trips through decode)
         self._dev = {
             "tokens": jnp.zeros((n_slots,), jnp.int32),
             "temps": jnp.zeros((n_slots,), jnp.float32),
@@ -254,7 +236,6 @@ class Engine:
         # chaos); zeros unless the injector schedules a NaN/Inf this step.
         self._zero_poison = jnp.zeros((n_slots,), jnp.float32)
 
-        self._decode = jax.jit(self._decode_impl)
         self._clear_slot = jax.jit(self._clear_slot_impl)
 
         if device is not None:
@@ -282,8 +263,8 @@ class Engine:
         self.handoff_cb: Optional[Callable[[Request], None]] = None
         self.n_handoffs_out = 0
         self.n_handoffs_in = 0
-        # interactive-over-batch preemption needs page eviction: paged only
-        self.preemption = bool(preemption) and paged
+        # interactive-over-batch preemption by page eviction
+        self.preemption = bool(preemption)
         self.n_preemptions = 0
 
     @property
@@ -302,38 +283,15 @@ class Engine:
         self._params, self.n_packed_biases = pack_biases(self.model, params)
 
     # ------------------------------------------------------------ jitted ops
-    def _admit_impl(self, params, caches, dev, padded, length, slot, temp,
-                    top_k, key):
-        """One-dispatch slot-dense admission: bucket-padded batch-1 prefill,
-        first-token sampling, cache writeback into ``slot``, sampling-state
-        update."""
-        pcaches = self.model.init_caches(1, self.max_len, self.cache.dtype)
-        logits, pcaches = self.model.prefill(params, padded, pcaches,
-                                             lengths=length)
-        caches = self.cache._write_impl(caches, pcaches, slot)
-        keys = sampling_lib.fold_keys(key[None], jnp.zeros((1,), jnp.int32))
-        tok = sampling_lib.sample(logits, temp[None], top_k[None], keys)[0]
-        dev = self._set_slot_impl(dev, slot, tok, temp, top_k, key)
-        return tok, caches, dev
-
-    def _decode_impl(self, params, caches, dev, poison):
-        logits, caches = self.model.decode_step(params, dev["tokens"], caches)
-        # fault seam + watchdog: the injector's per-slot poison adds here
-        # (zeros in normal operation), and the per-slot finite check rides
-        # the same dispatch — non-finite rows are quarantined on the host,
-        # their sampled garbage token never emitted
-        logits = logits + poison[:, None]
-        ok = jnp.isfinite(logits).all(axis=-1)
-        keys = sampling_lib.fold_keys(dev["keys"], dev["counters"])
-        tokens = sampling_lib.sample(logits, dev["temps"], dev["top_ks"], keys)
-        dev = dict(dev, tokens=tokens, counters=dev["counters"] + 1)
-        return dev, caches, ok
-
     def _decode_paged_impl(self, params, caches, dev, block_tables, live,
                            poison):
         logits, caches = self.model.decode_step(params, dev["tokens"], caches,
                                                 block_tables=block_tables,
                                                 live=live)
+        # fault seam + watchdog: the injector's per-slot poison adds here
+        # (zeros in normal operation), and the per-slot finite check rides
+        # the same dispatch — non-finite rows are quarantined on the host,
+        # their sampled garbage token never emitted
         logits = logits + poison[:, None]
         ok = jnp.isfinite(logits).all(axis=-1)
         keys = sampling_lib.fold_keys(dev["keys"], dev["counters"])
@@ -493,19 +451,17 @@ class Engine:
         """Instantaneous engine gauges for the /metrics scrape — one method
         the HTTP server and the replica router both read, so a Router can
         stand in for an Engine without the server peeking at internals."""
-        g = {
+        return {
             "repro_serve_slots_live": float(self._live.sum()),
             "repro_serve_slots_total": float(self.n_slots),
             "repro_serve_engine_steps_total": float(self.step_count),
             # per-layer linear biases served in packed order: the load-time
             # pass engaged (Granite-8B: 36 layers x 7 projections)
             "repro_serve_packed_biases": float(self.n_packed_biases),
+            "repro_serve_kv_pages_allocated": float(
+                self.cache.pool.allocated_count),
+            "repro_serve_kv_pages_free": float(self.cache.pool.free_count),
         }
-        if self.paged:
-            g["repro_serve_kv_pages_allocated"] = float(
-                self.cache.pool.allocated_count)
-            g["repro_serve_kv_pages_free"] = float(self.cache.pool.free_count)
-        return g
 
     def cancel(self, req: Request) -> None:
         """Abort a request (client disconnect): pull it out of whichever
@@ -517,18 +473,16 @@ class Engine:
         if req.state == RequestState.DONE:
             return
         slot = req.slot
-        if self.paged:
-            try:
-                self._prefill_queue.remove(req)
-            except ValueError:
-                pass
+        try:
+            self._prefill_queue.remove(req)
+        except ValueError:
+            pass
         self.scheduler.finish(req)
         self.metrics.on_cancel(req.id)
         if slot is not None:
-            if self.paged:
-                self.cache.free_slot(slot)
-                if self.spec_active:
-                    self.draft_cache.free_slot(slot)
+            self.cache.free_slot(slot)
+            if self.spec_active:
+                self.draft_cache.free_slot(slot)
             self._live[slot] = False
             if req.sampling.temperature > 0:
                 self._dev = self._clear_slot(self._dev,
@@ -592,20 +546,7 @@ class Engine:
 
     # ------------------------------------------------------------ step logic
     def _admit_one(self, req: Request, slot: int) -> None:
-        padded, n = self.scheduler.pad_prompt(req)
-        self.metrics.on_admit(req.id)
-        sp = req.sampling
-        tok_dev, self.cache.caches, self._dev = self._admit(
-            self.params, self.cache.caches, self._dev, jnp.asarray(padded),
-            jnp.asarray([n], jnp.int32), jnp.asarray(slot, jnp.int32),
-            jnp.asarray(sp.temperature, jnp.float32),
-            jnp.asarray(sp.top_k, jnp.int32), sampling_lib.base_key(sp.seed))
-        self._live[slot] = True
-        req.state = RequestState.DECODE
-        self._emit(req, int(tok_dev))
-
-    def _admit_one_paged(self, req: Request, slot: int) -> None:
-        """Paged admission is bookkeeping only: build the block table
+        """Admission is bookkeeping only: build the block table
         (reusing trie-matched prefix pages) and queue the prefill chunks —
         no device work until the chunk loop runs. A request arriving with a
         :class:`Handoff` payload (disaggregated serving) adopts the
@@ -632,7 +573,7 @@ class Engine:
         """Gather the prompt's page contents for migration to a decode-role
         engine. Must run while the request still owns its block-table row
         (``_emit`` calls it just before the stop-path ``free_slot``)."""
-        assert self.paged and req.slot is not None
+        assert req.slot is not None
         n_tok = len(req.prompt)
         n_pages = self.cache.pages_for(n_tok)
         width = min(_next_pow2(n_pages), self.cache.max_pages)
@@ -771,11 +712,9 @@ class Engine:
         return ran
 
     def decode_widths(self) -> List[int]:
-        """The active block-table widths paged decode can run at (the
+        """The active block-table widths decode can run at (the
         power-of-two ladder, capped at ``max_pages``) — one decode compile
         each."""
-        if not self.paged:
-            return []
         out, w = [], 1
         while w < self.cache.max_pages:
             out.append(w)
@@ -789,8 +728,6 @@ class Engine:
         always attends over at least ``chunk_tokens`` of context, so the
         narrower rungs never occur) — one chunk compile per rung per
         ``final`` variant."""
-        if not self.paged:
-            return []
         w_min = min(_next_pow2(self.cache.pages_for(self.chunk_tokens)),
                     self.cache.max_pages)
         return [w for w in self.decode_widths() if w >= w_min]
@@ -815,11 +752,10 @@ class Engine:
         so steady-state serving never pauses for a mid-stream compile (the
         width grows with the deepest live sequence). In spec mode the
         propose scan and the (k+1)-query verify program compile per rung
-        instead. The chunked-prefill ladder compiles alongside — every
+        as well. The chunked-prefill ladder compiles alongside — every
         prefill width × {non-final, final} chunk variant (plus the draft
         mirror in spec mode), against the null page so no real K/V moves.
-        Results are discarded; engine state is untouched. No-op for the
-        dense engine (one decode shape, compiled on first step). Mesh-aware:
+        Results are discarded; engine state is untouched. Mesh-aware:
         compiles under the construction-time mesh scope (see
         :meth:`_mesh_scope`), not whatever mesh happens to be active at
         call time."""
@@ -847,31 +783,27 @@ class Engine:
                                           zbt, zlive, zpos, self._zero_poison)
                 self._verify(self.params, self.cache.caches, self._dev, zbt,
                              zlive, zpos, dt, dq, self._zero_poison)
-                # the degradation ladder can suspend spec mid-flight: the
-                # plain-decode fallback must be warm too, or the first
-                # degraded step pauses for a compile
-                self._decode_paged(self.params, self.cache.caches, self._dev,
-                                   zbt, zlive, self._zero_poison)
-            else:
-                self._decode_paged(self.params, self.cache.caches, self._dev,
-                                   zbt, zlive, self._zero_poison)
-        if self.paged:
-            ztoks = jnp.zeros((1, self.chunk_tokens), jnp.int32)
-            zslot = jnp.zeros((), jnp.int32)
-            zstart = jnp.zeros((), jnp.int32)
-            zlen = jnp.ones((), jnp.int32)
-            for w in self.prefill_widths():
-                zrow = jnp.zeros((w,), jnp.int32)   # null page: writes vanish
-                for final in (False, True):
-                    self._chunk(self.params, self.cache.caches, self._dev,
-                                ztoks, zrow, zslot, zstart, zlen,
-                                jnp.zeros((), jnp.float32),
-                                jnp.zeros((), jnp.int32),
-                                sampling_lib.base_key(0), final=final)
-                if self.spec_active:
-                    self._chunk_draft(self.draft_params,
-                                      self.draft_cache.caches, ztoks, zrow,
-                                      zslot, zstart, zlen)
+            # in spec mode too: the degradation ladder can suspend spec
+            # mid-flight, and the first degraded step must not pause for a
+            # compile
+            self._decode_paged(self.params, self.cache.caches, self._dev,
+                               zbt, zlive, self._zero_poison)
+        ztoks = jnp.zeros((1, self.chunk_tokens), jnp.int32)
+        zslot = jnp.zeros((), jnp.int32)
+        zstart = jnp.zeros((), jnp.int32)
+        zlen = jnp.ones((), jnp.int32)
+        for w in self.prefill_widths():
+            zrow = jnp.zeros((w,), jnp.int32)   # null page: writes vanish
+            for final in (False, True):
+                self._chunk(self.params, self.cache.caches, self._dev,
+                            ztoks, zrow, zslot, zstart, zlen,
+                            jnp.zeros((), jnp.float32),
+                            jnp.zeros((), jnp.int32),
+                            sampling_lib.base_key(0), final=final)
+            if self.spec_active:
+                self._chunk_draft(self.draft_params,
+                                  self.draft_cache.caches, ztoks, zrow,
+                                  zslot, zstart, zlen)
 
     def _live_mask_dev(self) -> jax.Array:
         """Device copy of the liveness mask, re-uploaded only when slot
@@ -919,7 +851,6 @@ class Engine:
             # the normal free path runs and handoff_cb resubmits elsewhere.
             # An EOS stop is a real completion: no decode work remains.
             handing_off = (req.prefill_only and self.handoff_cb is not None
-                           and self.paged
                            and not (req.eos_id >= 0 and tok == req.eos_id))
             if handing_off:
                 req.handoff = self.extract_handoff(req)
@@ -927,10 +858,9 @@ class Engine:
             if not handing_off:
                 self.metrics.on_done(req.id)
             if slot is not None:
-                if self.paged:
-                    self.cache.free_slot(slot)
-                    if self.spec_active:
-                        self.draft_cache.free_slot(slot)
+                self.cache.free_slot(slot)
+                if self.spec_active:
+                    self.draft_cache.free_slot(slot)
                 self._live[slot] = False
                 if req.sampling.temperature > 0:
                     self._dev = self._clear_slot(
@@ -948,14 +878,9 @@ class Engine:
     def _report_kv(self) -> None:
         logical = sum(self._kv_len(r) for r in self.scheduler.running.values()
                       if r.state == RequestState.DECODE)
-        if self.paged:
-            self.metrics.on_kv(self.cache.kv_bytes_allocated(),
-                               int(logical * self.cache.token_bytes),
-                               self.cache.dense_reserved_bytes)
-        else:
-            self.metrics.on_kv(self.cache.kv_bytes,
-                               int(logical * self.cache.token_bytes),
-                               self.cache.kv_bytes)
+        self.metrics.on_kv(self.cache.kv_bytes_allocated(),
+                           int(logical * self.cache.token_bytes),
+                           self.cache.dense_reserved_bytes)
 
     # ----------------------------------------------------------- resilience
     def _poison_dev(self, site: str) -> jax.Array:
@@ -978,19 +903,17 @@ class Engine:
         """Terminal failure: free everything the request holds within this
         step and surface ``finish_reason`` through done_cb."""
         slot = req.slot
-        if self.paged:
-            try:
-                self._prefill_queue.remove(req)
-            except ValueError:
-                pass
+        try:
+            self._prefill_queue.remove(req)
+        except ValueError:
+            pass
         req.finish_reason = reason
         self.scheduler.finish(req)
         self.metrics.on_abort(req.id, reason)
         if slot is not None:
-            if self.paged:
-                self.cache.free_slot(slot)
-                if self.spec_active:
-                    self.draft_cache.free_slot(slot)
+            self.cache.free_slot(slot)
+            if self.spec_active:
+                self.draft_cache.free_slot(slot)
             self._live[slot] = False
             if req.sampling.temperature > 0:
                 self._dev = self._clear_slot(self._dev,
@@ -1035,10 +958,9 @@ class Engine:
         req.retry_at_step = self.step_count + res.backoff_steps(
             req.id, req.n_fault_retries)
         slot = req.slot
-        if self.paged:
-            self.cache.preempt_slot(slot)
-            if self.spec_active:
-                self.draft_cache.preempt_slot(slot)
+        self.cache.preempt_slot(slot)
+        if self.spec_active:
+            self.draft_cache.preempt_slot(slot)
         self._live[slot] = False
         if req.sampling.temperature > 0:
             self._dev = self._clear_slot(self._dev,
@@ -1078,8 +1000,6 @@ class Engine:
         self.metrics.on_degradation(new)
         log.warning("degradation ladder: %s -> %s", STAGE_NAMES[old],
                     STAGE_NAMES[new])
-        if not self.paged:
-            return
         if new >= 2 and old < 2:        # entering flush_prefix
             n = self.cache.flush_trie()
             self.cache.publish_enabled = False
@@ -1102,13 +1022,10 @@ class Engine:
         res = self.resilience
         if res.ladder is None:
             return
-        if self.paged:
-            cap = max(self.cache.pool.n_pages - 1, 1)
-            avail = self.cache.available()
-            util = 1.0 if (page_blocked or avail <= 0) \
-                else 1.0 - min(avail, cap) / cap
-        else:
-            util = 1.0 if page_blocked else 0.0
+        cap = max(self.cache.pool.n_pages - 1, 1)
+        avail = self.cache.available()
+        util = 1.0 if (page_blocked or avail <= 0) \
+            else 1.0 - min(avail, cap) / cap
         res.ladder.observe(res.pressure(util), self.step_count)
 
     @property
@@ -1122,8 +1039,8 @@ class Engine:
 
     # ------------------------------------------------------------- the step
     def step(self) -> bool:
-        """One engine iteration: admit into free slots, (paged) run prefill
-        chunks under the token budget, then one batched decode of all live
+        """One engine iteration: admit into free slots, run prefill chunks
+        under the token budget, then one batched decode of all live
         slots. Returns True if any work was done. The resilience bracket
         wraps every path: injected slow-steps fire in begin_step, the
         step-time EWMA monitor and fault-rate decay in end_step."""
@@ -1140,45 +1057,37 @@ class Engine:
     def _step_inner(self) -> bool:
         self._enforce_deadlines()
         page_blocked = False
-        if self.paged:
-            # one at a time: each admission consumes pages, and the pool
-            # predicate for the next queue head must see that (spec mode:
-            # in BOTH pools)
-            def _can(r):
-                nonlocal page_blocked
-                ok = self.cache.can_admit(len(r.prompt), r.max_new_tokens,
-                                          prompt=r.prompt)
-                if ok and self.spec_active:
-                    ok = self.draft_cache.can_admit(
-                        len(r.prompt), r.max_new_tokens, prompt=r.prompt)
-                if not ok:
-                    page_blocked = True     # pressure signal for the ladder
-                return ok
-            admitted = []
-            with _span("engine.admit") as span:
-                while True:
-                    pairs = self.scheduler.admit(
-                        can_admit=_can, max_n=1,
-                        eligible=self._retry_eligible)
-                    if pairs:
-                        self._admit_one_paged(*pairs[0])
-                        admitted += pairs
-                        continue
-                    # head blocked (slot or page pressure): preempt the
-                    # lowest-priority running request if it outranks, then
-                    # retry — each eviction returns capacity the predicate
-                    # re-checks
-                    if not self._preempt_for_head():
-                        break
-                span.set_metadata(n=len(admitted))
-            prefilled = self._prefill_chunks()
-        else:
-            with _span("engine.admit") as span:
-                admitted = self.scheduler.admit(eligible=self._retry_eligible)
-                for req, slot in admitted:
-                    self._admit_one(req, slot)
-                span.set_metadata(n=len(admitted))
-            prefilled = False
+
+        # one at a time: each admission consumes pages, and the pool
+        # predicate for the next queue head must see that (spec mode: in
+        # BOTH pools)
+        def _can(r):
+            nonlocal page_blocked
+            ok = self.cache.can_admit(len(r.prompt), r.max_new_tokens,
+                                      prompt=r.prompt)
+            if ok and self.spec_active:
+                ok = self.draft_cache.can_admit(
+                    len(r.prompt), r.max_new_tokens, prompt=r.prompt)
+            if not ok:
+                page_blocked = True     # pressure signal for the ladder
+            return ok
+        admitted = []
+        with _span("engine.admit") as span:
+            while True:
+                pairs = self.scheduler.admit(
+                    can_admit=_can, max_n=1, eligible=self._retry_eligible)
+                if pairs:
+                    self._admit_one(*pairs[0])
+                    admitted += pairs
+                    continue
+                # head blocked (slot or page pressure): preempt the
+                # lowest-priority running request if it outranks, then
+                # retry — each eviction returns capacity the predicate
+                # re-checks
+                if not self._preempt_for_head():
+                    break
+            span.set_metadata(n=len(admitted))
+        prefilled = self._prefill_chunks()
         self.step_count += 1
         self.metrics.on_queue_depth(len(self.scheduler.waiting))
         self._apply_ladder(page_blocked)
@@ -1192,66 +1101,53 @@ class Engine:
             return self._step_spec()
 
         res = self.resilience
-        if self.paged:
-            live_slots = np.nonzero(self._live)[0]
-            with _span("engine.decode.prep") as span:
-                # materialize this step's write pages and size the active
-                # block-table width to the deepest live sequence
-                needed, kv_tokens = 1, 0
-                wpos_arr = np.zeros((self.n_slots,), np.int32)
-                for slot in live_slots:
-                    req = self.scheduler.running.get(int(slot))
-                    if req is None:
-                        continue
-                    wpos = self._kv_len(req)
-                    wpos_arr[slot] = wpos
-                    kv_tokens += wpos + 1      # the kernel's length for it
-                    self.cache.ensure_decode_page(int(slot), wpos)
-                    needed = max(needed, self.cache.pages_used(int(slot),
-                                                               wpos + 1))
-                width = min(_next_pow2(needed), self.cache.max_pages)
-                span.set_metadata(live=len(live_slots), width=width,
-                                  kv_tokens=kv_tokens)
-                bt = self._block_tables_dev(width)
-                # live mask is load-bearing: mid-prefill slots hold real
-                # block tables + carried state that an unmasked decode
-                # would corrupt
-                live = self._live_mask_dev()
-                if self.spec_active:
-                    # suspended-spec interlude: verify leaves cache ``pos``
-                    # at the window entry depth, so the device counter the
-                    # plain decode trusts is stale after a spec step —
-                    # resync it to the host-authoritative accepted depth or
-                    # this step writes K/V over accepted positions
-                    self.cache.caches = self.model.set_paged_pos(
-                        self.cache.caches, jnp.asarray(wpos_arr))
-            try:
-                with _span("engine.decode.dispatch"):
-                    if res.injector is not None:
-                        res.injector.check("engine_step")
-                    self._dev, self.cache.caches, ok_dev = self._decode_paged(
-                        self.params, self.cache.caches, self._dev, bt, live,
-                        self._poison_dev("decode_logits"))
-            except Exception as e:          # noqa: BLE001 — bounded retry
-                return self._handle_step_fault(e)
-        else:
-            try:
-                with _span("engine.decode.dispatch"):
-                    if res.injector is not None:
-                        res.injector.check("engine_step")
-                    self._dev, self.cache.caches, ok_dev = self._decode(
-                        self.params, self.cache.caches, self._dev,
-                        self._poison_dev("decode_logits"))
-            except Exception as e:          # noqa: BLE001 — bounded retry
-                return self._handle_step_fault(e)
+        live_slots = np.nonzero(self._live)[0]
+        with _span("engine.decode.prep") as span:
+            # materialize this step's write pages and size the active
+            # block-table width to the deepest live sequence
+            needed, kv_tokens = 1, 0
+            wpos_arr = np.zeros((self.n_slots,), np.int32)
+            for slot in live_slots:
+                req = self.scheduler.running.get(int(slot))
+                if req is None:
+                    continue
+                wpos = self._kv_len(req)
+                wpos_arr[slot] = wpos
+                kv_tokens += wpos + 1      # the kernel's length for it
+                self.cache.ensure_decode_page(int(slot), wpos)
+                needed = max(needed, self.cache.pages_used(int(slot),
+                                                           wpos + 1))
+            width = min(_next_pow2(needed), self.cache.max_pages)
+            span.set_metadata(live=len(live_slots), width=width,
+                              kv_tokens=kv_tokens)
+            bt = self._block_tables_dev(width)
+            # live mask is load-bearing: mid-prefill slots hold real block
+            # tables + carried state that an unmasked decode would corrupt
+            live = self._live_mask_dev()
+            if self.spec_active:
+                # suspended-spec interlude: verify leaves cache ``pos`` at
+                # the window entry depth, so the device counter the plain
+                # decode trusts is stale after a spec step — resync it to
+                # the host-authoritative accepted depth or this step writes
+                # K/V over accepted positions
+                self.cache.caches = self.model.set_paged_pos(
+                    self.cache.caches, jnp.asarray(wpos_arr))
+        try:
+            with _span("engine.decode.dispatch"):
+                if res.injector is not None:
+                    res.injector.check("engine_step")
+                self._dev, self.cache.caches, ok_dev = self._decode_paged(
+                    self.params, self.cache.caches, self._dev, bt, live,
+                    self._poison_dev("decode_logits"))
+        except Exception as e:          # noqa: BLE001 — bounded retry
+            return self._handle_step_fault(e)
         res.consecutive_step_faults = 0
         with _span("engine.decode.sync"):
             next_np = np.asarray(self._dev["tokens"])
             ok_np = np.asarray(ok_dev)
 
         self.metrics.on_step(int(self._live.sum()), self.n_slots)
-        if self.paged:
-            self.metrics.on_kv_tokens_attended(kv_tokens)
+        self.metrics.on_kv_tokens_attended(kv_tokens)
         self._report_kv()
         live_slots = np.nonzero(self._live)[0]
         with _span("engine.emit", n=len(live_slots)):

@@ -291,7 +291,7 @@ class ServeMetrics:
     def on_kv(self, allocated_bytes: int, logical_bytes: int,
               reserved_bytes: int) -> None:
         """KV-memory snapshot for one step. ``allocated`` is what the cache
-        actually holds (paged: pages in use; dense: the full reservation);
+        actually holds (the pages in use);
         ``logical`` is live-sequence depth × bytes/token — with prefix
         sharing it can exceed ``allocated``; ``reserved`` is the dense
         ``n_slots × max_len`` equivalent. Peaks are kept."""

@@ -44,8 +44,8 @@ real token budget and resubmits to a decode replica, where admission
 *adopts* the payload (pages scattered into the local pool through the
 same ``admit_request`` reservation accounting as any prompt, so handoff
 can never deadlock the pool) and decoding continues from token 1 with
-the identical sampling-key sequence. Requires paged engines whose cache
-is fully attention-backed (``prefix_cache_enabled``) and no speculative
+the identical sampling-key sequence. Requires engines whose cache is
+fully attention-backed (``prefix_cache_enabled``) and no speculative
 decoding (the draft pool is not migrated).
 """
 
@@ -146,9 +146,8 @@ class Router:
             raise ValueError("Router needs at least one engine replica")
         e0 = engines[0]
         for e in engines[1:]:
-            if (e.paged, e.max_len) != (e0.paged, e0.max_len):
-                raise ValueError("Router replicas must agree on paged mode "
-                                 "and max_len")
+            if e.max_len != e0.max_len:
+                raise ValueError("Router replicas must agree on max_len")
         self.replicas = list(engines)
         self.live = [True] * len(engines)
         self.affinity_pages = affinity_pages
@@ -162,10 +161,9 @@ class Router:
                 raise ValueError(f"n_prefill must be in [1, {len(engines)}) "
                                  f"for disagg, got {n_prefill}")
             for e in engines:
-                if not e.paged or e.spec_active \
-                        or not e.cache.prefix_cache_enabled:
+                if e.spec_active or not e.cache.prefix_cache_enabled:
                     raise ValueError(
-                        "disagg requires paged engines with fully "
+                        "disagg requires engines with fully "
                         "attention-backed caches (prefix_cache_enabled) and "
                         "no speculative draft — the handoff migrates every "
                         "cache leaf and exactly one sampling stream")
@@ -186,10 +184,6 @@ class Router:
                 e.handoff_cb = self._on_handoff
 
     # --------------------------------------------------- facade properties
-    @property
-    def paged(self) -> bool:
-        return self.replicas[0].paged
-
     @property
     def max_len(self) -> int:
         return self.replicas[0].max_len
@@ -264,11 +258,9 @@ class Router:
         cands = self._candidates(role)
         if not cands:
             raise RuntimeError("no live replicas")
-        key = None
-        if self.paged:
-            key = prefix_affinity_key(req.prompt,
-                                      self.replicas[cands[0]].cache.page_size,
-                                      self.affinity_pages)
+        key = prefix_affinity_key(req.prompt,
+                                  self.replicas[cands[0]].cache.page_size,
+                                  self.affinity_pages)
         hit = False
         if key is not None and self._affinity.get(key) in cands:
             choice = self._affinity[key]

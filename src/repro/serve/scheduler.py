@@ -6,17 +6,12 @@ by ``(priority class, arrival)`` — strictly FCFS *within* a class, and an
 ``interactive`` request always outranks a ``batch`` one regardless of
 arrival order. ``arrival_seq`` is stamped once at first submit and
 survives preemption, so a preempted request rejoins the queue at its
-original position among its class. In the slot-dense engine prompts are
-right-padded to a *bucket* length (powers of two between ``min_bucket``
-and ``max_len``) so the jitted prefill compiles once per bucket, not once
-per prompt length — the engine's jit-stable-shapes contract. The paged
-engine (``strict_buckets=False``) replaces buckets with fixed-shape
-prefill *chunks*: any prompt with ``prompt + max_new_tokens <= max_len``
-is admittable (no largest-bucket rejection), and admission can
-additionally be gated by a ``can_admit`` predicate (page-pool pressure) —
-a blocked queue head blocks everyone behind it (the engine may then
-preempt a lower-priority running slot to unblock it; see
-``Engine._preempt_for_head``).
+original position among its class. The engine prefills prompts in
+fixed-shape *chunks*, so any prompt with ``prompt + max_new_tokens <=
+max_len`` is admittable, and admission can additionally be gated by a
+``can_admit`` predicate (page-pool pressure) — a blocked queue head
+blocks everyone behind it (the engine may then preempt a lower-priority
+running slot to unblock it; see ``Engine._preempt_for_head``).
 """
 
 from __future__ import annotations
@@ -24,7 +19,7 @@ from __future__ import annotations
 import bisect
 import dataclasses
 import enum
-from typing import Callable, List, Optional, Sequence, Tuple
+from typing import Callable, List, Optional, Tuple
 
 import numpy as np
 
@@ -52,7 +47,7 @@ class Request:
     sampling: SamplingParams = dataclasses.field(default_factory=SamplingParams)
     arrival_time: Optional[float] = None    # None -> stamped at submit time
     # priority class: "interactive" admits ahead of "batch" and may preempt
-    # it under page-pool pressure (paged engine)
+    # it under page-pool pressure
     priority: str = "interactive"
     # SLO deadline annotations (seconds from submit); None = no deadline.
     # Purely observational: attainment is reported per class in
@@ -68,7 +63,7 @@ class Request:
     state: RequestState = RequestState.WAITING
     slot: Optional[int] = None
     generated: List[int] = dataclasses.field(default_factory=list)
-    # paged-engine prefill progress: tokens already in cache (trie-matched
+    # prefill progress: tokens already in cache (trie-matched
     # prefix + completed chunks) / tokens skipped via prefix reuse
     prefill_pos: int = 0
     n_matched: int = 0
@@ -109,31 +104,15 @@ class Request:
         return PRIORITIES[self.priority]
 
 
-def make_buckets(min_bucket: int, max_len: int) -> Tuple[int, ...]:
-    """Power-of-two prompt buckets in [min_bucket, max_len]."""
-    buckets = []
-    b = max(int(min_bucket), 1)
-    while b < max_len:
-        buckets.append(b)
-        b *= 2
-    buckets.append(max_len)
-    return tuple(buckets)
-
-
 class Scheduler:
     """Priority queue + slot free-list. The engine calls :meth:`admit` once
     per step; the scheduler never touches device state. The waiting list is
     kept sorted by ``(priority rank, arrival_seq)`` — FCFS within a class,
     interactive ahead of batch across classes."""
 
-    def __init__(self, n_slots: int, max_len: int, min_bucket: int = 16,
-                 buckets: Optional[Sequence[int]] = None,
-                 strict_buckets: bool = True):
+    def __init__(self, n_slots: int, max_len: int):
         self.n_slots = n_slots
         self.max_len = max_len
-        self.strict_buckets = strict_buckets
-        self.buckets = tuple(sorted(buckets)) if buckets else \
-            make_buckets(min_bucket, max_len)
         self.waiting: List[Request] = []
         self.free_slots: List[int] = list(range(n_slots))
         self.running: dict = {}             # slot -> Request
@@ -146,14 +125,6 @@ class Scheduler:
             raise ValueError(
                 f"request {req.id}: prompt({len(req.prompt)}) + "
                 f"max_new_tokens({req.max_new_tokens}) > max_len({self.max_len})")
-        if self.strict_buckets and len(req.prompt) > self.buckets[-1]:
-            # reject before a slot is consumed — failing later, mid-admission,
-            # would leak the assigned slot and wedge the engine. The paged
-            # engine (strict_buckets=False) has no bucket ceiling: long
-            # prompts run as a sequence of fixed-shape chunks.
-            raise ValueError(
-                f"request {req.id}: prompt({len(req.prompt)}) exceeds the "
-                f"largest prompt bucket ({self.buckets[-1]})")
         req.state = RequestState.WAITING
         req.slot = None
         req.generated = []          # reset runtime fields: resubmit == fresh
@@ -168,37 +139,19 @@ class Scheduler:
         bisect.insort(self.waiting, req,
                       key=lambda r: (r.priority_rank, r.arrival_seq))
 
-    def bucket_len(self, prompt_len: int) -> int:
-        for b in self.buckets:
-            if b >= prompt_len:
-                return b
-        raise ValueError(f"prompt length {prompt_len} exceeds largest bucket "
-                         f"{self.buckets[-1]}")
-
-    def pad_prompt(self, req: Request) -> Tuple[np.ndarray, int]:
-        """Right-pad the prompt to its bucket. Returns ((1, Tb) tokens,
-        true length). Pad id 0 — padded positions are masked out by the
-        length-aware prefill, the value never matters."""
-        n = len(req.prompt)
-        tb = self.bucket_len(n)
-        padded = np.zeros((1, tb), np.int32)
-        padded[0, :n] = req.prompt
-        return padded, n
-
     def admit(self, can_admit: Optional[Callable[[Request], bool]] = None,
               max_n: Optional[int] = None,
               eligible: Optional[Callable[[Request], bool]] = None
               ) -> List[Tuple[Request, int]]:
         """Pop waiting requests into free slots (lowest slot first) in
-        (priority, arrival) order. ``can_admit`` (paged engine: page-pool
-        pressure) gates the queue head — a blocked head blocks everyone
-        behind it, keeping admission order stable regardless of which
-        slots freed when. The paged engine passes ``max_n=1`` and
-        re-checks between admissions, since each admission consumes pages
-        the predicate must see. ``eligible`` is different: an ineligible
-        request (a quarantined one still in retry backoff) is *skipped*,
-        not blocking — its delay is its own, FCFS holds among the
-        eligible."""
+        (priority, arrival) order. ``can_admit`` (page-pool pressure) gates
+        the queue head — a blocked head blocks everyone behind it, keeping
+        admission order stable regardless of which slots freed when. The
+        engine passes ``max_n=1`` and re-checks between admissions, since
+        each admission consumes pages the predicate must see. ``eligible``
+        is different: an ineligible request (a quarantined one still in
+        retry backoff) is *skipped*, not blocking — its delay is its own,
+        FCFS holds among the eligible."""
         out = []
         self.free_slots.sort()
         i = 0

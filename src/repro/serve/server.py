@@ -121,9 +121,8 @@ class GenerateServer:
         self.port = self._server.sockets[0].getsockname()[1]
         if self.auto_pump:
             self._pump_task = asyncio.create_task(self._pump())
-        log.info("listening on http://%s:%d (queue_limit=%d, %s engine)",
-                 self.host, self.port, self.queue_limit,
-                 "paged" if self.engine.paged else "slot-dense")
+        log.info("listening on http://%s:%d (queue_limit=%d)",
+                 self.host, self.port, self.queue_limit)
 
     async def run_forever(self) -> None:
         await self.start()
@@ -378,7 +377,6 @@ class GenerateServer:
             elif method == "GET" and target == "/healthz":
                 ladder = self.engine.resilience.ladder
                 info = {"ok": not self._engine_failed,
-                        "paged": self.engine.paged,
                         "n_slots": self.engine.n_slots,
                         "max_len": self.engine.max_len,
                         "spec_active": self.engine.spec_active,

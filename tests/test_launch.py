@@ -96,3 +96,27 @@ base, fused = coll(False), coll(True)
 assert fused < base, (base, fused)
 print("OK", base, "->", fused)
 """)
+
+
+def test_launcher_builds_paged_engine():
+    """The launcher's default flags serve from the paged KV cache; the
+    removed ``--static`` and ``--paged`` flags no longer parse, and an
+    embed-frontend arch stops with the engine's own reason."""
+    import pytest
+
+    from repro.launch import serve
+    from repro.serve import PagedCache
+
+    args = serve.build_parser().parse_args(["--arch", "olmo-1b", "--smoke"])
+    _, model, params = serve._load_model(args)
+    engine, mode = serve._build_engine(args, model, params, chaos=False)
+    assert isinstance(engine.cache, PagedCache) and mode == "paged"
+    assert engine.cache.page_size == args.page_size
+    for gone in ("--static", "--paged", "--batch=4"):
+        with pytest.raises(SystemExit):
+            serve.build_parser().parse_args(["--arch", "olmo-1b", gone])
+    args = serve.build_parser().parse_args(["--arch", "qwen2-vl-72b",
+                                            "--smoke"])
+    _, model, params = serve._load_model(args)
+    with pytest.raises(SystemExit, match="token frontends only"):
+        serve._build_engine(args, model, params, chaos=False)

@@ -159,7 +159,7 @@ def test_chunked_equals_monolithic_across_chunk_sizes(chunk_tokens):
     reqs = [Request(id=i, prompt=rng.integers(0, m.cfg.vocab, size=plen),
                     max_new_tokens=6)
             for i, plen in enumerate([21, 37, 8])]
-    eng = Engine(m, p, n_slots=2, max_len=64, paged=True, page_size=8,
+    eng = Engine(m, p, n_slots=2, max_len=64, page_size=8,
                  prefill_chunk_tokens=chunk_tokens)
     out = eng.run(reqs)
     for r in reqs:
@@ -178,7 +178,7 @@ def test_interpret_kernel_engine_parity():
     saved = ops._PREFILL_BACKEND
     ops.set_prefill_backend("interpret")
     try:
-        eng = Engine(m, p, n_slots=2, max_len=64, paged=True, page_size=8,
+        eng = Engine(m, p, n_slots=2, max_len=64, page_size=8,
                      prefill_chunk_tokens=16)
         out = eng.run(reqs)
     finally:
@@ -204,7 +204,7 @@ def test_trie_hit_offsets_start_past_zero(backend):
     saved = ops._PREFILL_BACKEND
     ops.set_prefill_backend(backend)
     try:
-        eng = Engine(m, p, n_slots=2, max_len=64, paged=True, page_size=8,
+        eng = Engine(m, p, n_slots=2, max_len=64, page_size=8,
                      prefill_chunk_tokens=16)
         eng.submit(reqs[0])
         while eng.has_work():
@@ -227,7 +227,7 @@ def test_warmup_covers_prefill_ladder():
     engine can dispatch; a post-warmup serve must add no new chunk
     compiles."""
     m, p = _model()
-    eng = Engine(m, p, n_slots=2, max_len=64, paged=True, page_size=8,
+    eng = Engine(m, p, n_slots=2, max_len=64, page_size=8,
                  prefill_chunk_tokens=16)
     assert eng.prefill_widths() == [w for w in eng.decode_widths() if w >= 2]
     eng.warmup()

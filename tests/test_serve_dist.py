@@ -38,7 +38,6 @@ def _engine(**kw):
     _, m, p = _model()
     kw.setdefault("n_slots", 2)
     kw.setdefault("max_len", 64)
-    kw.setdefault("paged", True)
     kw.setdefault("page_size", 8)
     return Engine(m, p, **kw)
 
@@ -147,7 +146,7 @@ def run(m, p, mesh):
             max_new_tokens=int(rng.integers(2, 10))))
     ctx = sh.use_mesh(mesh) if mesh is not None else contextlib.nullcontext()
     with ctx:
-        eng = Engine(m, p, n_slots=2, max_len=64, paged=True, page_size=8)
+        eng = Engine(m, p, n_slots=2, max_len=64, page_size=8)
     done = {}
     eng.done_cb = lambda r: done.setdefault(r.id, list(r.generated))
     for r in reqs:
@@ -231,7 +230,7 @@ def test_replicas_placed_one_per_device():
 import jax
 from repro.launch import serve
 
-flags = ["--arch", "olmo-1b", "--smoke", "--paged", "--page-size", "8",
+flags = ["--arch", "olmo-1b", "--smoke", "--page-size", "8",
          "--slots", "2", "--requests", "6", "--rate", "50",
          "--prompt-len", "16", "--gen", "4"]
 
@@ -273,8 +272,17 @@ def test_router_disagg_handoff_token_identical():
 
 
 def test_router_disagg_rejects_unsuitable_engines():
-    with pytest.raises(ValueError):
-        Router([_engine(paged=False), _engine(paged=False)], disagg=True)
+    """The handoff migrates every cache leaf and one sampling stream: a
+    replica with a draft pool, or with recurrent state, cannot take part."""
+    _, m, p = _model()
+    with pytest.raises(ValueError, match="speculative draft"):
+        Router([_engine(spec_draft=(m, p), spec_k=2), _engine()],
+               disagg=True)
+    rwkv = build(common.get_config("rwkv6-3b", smoke=True))
+    recurrent = Engine(rwkv, rwkv.init(jax.random.PRNGKey(0)), n_slots=2,
+                       max_len=64, page_size=8)
+    with pytest.raises(ValueError, match="attention-backed"):
+        Router([_engine(), recurrent], disagg=True)
     with pytest.raises(ValueError):
         Router([_engine()], disagg=True)
 

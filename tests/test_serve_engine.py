@@ -2,10 +2,10 @@
 
 The load-bearing property: for row-independent architectures, the engine's
 greedy output is **token-for-token identical** to a static batched greedy
-decode of the same prompts — across bucketed prompt padding, staggered
-admission, slot reuse after eviction, and per-request EOS stops. Verified
-for an attention arch (olmo smoke), an RWKV arch (rwkv6 smoke), and a pure
-Mamba config.
+decode of the same prompts — across chunked prefill with a padded tail,
+staggered admission, slot reuse after eviction, and per-request EOS stops.
+Verified for an attention arch (olmo smoke), an RWKV arch (rwkv6 smoke), and
+a pure Mamba config.
 """
 
 import functools
@@ -18,12 +18,13 @@ import pytest
 from repro.configs import common
 from repro.models import ModelConfig, build
 from repro.serve import (Engine, Request, RequestState, SamplingParams,
-                         Scheduler, make_buckets, sample)
+                         Scheduler, sample)
 
 MAMBA = ModelConfig(name="mamba-tiny", n_layers=2, d_model=64, n_heads=4,
                     n_kv_heads=4, d_ff=128, vocab=96, pattern=("mamba",),
                     mpd_c=4)
 ARCHS = ("olmo-1b", "rwkv6-3b", "mamba-tiny")
+PAGE = 8        # small pages: a 20-token prompt spans three of them
 
 
 @functools.lru_cache(maxsize=None)
@@ -59,11 +60,11 @@ def _reference(m, p, req):
 
 @pytest.mark.parametrize("arch", ARCHS)
 def test_engine_matches_static_greedy(arch):
-    """More requests than slots: admission, eviction, slot reuse, bucketed
-    padding — greedy output must equal the static batched decode exactly."""
+    """More requests than slots: admission, eviction, slot reuse, chunked
+    prefill — greedy output must equal the static batched decode exactly."""
     m, p = _model(arch)
     reqs = _requests(m.cfg, 6, seed=1)
-    eng = Engine(m, p, n_slots=2, max_len=64)
+    eng = Engine(m, p, n_slots=2, max_len=64, page_size=PAGE)
     out = eng.run(reqs)
     for r in reqs:
         assert out[r.id] == _reference(m, p, r), (arch, r.id)
@@ -73,13 +74,13 @@ def test_engine_matches_static_greedy(arch):
     assert 0.0 < s["occupancy_mean"] <= 1.0
 
 
-@pytest.mark.parametrize("arch", ["olmo-1b", "rwkv6-3b"])
+@pytest.mark.parametrize("arch", ARCHS)
 def test_staggered_admission(arch):
     """Request B arrives mid-decode of request A: B's output must be
     unaffected by when it was admitted, and A's by B's arrival."""
     m, p = _model(arch)
     reqs = _requests(m.cfg, 3, seed=2, max_gen=12)
-    eng = Engine(m, p, n_slots=3, max_len=64)
+    eng = Engine(m, p, n_slots=3, max_len=64, page_size=PAGE)
     eng.submit(reqs[0])
     for _ in range(3):                       # A decodes alone for 3 steps
         eng.step()
@@ -92,50 +93,73 @@ def test_staggered_admission(arch):
         assert list(r.generated) == _reference(m, p, r), (arch, r.id)
 
 
-def test_slot_reuse_after_eviction():
-    """n_slots=1 forces strict sequential reuse of the single slot; the
-    writeback must fully mask the previous occupant's cache rows."""
-    m, p = _model("olmo-1b")
+@pytest.mark.parametrize("arch", ARCHS)
+def test_slot_reuse_after_eviction(arch):
+    """n_slots=1 forces strict sequential reuse of the single slot: the
+    next occupant must see none of the previous one's K/V pages or pinned
+    recurrent-state rows."""
+    m, p = _model(arch)
     reqs = _requests(m.cfg, 3, seed=3)
-    eng = Engine(m, p, n_slots=1, max_len=64)
+    eng = Engine(m, p, n_slots=1, max_len=64, page_size=PAGE)
     out = eng.run(reqs)
     for r in reqs:
-        assert out[r.id] == _reference(m, p, r), r.id
+        assert out[r.id] == _reference(m, p, r), (arch, r.id)
 
 
-def test_per_request_eos_stop():
+@pytest.mark.parametrize("arch", ARCHS)
+def test_per_request_eos_stop(arch):
     """EOS taken from the reference continuation stops that request early;
     the co-resident request is unaffected."""
-    m, p = _model("olmo-1b")
+    m, p = _model(arch)
     reqs = _requests(m.cfg, 2, seed=4, max_gen=12)
     ref0 = _reference(m, p, reqs[0])
     assert len(ref0) >= 4
     reqs[0].eos_id = ref0[2]                 # stop after the 3rd token
     cut = ref0.index(reqs[0].eos_id) + 1     # first occurrence wins
-    eng = Engine(m, p, n_slots=2, max_len=64)
+    eng = Engine(m, p, n_slots=2, max_len=64, page_size=PAGE)
     out = eng.run(reqs)
     assert out[reqs[0].id] == ref0[:cut]
     assert out[reqs[1].id] == _reference(m, p, reqs[1])
 
 
+def _close(got, want):
+    # the padded chunk and the exact prefill run the same arithmetic on
+    # different shapes (a masked attention over the chunk's pages against
+    # a dense causal one), so they agree to float32 re-association, not
+    # bitwise; 1e-4 of the tensor's own scale, as for the logits
+    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+    scale = float(np.max(np.abs(want))) + 1e-6
+    np.testing.assert_allclose(got, want, atol=1e-4 * scale)
+
+
 @pytest.mark.parametrize("arch", ARCHS)
-def test_prefill_lengths_matches_exact(arch):
-    """Length-aware right-padded prefill == exact-length prefill: logits at
-    the last real token and the first greedy continuation agree."""
+def test_prefill_chunk_padded_tail_matches_prefill(arch):
+    """A prefill chunk whose real tokens stop short of its width (the
+    engine's right-padded final chunk) gives the unpadded prefill's logits
+    at the last real token and the same carried state: the K/V in the
+    request's pages and its slot's depth for attention, the slot's
+    recurrent-state rows for mamba/rwkv."""
     m, p = _model(arch)
-    cfg = m.cfg
-    B, T = 3, 16
-    toks = jax.random.randint(jax.random.PRNGKey(5), (B, T), 0, cfg.vocab)
-    lens = jnp.asarray([4, 16, 9], jnp.int32)
-    lg, caches = jax.jit(m.prefill)(p, toks, m.init_caches(B, 32),
-                                    lengths=lens)
-    for b in range(B):
-        n = int(lens[b])
-        lg_ref, _ = m.prefill(p, toks[b:b + 1, :n], m.init_caches(1, 32))
-        scale = float(jnp.max(jnp.abs(lg_ref))) + 1e-6
-        np.testing.assert_allclose(np.asarray(lg[b]), np.asarray(lg_ref[0]),
-                                   atol=1e-4 * scale)
-        assert int(jnp.argmax(lg[b])) == int(jnp.argmax(lg_ref[0]))
+    width, n, slot = 2 * PAGE, 11, 1
+    toks = jax.random.randint(jax.random.PRNGKey(5), (1, n), 0, m.cfg.vocab)
+    padded = jnp.zeros((1, width), jnp.int32).at[:, :n].set(toks)
+    bt_row = jnp.asarray([3, 1], jnp.int32)     # out of order on purpose
+    chunk = jax.jit(m.prefill_chunk, static_argnames=("final",))
+    lg, caches = chunk(p, padded, m.init_paged_caches(2, 4, PAGE), bt_row,
+                       slot, 0, n)
+    lg_ref, ref = m.prefill(p, toks, m.init_caches(1, width))
+    _close(lg[0], lg_ref[0])
+    assert int(jnp.argmax(lg[0])) == int(jnp.argmax(lg_ref[0]))
+    for spec, c, r in zip(m.block_specs, caches, ref):
+        if spec["kind"] in ("attn", "attn_moe"):
+            for pool, dense in (("kp", "k"), ("vp", "v")):
+                rows = c[pool][:, bt_row].reshape(
+                    (c[pool].shape[0], width) + c[pool].shape[3:])
+                _close(rows[:, :n], r[dense][:, 0, :n])
+            np.testing.assert_array_equal(np.asarray(c["pos"][:, slot]), n)
+        else:
+            for k in r:
+                _close(c[k][:, slot], r[k][:, 0])
 
 
 # ------------------------------------------------------------------- sampling
@@ -190,39 +214,12 @@ def test_resubmit_is_fresh():
     assert first == second
 
 
-def test_slot_cache_write_and_reset():
-    """SlotCache public API: writeback lands in exactly the target slot's
-    rows; reset zeroes exactly that slot."""
-    from repro.serve import SlotCache
-
-    m, p = _model("olmo-1b")
-    sc = SlotCache(m, n_slots=3, max_len=16)
-    toks = jnp.arange(8)[None] % m.cfg.vocab
-    _, pcaches = m.prefill(p, toks, m.init_caches(1, 16),
-                           lengths=jnp.asarray([8], jnp.int32))
-    sc.write_slot(pcaches, 1)
-    flat_big = jax.tree.leaves(sc.caches)
-    flat_new = jax.tree.leaves(pcaches)
-    ix = jax.tree.leaves(sc._batch_ix)
-    for big, new, b in zip(flat_big, flat_new, ix):
-        got = jnp.take(big, 1, axis=b)
-        np.testing.assert_array_equal(np.asarray(got, np.float32),
-                                      np.asarray(jnp.take(new, 0, axis=b),
-                                                 np.float32))
-        other = jnp.take(big, 0, axis=b)       # untouched slot stays zero
-        assert float(jnp.abs(other.astype(jnp.float32)).sum()) == 0.0
-    sc.reset_slot(1)
-    for big, b in zip(jax.tree.leaves(sc.caches), ix):
-        assert float(jnp.abs(jnp.take(big, 1, axis=b)
-                             .astype(jnp.float32)).sum()) == 0.0
-
-
 # ---------------------------------------------------------------- sharding
 
 def test_engine_on_mesh_matches_unsharded():
-    """Slot caches placed through repro.dist on a (2,4) host mesh (KV slots
-    shard per the long-context rules): greedy output must equal the
-    no-mesh run exactly."""
+    """Page pools placed through repro.dist on a (2,4) host mesh (per the
+    long-context rules): greedy output must equal the no-mesh run
+    exactly."""
     from conftest import run_forced_device_subprocess
     out = run_forced_device_subprocess("""
 import numpy as np, jax
@@ -239,9 +236,9 @@ def reqs():
     rng = np.random.default_rng(7)
     return [Request(id=i, prompt=rng.integers(0, 96, size=int(rng.integers(3, 18))),
                     max_new_tokens=int(rng.integers(2, 8))) for i in range(4)]
-plain = Engine(m, p, n_slots=2, max_len=48).run(reqs())
+plain = Engine(m, p, n_slots=2, max_len=48, page_size=8).run(reqs())
 with sh.use_mesh_rules(make_host_mesh(2, 4), sh.long_context_rules()):
-    meshed = Engine(m, p, n_slots=2, max_len=48).run(reqs())
+    meshed = Engine(m, p, n_slots=2, max_len=48, page_size=8).run(reqs())
 assert plain == meshed, (plain, meshed)
 print("MESH_OK")
 """)
@@ -250,11 +247,11 @@ print("MESH_OK")
 
 # ------------------------------------------------------------------ scheduler
 
-def test_buckets_and_admission():
-    assert make_buckets(16, 128) == (16, 32, 64, 128)
-    assert make_buckets(16, 100) == (16, 32, 64, 100)
-    s = Scheduler(n_slots=2, max_len=64, min_bucket=16)
-    assert s.bucket_len(3) == 16 and s.bucket_len(17) == 32
+def test_scheduler_admission():
+    """FCFS into free slots, reuse of a freed slot, and the ``max_len``
+    rejection at submit (prompt + max_new_tokens), which holds for any
+    prompt length the budget admits."""
+    s = Scheduler(n_slots=2, max_len=64)
     r = [Request(id=i, prompt=np.arange(4) + 1, max_new_tokens=2)
          for i in range(3)]
     for x in r:
@@ -267,17 +264,14 @@ def test_buckets_and_admission():
     with pytest.raises(ValueError):
         s.submit(Request(id=9, prompt=np.zeros(60, np.int32),
                          max_new_tokens=30))  # exceeds max_len
-    s2 = Scheduler(n_slots=2, max_len=64, buckets=[16, 32])
-    with pytest.raises(ValueError):           # rejected before slot assignment
-        s2.submit(Request(id=10, prompt=np.zeros(40, np.int32),
-                          max_new_tokens=8))
-    # paged mode (strict_buckets=False) has no bucket ceiling
-    s3 = Scheduler(n_slots=2, max_len=64, buckets=[16, 32],
-                   strict_buckets=False)
-    s3.submit(Request(id=11, prompt=np.zeros(40, np.int32), max_new_tokens=8))
-    with pytest.raises(ValueError):           # max_len still caps the total
-        s3.submit(Request(id=12, prompt=np.zeros(60, np.int32),
-                          max_new_tokens=8))
+    with pytest.raises(ValueError):           # rejected before a slot is held
+        s.submit(Request(id=10, prompt=np.zeros(60, np.int32),
+                         max_new_tokens=8))
+    assert [q.id for q in s.waiting] == []
+    s.submit(Request(id=11, prompt=np.zeros(56, np.int32), max_new_tokens=8))
+    assert [(q.id, sl) for q, sl in s.admit()] == []   # both slots busy
+    s.finish(r[1])
+    assert [(q.id, sl) for q, sl in s.admit()] == [(11, 1)]
 
 
 # -------------------------------------------------- scheduler lifecycle edges
@@ -309,7 +303,7 @@ def test_finish_never_admitted_request():
 
 def test_resubmit_finished_request_resets_runtime_fields():
     """A finished request resubmitted (retry) must start from a clean
-    slate: state, slot, generated, and paged prefill progress all reset."""
+    slate: state, slot, generated, and prefill progress all reset."""
     s = Scheduler(n_slots=1, max_len=64)
     r = _reqs(1)[0]
     s.submit(r)
@@ -345,7 +339,7 @@ def test_admission_order_stable_when_slots_free_out_of_order():
     s.finish(first[0])
     s.finish(first[1])
     assert [(q.id, sl) for q, sl in s.admit()] == [(11, 0), (12, 1)]
-    # max_n caps a single admit() round (paged engines re-check the pool
+    # max_n caps a single admit() round (the engine re-checks the pool
     # between admissions)
     for r in list(s.running.values()):
         s.finish(r)

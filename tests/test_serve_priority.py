@@ -79,7 +79,7 @@ def _track_admissions(eng):
 # ------------------------------------------------------------ scheduler unit
 
 def test_scheduler_fcfs_within_class():
-    s = Scheduler(n_slots=2, max_len=64, strict_buckets=False)
+    s = Scheduler(n_slots=2, max_len=64)
     reqs = [Request(id=i, prompt=np.arange(1, 5), priority="batch")
             for i in range(4)]
     for r in reqs:
@@ -88,7 +88,7 @@ def test_scheduler_fcfs_within_class():
 
 
 def test_scheduler_priority_ordering():
-    s = Scheduler(n_slots=2, max_len=64, strict_buckets=False)
+    s = Scheduler(n_slots=2, max_len=64)
     s.submit(Request(id=0, prompt=np.arange(1, 5), priority="batch"))
     s.submit(Request(id=1, prompt=np.arange(1, 5), priority="batch"))
     s.submit(Request(id=2, prompt=np.arange(1, 5), priority="interactive"))
@@ -97,7 +97,7 @@ def test_scheduler_priority_ordering():
 
 
 def test_scheduler_preempt_requeues_at_original_position():
-    s = Scheduler(n_slots=2, max_len=64, strict_buckets=False)
+    s = Scheduler(n_slots=2, max_len=64)
     reqs = [Request(id=i, prompt=np.arange(1, 5), priority="batch")
             for i in range(4)]
     for r in reqs:
@@ -125,7 +125,7 @@ def test_equal_priority_fcfs_stable():
     rng = np.random.default_rng(0)
     reqs = [Request(id=i, prompt=rng.integers(0, m.cfg.vocab, size=10),
                     max_new_tokens=5) for i in range(5)]
-    eng = Engine(m, p, n_slots=2, max_len=64, paged=True, page_size=8)
+    eng = Engine(m, p, n_slots=2, max_len=64, page_size=8)
     order = _track_admissions(eng)
     out = eng.run(reqs)
     assert sorted(order[:2]) == [0, 1]      # first wave fills both slots
@@ -142,7 +142,7 @@ def test_interactive_admits_before_queued_batch():
     rng = np.random.default_rng(1)
     reqs = [Request(id=i, prompt=rng.integers(0, m.cfg.vocab, size=8),
                     max_new_tokens=4, priority="batch") for i in range(3)]
-    eng = Engine(m, p, n_slots=1, max_len=64, paged=True, page_size=8,
+    eng = Engine(m, p, n_slots=1, max_len=64, page_size=8,
                  preemption=False)
     order = _track_admissions(eng)
     for r in reqs:
@@ -172,7 +172,7 @@ def test_interactive_preempts_batch_and_resumes_identical():
                      max_new_tokens=8, priority="batch") for i in range(2)]
     # pool of 7 usable pages: both batch requests reserve 3+3, leaving 1 —
     # not enough for the interactive worst case (2) without eviction
-    eng = Engine(m, p, n_slots=2, max_len=32, paged=True, page_size=8,
+    eng = Engine(m, p, n_slots=2, max_len=32, page_size=8,
                  n_pages=8)
     for r in batch:
         eng.submit(r)
@@ -218,7 +218,7 @@ def test_preemption_spares_trie_shared_pages():
     # 8 usable pages: both batch requests decode (3 pages each), leaving 2
     # free — short of the interactive worst case (3), forcing preemption,
     # but with enough slack that admission never reclaims trie pages
-    eng = Engine(m, p, n_slots=2, max_len=32, paged=True, page_size=8,
+    eng = Engine(m, p, n_slots=2, max_len=32, page_size=8,
                  n_pages=9)
     for r in batch:
         eng.submit(r)
@@ -258,7 +258,7 @@ def test_cancel_running_and_waiting():
     rng = np.random.default_rng(4)
     reqs = [Request(id=i, prompt=rng.integers(0, m.cfg.vocab, size=10),
                     max_new_tokens=12) for i in range(3)]
-    eng = Engine(m, p, n_slots=2, max_len=64, paged=True, page_size=8)
+    eng = Engine(m, p, n_slots=2, max_len=64, page_size=8)
     for r in reqs:
         eng.submit(r)
     eng.step()

@@ -34,7 +34,7 @@ from repro.configs import common
 from repro.models import build
 from repro.serve import (DegradationLadder, Engine, FaultInjector, FaultSpec,
                          GenerateServer, InjectedFault, Request, Resilience,
-                         parse_schedule, storm_schedule)
+                         SamplingParams, parse_schedule, storm_schedule)
 from repro.serve.cache import NULL_PAGE
 
 from test_serve_paged import _model, _reference, _requests
@@ -183,7 +183,7 @@ def test_chaos_storm_token_identical():
     ]
     res = Resilience(injector=FaultInjector(schedule, seed=0),
                      ladder=DegradationLadder())
-    eng = Engine(m, p, n_slots=3, max_len=64, paged=True, page_size=8,
+    eng = Engine(m, p, n_slots=3, max_len=64, page_size=8,
                  resilience=res)
     reqs = _fresh_requests(m.cfg, 4, seed=11)
     out = eng.run(reqs)
@@ -209,23 +209,42 @@ def test_chaos_storm_with_spec_draft():
     must quarantine (never leak resampled garbage), and survivors stay
     identical to fault-free spec output (== static greedy)."""
     m, p = _model("olmo-1b")
-    baseline = {r.id: _reference(m, p, r)
-                for r in _fresh_requests(m.cfg, 3, seed=12)}
+
+    def requests():
+        reqs = _fresh_requests(m.cfg, 3, seed=12)
+        # the draft site's row samples: a greedy row proposes the argmax
+        # with a one-hot q, which a poisoned draft leaves finite, and its
+        # acceptance against the target's argmax takes no garbage token
+        reqs[0].sampling = SamplingParams(temperature=0.8, top_k=8, seed=3)
+        return reqs
+
+    def serve(resilience):
+        eng = Engine(m, p, n_slots=2, max_len=64, page_size=8,
+                     spec_draft=(m, p), spec_k=3, resilience=resilience)
+        assert eng.spec_active
+        reqs = requests()
+        return eng, reqs, eng.run(reqs)
+
+    _, _, baseline = serve(None)
+    for r in requests()[1:]:
+        assert baseline[r.id] == _reference(m, p, r), r.id
+    # the draft is the target itself, so nearly every window is accepted
+    # whole and the fault-free run drains in a few steps: step 0 decodes
+    # request 0 in slot 0 (request 1 still queued for its chunk), step 1
+    # decodes request 1 in slot 1. Each fault lands on a live slot there.
     schedule = [
-        FaultSpec("draft_logits", step=4, slot=0),
-        FaultSpec("decode_logits", step=6, slot=1,
+        FaultSpec("draft_logits", step=0, slot=0),
+        FaultSpec("decode_logits", step=1, slot=1,
                   value=float("inf")),
     ]
     res = Resilience(injector=FaultInjector(schedule, seed=1))
-    eng = Engine(m, p, n_slots=2, max_len=64, paged=True, page_size=8,
-                 spec_draft=(m, p), spec_k=3, resilience=res)
-    assert eng.spec_active
-    reqs = _fresh_requests(m.cfg, 3, seed=12)
-    out = eng.run(reqs)
+    eng, reqs, out = serve(res)
     for r in reqs:
         assert r.finish_reason not in ("fault", "deadline"), r.id
         assert out[r.id] == baseline[r.id], r.id
-    assert res.injector.total_injected >= 1
+    assert res.injector.counts["draft_logits"] == 1
+    assert res.injector.counts["decode_logits"] == 1
+    assert eng.n_quarantines == 2           # both poisoned rows were live
     _pool_conserved(eng.cache)
     _pool_conserved(eng.draft_cache)
 
@@ -238,7 +257,7 @@ def test_retries_exhausted_finish_reason_fault():
     schedule = [FaultSpec("decode_logits", step=0, n_steps=10_000, slot=0)]
     res = Resilience(injector=FaultInjector(schedule), max_fault_retries=2,
                      retry_backoff_steps=1)
-    eng = Engine(m, p, n_slots=1, max_len=64, paged=True, page_size=8,
+    eng = Engine(m, p, n_slots=1, max_len=64, page_size=8,
                  resilience=res)
     req = _fresh_requests(m.cfg, 1, seed=5)[0]
     eng.submit(req)
@@ -258,13 +277,15 @@ def test_retries_exhausted_finish_reason_fault():
 
 
 def test_quarantine_does_not_perturb_dense_engine():
-    """The watchdog also covers the slot-dense (non-paged) engine."""
-    m, p = _model("olmo-1b")
+    """The watchdog also covers a recurrent arch, whose quarantined slot
+    must give back its pinned state row with its pages: the retry and the
+    co-batched survivor both match the static decode."""
+    m, p = _model("rwkv6-3b")
     baseline = {r.id: _reference(m, p, r)
                 for r in _fresh_requests(m.cfg, 3, seed=13)}
     res = Resilience(
         injector=FaultInjector([FaultSpec("decode_logits", step=2, slot=0)]))
-    eng = Engine(m, p, n_slots=2, max_len=64, resilience=res)
+    eng = Engine(m, p, n_slots=2, max_len=64, page_size=8, resilience=res)
     reqs = _fresh_requests(m.cfg, 3, seed=13)
     out = eng.run(reqs)
     for r in reqs:
@@ -286,7 +307,7 @@ def test_quarantined_head_does_not_wedge_preemption():
                 max_new_tokens=8, priority="batch")
     baseline = {r.id: _reference(m, p, r) for r in (i, b)}
     res = Resilience(injector=FaultInjector(storm_schedule()))
-    eng = Engine(m, p, n_slots=4, max_len=48, paged=True, page_size=8,
+    eng = Engine(m, p, n_slots=4, max_len=48, page_size=8,
                  preemption=True, resilience=res)
     out = eng.run([i, b], max_steps=200)     # pre-fix: never drains
     for r in (i, b):
@@ -306,7 +327,7 @@ def test_deadline_abort_frees_within_step():
     for r in reqs:                 # long-lived: still running at the abort
         r.max_new_tokens = 16
     baseline = _reference(m, p, reqs[1])
-    eng = Engine(m, p, n_slots=2, max_len=64, paged=True, page_size=8)
+    eng = Engine(m, p, n_slots=2, max_len=64, page_size=8)
     now = [0.0]
     eng.metrics.clock = lambda: now[0]
     reqs[0].e2e_slo_s = 0.5
@@ -341,7 +362,7 @@ def test_ladder_spec_suspend_resume_exact():
     baseline = {r.id: _reference(m, p, r)
                 for r in _fresh_requests(m.cfg, 2, seed=15)}
     lad = DegradationLadder()
-    eng = Engine(m, p, n_slots=2, max_len=64, paged=True, page_size=8,
+    eng = Engine(m, p, n_slots=2, max_len=64, page_size=8,
                  spec_draft=(m, p), spec_k=3,
                  resilience=Resilience(ladder=lad))
     reqs = _fresh_requests(m.cfg, 2, seed=15)
@@ -364,7 +385,7 @@ def test_ladder_spec_suspend_resume_exact():
 def test_ladder_flush_prefix_stage_flushes_and_suspends_publish():
     m, p = _model("olmo-1b")
     lad = DegradationLadder()
-    eng = Engine(m, p, n_slots=2, max_len=64, paged=True, page_size=8,
+    eng = Engine(m, p, n_slots=2, max_len=64, page_size=8,
                  resilience=Resilience(ladder=lad))
     reqs = _fresh_requests(m.cfg, 2, seed=16)
     eng.run(reqs)
@@ -424,7 +445,7 @@ def _raw_post(port, path, body: bytes):
 
 def test_server_rejects_malformed_and_unknown_fields():
     m, p = _model("olmo-1b")
-    engine = Engine(m, p, n_slots=1, max_len=64, paged=True, page_size=8)
+    engine = Engine(m, p, n_slots=1, max_len=64, page_size=8)
 
     async def main():
         server = GenerateServer(engine, port=0, queue_limit=4,
@@ -459,7 +480,7 @@ def test_server_midstream_engine_fault_structured_error():
         injector=FaultInjector([FaultSpec("engine_step", step=1,
                                           n_steps=1000)]),
         max_consecutive_step_faults=0)     # first fault is terminal
-    engine = Engine(m, p, n_slots=1, max_len=64, paged=True, page_size=8,
+    engine = Engine(m, p, n_slots=1, max_len=64, page_size=8,
                     resilience=res)
 
     async def main():
@@ -484,7 +505,7 @@ def test_server_midstream_engine_fault_structured_error():
 def test_server_sheds_batch_when_ladder_saturated():
     m, p = _model("olmo-1b")
     lad = DegradationLadder()
-    engine = Engine(m, p, n_slots=1, max_len=64, paged=True, page_size=8,
+    engine = Engine(m, p, n_slots=1, max_len=64, page_size=8,
                     resilience=Resilience(ladder=lad))
     lad.force(3)
 
@@ -512,7 +533,7 @@ def test_server_injected_500_is_structured():
     m, p = _model("olmo-1b")
     res = Resilience(
         injector=FaultInjector([FaultSpec("server_error", step=0)]))
-    engine = Engine(m, p, n_slots=1, max_len=64, paged=True, page_size=8,
+    engine = Engine(m, p, n_slots=1, max_len=64, page_size=8,
                     resilience=res)
 
     async def main():
@@ -541,7 +562,7 @@ def test_prometheus_chaos_series():
     res = Resilience(
         injector=FaultInjector([FaultSpec("decode_logits", step=3, slot=0)]),
         ladder=lad)
-    eng = Engine(m, p, n_slots=2, max_len=64, paged=True, page_size=8,
+    eng = Engine(m, p, n_slots=2, max_len=64, page_size=8,
                  resilience=res)
     reqs = _fresh_requests(m.cfg, 2, seed=17)
     for r in reqs:                 # long-lived: slot 0 still live at step 3

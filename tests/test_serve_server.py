@@ -123,7 +123,7 @@ def test_sse_stream_matches_direct_engine():
     rng = np.random.default_rng(0)
     prompts = [rng.integers(0, m.cfg.vocab, size=int(n)).tolist()
                for n in (9, 13, 7)]
-    engine = Engine(m, p, n_slots=2, max_len=64, paged=True, page_size=8)
+    engine = Engine(m, p, n_slots=2, max_len=64, page_size=8)
 
     async def main():
         server = GenerateServer(engine, port=0, queue_limit=8)
@@ -158,7 +158,7 @@ def test_sse_stream_matches_with_spec_draft():
     rng = np.random.default_rng(1)
     prompts = [rng.integers(0, m.cfg.vocab, size=int(n)).tolist()
                for n in (10, 12)]
-    engine = Engine(m, p, n_slots=2, max_len=64, paged=True, page_size=8,
+    engine = Engine(m, p, n_slots=2, max_len=64, page_size=8,
                     spec_draft=(m, p), spec_k=3)
     assert engine.spec_active
 
@@ -186,7 +186,7 @@ def test_backpressure_429_retry_after():
     """With the pump paused nothing drains: queue_limit=1 admits one
     waiting request and turns the next away with 429 + Retry-After."""
     m, p = _model()
-    engine = Engine(m, p, n_slots=1, max_len=64, paged=True, page_size=8)
+    engine = Engine(m, p, n_slots=1, max_len=64, page_size=8)
 
     async def main():
         server = GenerateServer(engine, port=0, queue_limit=1,
@@ -236,7 +236,7 @@ def test_disconnect_cancels_and_returns_pages():
     rng = np.random.default_rng(2)
     keep_prompt = rng.integers(0, m.cfg.vocab, size=9).tolist()
     drop_prompt = rng.integers(0, m.cfg.vocab, size=11).tolist()
-    engine = Engine(m, p, n_slots=2, max_len=64, paged=True, page_size=8)
+    engine = Engine(m, p, n_slots=2, max_len=64, page_size=8)
 
     async def main():
         server = GenerateServer(engine, port=0, auto_pump=False)
@@ -291,7 +291,7 @@ def test_disconnect_cancels_and_returns_pages():
 
 def test_metrics_and_healthz_endpoints():
     m, p = _model()
-    engine = Engine(m, p, n_slots=2, max_len=64, paged=True, page_size=8)
+    engine = Engine(m, p, n_slots=2, max_len=64, page_size=8)
 
     async def main():
         server = GenerateServer(engine, port=0)
@@ -325,7 +325,7 @@ def test_metrics_and_healthz_endpoints():
 
 def test_bad_request_400():
     m, p = _model()
-    engine = Engine(m, p, n_slots=1, max_len=32, paged=True, page_size=8)
+    engine = Engine(m, p, n_slots=1, max_len=32, page_size=8)
 
     async def main():
         server = GenerateServer(engine, port=0, auto_pump=False)

@@ -78,7 +78,7 @@ def _requests(cfg, n, seed=0, max_prompt=20, max_gen=10, sampled=False):
 
 
 def _run(m, p, reqs, *, spec_draft=None, spec_k=4, n_slots=2):
-    eng = Engine(m, p, n_slots=n_slots, max_len=64, paged=True, page_size=8,
+    eng = Engine(m, p, n_slots=n_slots, max_len=64, page_size=8,
                  spec_draft=spec_draft, spec_k=spec_k)
     return eng.run(reqs), eng
 
@@ -148,10 +148,13 @@ def test_spec_recurrent_fallback(arch):
     assert s["draft_acceptance_rate"] == 0.0
 
 
-def test_spec_requires_paged():
+def test_engine_rejects_slot_dense():
+    """The engine has one memory model: asking for the removed slot-dense
+    one is an error, with or without a draft."""
     m, p = _model("olmo-1b")
-    with pytest.raises(ValueError, match="paged"):
-        Engine(m, p, n_slots=2, max_len=64, spec_draft=(m, p))
+    for kw in ({}, {"spec_draft": (m, p)}):
+        with pytest.raises(ValueError, match="slot-dense"):
+            Engine(m, p, n_slots=2, max_len=64, paged=False, **kw)
 
 
 # ------------------------------------------------------------------- sampling

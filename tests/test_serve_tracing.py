@@ -50,7 +50,7 @@ def _inside(inner, outer):
 @pytest.mark.parametrize("spec", [False, True], ids=["paged", "spec"])
 def test_engine_spans_nest_under_step(tmp_path, spec):
     m, p = _model("olmo-1b")
-    eng = Engine(m, p, n_slots=2, max_len=64, paged=True, page_size=8,
+    eng = Engine(m, p, n_slots=2, max_len=64, page_size=8,
                  prefill_chunk_tokens=8,
                  spec_draft=_drafts("olmo-1b")["perfect"] if spec else None)
     reqs = _requests(m.cfg, 4, seed=3, max_prompt=24)
@@ -117,7 +117,7 @@ def test_kv_tokens_are_the_decode_kernel_lengths(tmp_path, spec):
     import numpy as np
 
     m, p = _model("olmo-1b")
-    eng = Engine(m, p, n_slots=2, max_len=64, paged=True, page_size=8,
+    eng = Engine(m, p, n_slots=2, max_len=64, page_size=8,
                  prefill_chunk_tokens=8,
                  spec_draft=_drafts("olmo-1b")["perfect"] if spec else None)
     handed = []
@@ -157,7 +157,7 @@ def test_kv_tokens_are_the_decode_kernel_lengths(tmp_path, spec):
 
 def _engine():
     m, p = _model("olmo-1b")
-    return Engine(m, p, n_slots=2, max_len=64, paged=True, page_size=8,
+    return Engine(m, p, n_slots=2, max_len=64, page_size=8,
                   prefill_chunk_tokens=8)
 
 
